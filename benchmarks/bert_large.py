@@ -70,8 +70,8 @@ def main():
 
     tokens_per_sec = gas * micro_bs * seq / best
     achieved = tokens_per_sec * model.flops_per_token(seq)
-    from bench import detect_peak
-    peak = detect_peak()
+    from bench import device_peaks
+    peak = device_peaks()["bf16_flops"]
     out = {
         "benchmark": "bert_large_mlm_bf16_train",
         "seq": seq, "micro_bs": micro_bs, "gas": gas,

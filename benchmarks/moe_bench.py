@@ -14,7 +14,7 @@ import numpy as np
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from bench import detect_peak  # noqa: E402 — shared per-generation peak
+from bench import device_peaks  # noqa: E402 — the one table of peaks
 
 
 def main():
@@ -59,7 +59,7 @@ def main():
         best = min(best, time.perf_counter() - t0)
     tok_s = steps * gas * micro * seq / best
     fpt = model.flops_per_token(seq)          # ACTIVE-param flops
-    peak = detect_peak()
+    peak = device_peaks()["bf16_flops"]
     report = {
         "benchmark": "gpt2_moe_8e_top2_bf16_train",
         "model": "gpt2-small + 8 experts top-2",

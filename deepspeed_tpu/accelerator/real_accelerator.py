@@ -16,15 +16,15 @@ def _detect():
     name = os.environ.get("DSTPU_ACCELERATOR")
     if name == "cpu":
         return CPU_Accelerator()
-    if name == "tpu":
-        return TPU_Accelerator()
-    try:
-        import jax
-        if any(d.platform != "cpu" for d in jax.devices()):
-            return TPU_Accelerator()
-    except Exception:
-        pass
-    return CPU_Accelerator()
+    import jax
+    # an exception from jax.devices() propagates: a backend that cannot be
+    # reached is an error, not a reason to carry on on the CPU
+    has_tpu = any(d.platform != "cpu" for d in jax.devices())
+    if name == "tpu" and not has_tpu:
+        raise RuntimeError(
+            "DSTPU_ACCELERATOR=tpu but jax sees no TPU device "
+            f"(devices: {jax.devices()})")
+    return TPU_Accelerator() if has_tpu else CPU_Accelerator()
 
 
 def get_accelerator():

@@ -15,7 +15,12 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         self._seed = 42
 
     def _devices(self):
-        return [d for d in jax.devices() if d.platform != "cpu"] or jax.devices()
+        devs = [d for d in jax.devices() if d.platform != "cpu"]
+        if not devs:
+            raise RuntimeError(
+                f"TPU accelerator selected but jax sees no TPU device "
+                f"(devices: {jax.devices()})")
+        return devs
 
     def device_name(self, device_index=None):
         if device_index is None:
@@ -35,10 +40,6 @@ class TPU_Accelerator(DeepSpeedAccelerator):
     def synchronize(self, device_index=None):
         # XLA async dispatch: block until all queued work is done.
         jax.block_until_ready(jax.device_put(0, self.device(device_index)))
-        try:
-            self.device(device_index).synchronize_all_activity()
-        except Exception:
-            pass
 
     def manual_seed(self, seed):
         self._seed = seed
@@ -47,10 +48,7 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return jax.random.PRNGKey(self._seed)
 
     def memory_stats(self, device_index=None):
-        try:
-            return dict(self.device(device_index).memory_stats() or {})
-        except Exception:
-            return {}
+        return dict(self.device(device_index).memory_stats() or {})
 
     def is_bf16_supported(self):
         return True
@@ -85,7 +83,6 @@ class TPU_Accelerator(DeepSpeedAccelerator):
         return get_builder_class(class_name, backend="tpu")
 
     def on_accelerator(self, tensor):
-        try:
-            return any(d.platform != "cpu" for d in tensor.devices())
-        except Exception:
+        if not hasattr(tensor, "devices"):      # numpy / python scalars
             return False
+        return any(d.platform != "cpu" for d in tensor.devices())

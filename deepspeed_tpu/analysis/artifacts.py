@@ -139,9 +139,10 @@ def lower_pipe_step(size: str = "tiny", pp: int = 8,
                     ) -> HloArtifact:
     """The compiled 1F1B pipeline step (shard_map over 'pipe', ppermute
     stage hops through the comm dispatch). pp spans the whole mesh
-    (dp=1): the jax pin's pre-0.5 shard_map crashes XLA's partitioner
-    on partial-manual regions with a non-trivial auto axis, so the
-    pp-only layout is the one this backend can lower — the collective
+    (dp=1): under an older jax, shard_map crashed XLA's partitioner
+    on partial-manual regions with a non-trivial auto axis; not retested
+    on jax 0.9 (ROADMAP B3), so the artifact keeps the pp-only layout —
+    the collective
     structure under audit (per-tick ppermute chain + aux psum) is
     identical."""
     from ..models.gpt2 import GPT2Config, GPT2Model

@@ -107,10 +107,14 @@ class HloArtifact:
 _MAIN_RE = re.compile(r"func\.func\s+(?:public\s+)?@main\((.*?)\)\s*->",
                       re.DOTALL)
 # the attr dict may nest braces inside quoted strings ('mhlo.sharding =
-# "{devices=[8,1]<=[8]}"') — consume quoted runs atomically so the
-# closing brace found is the attr dict's own
+# "{devices=[8,1]<=[8]}"') and, under Shardy, one level of bare ones
+# ('sdy.sharding = #sdy.sharding<@mesh, [{}, {"data"}]>'). Every run is
+# possessive: with plain quantifiers a dict this pattern cannot close
+# backtracks exponentially (jax 0.9 signatures never returned).
+_ATTR_RUN = r"[^{}\"]++|\"[^\"]*+\""
 _ARG_RE = re.compile(
-    r"%arg(\d+):\s*tensor<([^>]*)>\s*(\{(?:[^{}\"]+|\"[^\"]*\")*\})?")
+    r"%arg(\d+):\s*tensor<([^>]*)>\s*"
+    r"(\{(?:" + _ATTR_RUN + r"|\{(?:" + _ATTR_RUN + r")*+\})*+\})?")
 _MLIR_DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "i64": 8,
                      "ui64": 8, "i32": 4, "ui32": 4, "i16": 2, "ui16": 2,
                      "i8": 1, "ui8": 1, "i1": 1, "f8E4M3FN": 1,

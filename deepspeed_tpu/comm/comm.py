@@ -103,14 +103,12 @@ def init_distributed(dist_backend: str = "xla",
 
 def _dist_state():
     """The jax.distributed global state (None outside multi-process runs).
-    The control plane below reads it directly — backend-independent, so it
-    works even when a device plugin shadows the default backend."""
-    try:
-        from jax._src import distributed
-        if distributed.global_state.client is not None:
-            return distributed.global_state
-    except Exception:
-        pass
+    Private import: the barrier and object broadcast below need the
+    coordination-service client (key-value store, wait_at_barrier), which
+    jax 0.9 exposes nowhere public."""
+    from jax._src import distributed
+    if distributed.global_state.client is not None:
+        return distributed.global_state
     return None
 
 

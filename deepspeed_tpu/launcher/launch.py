@@ -16,8 +16,10 @@ re-planned to the next smaller valid world size from
 
 A JAX SPMD job runs ONE process per host (the process drives all local TPU
 chips), so the default --nproc_per_node is 1 — unlike the reference's
-process-per-GPU model. >1 is supported for the CPU-backend test rig, where N
-single-device processes emulate N hosts on one machine.
+process-per-GPU model. >1 is for the CPU-backend test rig only, where N
+single-device processes emulate N hosts on one machine: a chip belongs to
+one process at a time and nothing here gives each worker its own, so off
+the CPU backend >1 is refused.
 """
 
 import argparse
@@ -169,6 +171,15 @@ def main(argv=None):
                        "disabling restarts (kill-the-tree semantics)")
         args.max_restarts = 0
     nproc = args.nproc_per_node
+    on_cpu = os.environ.get("JAX_PLATFORMS", "").lower().startswith("cpu") \
+        or os.environ.get("DSTPU_ACCELERATOR", "").lower() == "cpu"
+    if nproc > 1 and not on_cpu:
+        logger.error(
+            f"launch: --nproc_per_node={nproc} would start {nproc} "
+            f"processes that all claim this host's TPU chips; one process "
+            f"drives all local chips (use the default 1), or set "
+            f"JAX_PLATFORMS=cpu for the CPU test rig")
+        return 2
     failures = 0
     for attempt in range(args.max_restarts + 1):
         rc = _run_group(args, attempt, nproc)

@@ -215,11 +215,15 @@ class GPT2Model(ModelSpec):
             # qkv matmul produced — no head transposes in fwd OR bwd, and
             # no duplicate [B,H,T,D] residual save (round-3 profiling:
             # ~5 ms/micro of relayout copies at 125M)
-            from ..ops.flash_attention import _on_tpu
+            from ..ops.flash_attention import pallas_per_device
             from ..ops.pallas.flash_attention_packed import \
                 packed_flash_attention
-            attn = packed_flash_attention(q, k, v, h,
-                                          interpret=not _on_tpu())
+            from ..parallel.topology import on_tpu
+            interpret = not on_tpu()
+            attn = pallas_per_device(
+                lambda q, k, v, n: packed_flash_attention(
+                    q, k, v, n, interpret=interpret),
+                q, k, v, h, packed=True)
             attn = attn @ p["attn_proj_w"].astype(attn.dtype) + \
                 p["attn_proj_b"].astype(attn.dtype)
             return x + self._dropout(attn, rng, train, 0)
@@ -244,22 +248,24 @@ class GPT2Model(ModelSpec):
     def _packed_attn_ok(self, t: int, hd: int, h: int) -> bool:
         """Packed-layout Pallas attention eligibility: TPU pallas backend,
         no live 'seq' axis (sp uses the [B,H,T,D] kernels), and shapes the
-        packed kernel supports. Env override DSTPU_PACKED_ATTN=0 disables
-        (read at TRACE time — set it before the first compile; a cached
-        jitted step keeps whichever path it was traced with)."""
-        import os as _os
-        if _os.environ.get("DSTPU_PACKED_ATTN", "1") == "0":
-            return False
-        from ..ops.flash_attention import _on_tpu
+        packed kernel supports."""
         from ..ops.pallas.flash_attention_packed import supported
         from ..ops.seq_parallel import seq_axis_size
+        from ..parallel.constraints import active_mesh
+        from ..parallel.topology import MODEL_AXIS, on_tpu
         # auto engages on real TPU; backend 'pallas' also engages on CPU
         # (interpret mode — the parity-test path)
         if self.config.attn_backend == "pallas":
             pass
-        elif self.config.attn_backend != "auto" or not _on_tpu():
+        elif self.config.attn_backend != "auto" or not on_tpu():
             return False
-        return seq_axis_size() == 1 and supported(t, hd, h, True, None)
+        # under tensor parallelism each device's kernel sees h / tp heads
+        # (ops/flash_attention.pallas_per_device)
+        mesh = active_mesh()
+        tp = int(mesh.shape.get(MODEL_AXIS, 1)) if mesh is not None else 1
+        if h % tp:
+            tp = 1
+        return seq_axis_size() == 1 and supported(t, hd, h // tp, True, None)
 
     def _mlp_sublayer(self, x, p, rng, train):
         """ln2 → fc → gelu → proj → residual (+dropout). Returns (x, aux)."""

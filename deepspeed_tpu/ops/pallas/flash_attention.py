@@ -49,6 +49,9 @@ _BTN = (((1,), (1,)), ((0,), (0,)))
 # for fp32 temporaries — budget well under the 16M scoped-vmem limit (the
 # train-step context proved tighter than a standalone call: GH=4 at
 # T=1024/D=64 compiled alone but blew scoped vmem inside the fused step).
+# Re-checked with libtpu 0.0.34 (PR 24): at the folds this budget picks,
+# the whole GPT-2 350M train step (micro 8, T=1024) compiles for a v5e with
+# these kernels in it, forward and fused backward.
 # Env override for experiments: DSTPU_FLASH_VMEM_BUDGET (bytes).
 import os as _os
 _VMEM_BUDGET = int(_os.environ.get("DSTPU_FLASH_VMEM_BUDGET",
@@ -79,10 +82,12 @@ def _pick_blocks(t: int):
 
 
 def _pick_gh(bh: int, t: int, d: int, bq: int, bk: int,
-             itemsize: int = 2) -> int:
-    """Largest head fold whose resident footprint fits the VMEM budget.
-    ``itemsize`` is the q/k/v element size (2 for bf16, 4 for fp32 —
-    fp32 inputs double the K/V, q/o and p footprints)."""
+             itemsize: int = 2):
+    """Largest head fold whose resident footprint fits the VMEM budget,
+    or None when not even one head fits (the caller then takes the
+    streamed kernels). ``itemsize`` is the q/k/v element size (2 for
+    bf16, 4 for fp32 — fp32 inputs double the K/V, q/o and p
+    footprints)."""
     for gh in (8, 4, 2, 1):
         if bh % gh:
             continue
@@ -91,17 +96,19 @@ def _pick_gh(bh: int, t: int, d: int, bq: int, bk: int,
         qo_bytes = gh * bq * d * (2 * itemsize + 4)   # q, o, fp32 acc
         if s_bytes + kv_bytes + qo_bytes <= _VMEM_BUDGET:
             return gh
-    return 1
+    return None
 
 
-# Above this K/V footprint the resident kernels (full K/V per head in VMEM)
+# From this K/V footprint up the resident kernels (full K/V per head in VMEM)
 # give way to the streamed kernels (k-blocks as a grid dimension, online
 # accumulators in scratch) — the long-context single-chip path.
+# The bound is inclusive: at exactly 1 MiB (T=8192, D=64, bf16) the fused
+# resident backward needs ~2x what Mosaic's scoped VMEM holds.
 _RESIDENT_MAX_KV_BYTES = 1024 * 1024
 
 
 def _streamed(t: int, d: int, itemsize: int) -> bool:
-    return t * d * itemsize > _RESIDENT_MAX_KV_BYTES
+    return t * d * itemsize >= _RESIDENT_MAX_KV_BYTES
 
 
 def _pick_gh_streamed(bh: int, d: int, bq: int, bk: int,
@@ -114,6 +121,9 @@ def _pick_gh_streamed(bh: int, d: int, bq: int, bk: int,
         qo_bytes = gh * bq * d * (2 * itemsize + 4 * 3)  # q, o, acc+m+l f32
         if s_bytes + kv_bytes + qo_bytes <= _VMEM_BUDGET:
             return gh
+    # one head is the floor: its footprint does not grow with T, and it
+    # compiles for the v5e at the widest shape supported() admits (d=256,
+    # fp32 — a case of tests/unit/test_chip_compile.py)
     return 1
 
 
@@ -177,14 +187,15 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret, window=None):
     b, h, t, d = q.shape
     bh = b * h
     qf, kf, vf = (x.reshape(bh, t, d) for x in (q, k, v))
-    if _streamed(t, d, q.dtype.itemsize):
+    gh = None if _streamed(t, d, q.dtype.itemsize) else (
+        int(_os.environ.get("DSTPU_FLASH_GH_FWD", 0)) or
+        _pick_gh(bh, t, d, block_q, block_k, q.dtype.itemsize))
+    if gh is None:
         gh = _pick_gh_streamed(bh, d, block_q, block_k,
                                q.dtype.itemsize)
         out, lse = _fwd_streamed(qf, kf, vf, causal, scale, block_q, block_k,
                                  interpret, window, gh)
         return out.reshape(b, h, t, d), lse.reshape(b, h, t, 1)
-    gh = int(_os.environ.get("DSTPU_FLASH_GH_FWD", 0)) or \
-        _pick_gh(bh, t, d, block_q, block_k, q.dtype.itemsize)
     grid = (bh // gh, t // block_q)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
                                block_q=block_q, block_k=block_k, t_k=t, gh=gh,
@@ -280,9 +291,11 @@ def _bwd_fused_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _pick_gh_fused_bwd(bh: int, t: int, d: int, bq: int, bk: int,
-                       itemsize: int = 2) -> int:
-    """Head fold for the fused backward: q/do resident [GH,T,D] plus the
-    f32 dq scratch dominate. Budget is 2x the fwd budget — calibrated on
+                       itemsize: int = 2):
+    """Head fold for the fused backward, or None when not even one head
+    fits (the caller then takes the streamed kernels): q/do resident
+    [GH,T,D] plus the f32 dq scratch dominate. Budget is 2x the fwd
+    budget — calibrated on
     the real chip: gh=2 at (bh96, t1024, d64, bq512, bk256, bf16)
     compiles inside the fused train step (estimate 5.2M), gh=4 blows the
     16M scoped-vmem limit by 1.8M (estimate 12.6M)."""
@@ -295,7 +308,7 @@ def _pick_gh_fused_bwd(bh: int, t: int, d: int, bq: int, bk: int,
         tmp = gh * bq * bk * (4 + 4 + 2 * itemsize)  # s/p, dp/ds, p_lp+ds_lp
         if resident + dq_bytes + kv_bytes + tmp <= 2 * _VMEM_BUDGET:
             return gh
-    return 1
+    return None
 
 
 def _bwd_fused(qf, kf, vf, dof, lsef, deltaf, causal, scale, block_q,
@@ -406,24 +419,23 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret,
     qf, kf, vf, dof = (x.reshape(bh, t, d) for x in (q, k, v, do))
     lsef = lse.reshape(bh, t, 1)
     deltaf = delta.reshape(bh, t, 1)
+    fused = _os.environ.get("DSTPU_FLASH_BWD", "fused") == "fused"
     if _streamed(t, d, q.dtype.itemsize):
-        gh = _pick_gh_streamed(bh, d, block_q, block_k,
-                               q.dtype.itemsize)
-        dq, dk, dv = _bwd_streamed(qf, kf, vf, dof, lsef, deltaf, causal,
-                                   scale, block_q, block_k, interpret,
-                                   window, gh)
-        return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
-                dv.reshape(b, h, t, d))
-    if _os.environ.get("DSTPU_FLASH_BWD", "fused") == "fused":
-        gh_fused = int(_os.environ.get("DSTPU_FLASH_GH_BWD", 0)) or \
+        gh = None
+    elif fused:
+        gh = int(_os.environ.get("DSTPU_FLASH_GH_BWD", 0)) or \
             _pick_gh_fused_bwd(bh, t, d, block_q, block_k,
                                q.dtype.itemsize)
-        dq, dk, dv = _bwd_fused(qf, kf, vf, dof, lsef, deltaf, causal,
-                                scale, block_q, block_k, interpret, window,
-                                gh_fused)
+    else:
+        gh = _pick_gh(bh, t, d, block_q, block_k, q.dtype.itemsize)
+    if gh is None or fused:
+        run = _bwd_streamed if gh is None else _bwd_fused
+        gh = gh or _pick_gh_streamed(bh, d, block_q, block_k,
+                                     q.dtype.itemsize)
+        dq, dk, dv = run(qf, kf, vf, dof, lsef, deltaf, causal, scale,
+                         block_q, block_k, interpret, window, gh)
         return (dq.reshape(b, h, t, d), dk.reshape(b, h, t, d),
                 dv.reshape(b, h, t, d))
-    gh = _pick_gh(bh, t, d, block_q, block_k, q.dtype.itemsize)
 
     blk_spec = pl.BlockSpec((gh, block_q, d), lambda n, i: (n, i, 0))
     full_spec = pl.BlockSpec((gh, t, d), lambda n, i: (n, 0, 0))

@@ -236,17 +236,10 @@ def sparse_attention(q, k, v, layout, block, softmax_scale=None,
                     f"dense-masked fallback")
             return sparse_attention_pallas(
                 q, k, v, layout, block, softmax_scale=softmax_scale)
-        on_tpu = jax.devices()[0].platform == "tpu"
-        if ok and on_tpu:
-            try:
-                return sparse_attention_pallas(
-                    q, k, v, layout, block, softmax_scale=softmax_scale)
-            except Exception as exc:  # noqa: BLE001
-                import warnings
-                warnings.warn(
-                    f"pallas block-sparse kernel failed "
-                    f"({type(exc).__name__}: {exc}); falling back to the "
-                    f"dense-masked path", RuntimeWarning)
+        from ..parallel.topology import on_tpu
+        if ok and on_tpu():
+            return sparse_attention_pallas(
+                q, k, v, layout, block, softmax_scale=softmax_scale)
     from .flash_attention import reference_attention
     mask = jnp.asarray(layout_to_mask(layout, block))[None]  # [1,H,T,T]
     return reference_attention(q, k, v, causal=False, mask=mask,

@@ -8,6 +8,8 @@ Axes of size 1 are kept (no-op for XLA, zero cost).
 """
 
 from jax import lax
+# private: jax 0.9 has no public reader for the legacy ``with mesh:`` context
+# this framework uses (jax.sharding.get_mesh sees only jax.set_mesh)
 from jax._src.mesh import thread_resources
 from jax.sharding import PartitionSpec as P
 

@@ -58,13 +58,23 @@ def hierarchical_axis_groups(axis_size: int, devices_per_host: int):
 
 
 def default_devices():
-    """Device list for mesh construction, via the accelerator facade so that
-    DSTPU_ACCELERATOR=cpu (the test harness) selects the virtual CPU devices
-    even when a TPU plugin owns the default backend."""
+    """Device list for mesh construction: the default backend's devices, or
+    the (virtual) CPU devices when DSTPU_ACCELERATOR=cpu asks for them on a
+    host whose default backend is the TPU."""
     import os
     if os.environ.get("DSTPU_ACCELERATOR") == "cpu":
         return jax.devices("cpu")
     return jax.devices()
+
+
+def on_tpu() -> bool:
+    """Will a computation traced now run on a TPU? The one place that asks:
+    the devices the active mesh was built from, or, outside a mesh context,
+    the devices a mesh would be built from (``default_devices``)."""
+    from .constraints import active_mesh
+    mesh = active_mesh()
+    devices = mesh.devices.flat if mesh is not None else default_devices()
+    return devices[0].platform == "tpu"
 
 
 class ProcessTopology:

@@ -42,26 +42,13 @@ from ..engine import DeepSpeedEngine, _cast_tree
 from . import schedule as sched
 from .module import PipelineModule
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:                      # pre-0.5 spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 def _pipe_shard_map(body, mesh, in_specs, out_specs):
     """shard_map manual over ONLY the 'pipe' axis, replication check off
-    (outputs are made consistent by the explicit ppermute/psum legs).
-    Spelled for both shard_map generations: ``axis_names``/``check_vma``
-    (jax >= 0.5) vs ``auto``/``check_rep`` (the experimental module this
-    jax pin ships) — the same dual-spelling compressed_step.py uses."""
-    try:
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, axis_names={PIPE_AXIS},
-                          check_vma=False)
-    except TypeError:
-        auto = frozenset(mesh.axis_names) - {PIPE_AXIS}
-        return _shard_map(body, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False, auto=auto)
+    (outputs are made consistent by the explicit ppermute/psum legs)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, axis_names={PIPE_AXIS},
+                         check_vma=False)
 
 
 class PipelineEngine(DeepSpeedEngine):

@@ -36,11 +36,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:                      # pre-0.5 spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from ... import comm
 from ...parallel.topology import DATA_AXIS
 
@@ -48,13 +43,9 @@ from ...parallel.topology import DATA_AXIS
 def _shard_map_norep(fn, mesh, in_specs, out_specs):
     """shard_map with the replication check disabled (outputs are made
     consistent by explicit collectives, which the checker cannot see
-    through on every jax version)."""
-    try:
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_rep=False)
-    except TypeError:                    # newer spelling
-        return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
+    through)."""
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _dp_dim(spec) -> int:

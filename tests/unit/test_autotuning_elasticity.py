@@ -187,8 +187,8 @@ def _shape_125m():
 
 def test_cost_model_memory_feasibility():
     """The analytic memory model must know 1.3B optimizer state does not
-    fit one chip without offload, but does WITH offload (the measured
-    reality of benchmarks/gpt2_1p3b.json)."""
+    fit one chip without offload, but does WITH offload (what
+    benchmarks/baseline_ladder.py 1p3b relies on)."""
     from deepspeed_tpu.autotuning.cost_model import (ModelShape,
                                                      estimate_memory_bytes)
     big = ModelShape(n_params=1_313_000_000, hidden=2048, n_layer=24,

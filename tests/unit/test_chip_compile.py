@@ -1,0 +1,106 @@
+"""Ahead-of-time compiles of the Pallas attention kernels for a described
+TPU v5e (no chip attached): Mosaic and the TPU compiler accept what the
+interpret-mode tests cannot see — tiling, scoped VMEM, HBM fit. Nothing
+runs; a compile that passes is not a chip run.
+
+The topology is described inside a module-scoped fixture (never at import):
+only the xdist worker that is handed this file loads the TPU library.
+"""
+
+import os
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.ops.pallas.block_sparse_attention import \
+    sparse_attention_pallas
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.flash_attention_packed import \
+    packed_flash_attention
+from deepspeed_tpu.ops.sparse_attention_ops import FixedSparsityConfig
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """Sharding on one described chip; the persistent compile cache is off
+    while this module runs (an AOT entry cannot be read back without a
+    chip, and the next compile would warn)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, one_chip, shape, dtype, grad=True):
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    step = fn
+    if grad:
+        step = jax.grad(
+            lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)),
+            argnums=(0, 1, 2))
+    compiled = jax.jit(step).lower(x, x, x).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("b,h,d", [(8, 12, 64), (8, 16, 64), (4, 32, 64),
+                                   (4, 16, 128)])
+def test_packed_fwd_bwd(one_chip, b, h, d):
+    """The GPT-2 125M / 350M / 1.3B train-step attention shapes."""
+    _compile(lambda q, k, v: packed_flash_attention(q, k, v, h),
+             one_chip, (b, 1024, h * d), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("shape,dtype", [
+    ((8, 16, 1024, 64), jnp.bfloat16),
+    ((1, 12, 4096, 64), jnp.bfloat16),
+    # exactly 1 MiB of K/V per head: the resident/streamed boundary, where
+    # the resident fused backward ran out of scoped VMEM
+    ((1, 12, 8192, 64), jnp.bfloat16),
+    ((1, 12, 32768, 64), jnp.bfloat16),
+    # the widest head supported() admits, where the streamed kernels' head
+    # fold bottoms out at 1
+    ((1, 8, 8192, 256), jnp.float32),
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else v.__name__)
+def test_flash_fwd_bwd(one_chip, shape, dtype):
+    _compile(lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
+                                             False, None),
+             one_chip, shape, dtype)
+
+
+@pytest.mark.parametrize("t", [1024, 8192])
+def test_sliding_window_fwd(one_chip, t):
+    """Resident (T=1024) and streamed (T=8192) windowed forward."""
+    _compile(lambda q, k, v: flash_attention(q, k, v, True, None, None, None,
+                                             False, 256),
+             one_chip, (1, 12, t, 64), jnp.bfloat16, grad=False)
+
+
+@pytest.mark.parametrize("t", [1024, 4096])
+def test_block_sparse_fwd_bwd(one_chip, t):
+    """The layout ops/sparse_attention_ops.py builds for a causal Fixed
+    config (local window of 4 blocks + 1 global block, block 64)."""
+    h, block = 12, 64
+    layout = FixedSparsityConfig(
+        num_heads=h, block=block, num_local_blocks=4, num_global_blocks=1,
+        attention="unidirectional").make_layout(t)
+    assert np.asarray(layout).any()
+    _compile(lambda q, k, v: sparse_attention_pallas(q, k, v, layout, block),
+             one_chip, (2, h, t, 64), jnp.bfloat16)

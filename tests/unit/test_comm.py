@@ -6,10 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import deepspeed_tpu.comm as dist
 from deepspeed_tpu.parallel import initialize_mesh

@@ -86,7 +86,7 @@ def test_ast_host_sync_only_in_traced_functions():
 
 def test_ast_host_sync_in_shard_mapped_and_wrapped_fn():
     src = (
-        "import jax\nfrom jax.experimental.shard_map import shard_map\n"
+        "import jax\nfrom jax import shard_map\n"
         "def body(x):\n"
         "    return x.sum().item()\n"
         "out = shard_map(body, mesh=None, in_specs=None, out_specs=None)\n"

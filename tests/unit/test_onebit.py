@@ -9,10 +9,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.5 spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import deepspeed_tpu
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model

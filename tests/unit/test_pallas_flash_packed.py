@@ -64,36 +64,34 @@ def test_grads_match_reference(window):
 
 
 @pytest.mark.slow
-def test_model_dispatch_matches_transpose_path(monkeypatch):
+def test_model_dispatch_matches_transpose_path():
     """GPT2Model with attn_backend='pallas' (packed path on CPU interpret)
-    == the same model with the packed path disabled."""
+    == the same weights through the [B,H,T,D] XLA attention path."""
+    import dataclasses
     from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 
     cfg = GPT2Config(vocab_size=256, n_positions=128, n_embd=256, n_layer=2,
                      n_head=4, pad_vocab_to_multiple=64,
                      attn_backend="pallas")
     model = GPT2Model(cfg)
+    plain = GPT2Model(dataclasses.replace(cfg, attn_backend="xla"))
     params = model.init(jax.random.PRNGKey(0))
     rng = np.random.default_rng(0)
     ids = jnp.asarray(rng.integers(0, 256, (2, 128)), jnp.int32)
 
-    monkeypatch.setenv("DSTPU_PACKED_ATTN", "1")
     assert model._packed_attn_ok(128, 64, 4)
-    logits_packed = model.logits(params, ids, train=False)
-    monkeypatch.setenv("DSTPU_PACKED_ATTN", "0")
-    assert not model._packed_attn_ok(128, 64, 4)
-    logits_plain = model.logits(params, ids, train=False)
-    np.testing.assert_allclose(np.asarray(logits_packed),
-                               np.asarray(logits_plain),
-                               atol=2e-4, rtol=2e-4)
+    assert not plain._packed_attn_ok(128, 64, 4)
+    np.testing.assert_allclose(
+        np.asarray(model.logits(params, ids, train=False)),
+        np.asarray(plain.logits(params, ids, train=False)),
+        atol=2e-4, rtol=2e-4)
 
     # grads agree too (the custom-vjp backward)
-    def loss(p, packed):
-        monkeypatch.setenv("DSTPU_PACKED_ATTN", "1" if packed else "0")
-        return model.apply(p, {"input_ids": ids}, train=False)
+    def loss(m):
+        return lambda p: m.apply(p, {"input_ids": ids}, train=False)
 
-    g1 = jax.grad(lambda p: loss(p, True))(params)
-    g0 = jax.grad(lambda p: loss(p, False))(params)
+    g1 = jax.grad(loss(model))(params)
+    g0 = jax.grad(loss(plain))(params)
     for a, b in zip(jax.tree.leaves(g1), jax.tree.leaves(g0)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=3e-4, rtol=3e-4)

@@ -155,7 +155,7 @@ def test_prometheus_dump_format(tracer):
 # ---------------------------------------------------------------- comm spans
 
 def test_comm_span_byte_accounting(tracer):
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     mesh = jax.sharding.Mesh(np.array(jax.devices()), ("data",))
 
     @jax.jit
