@@ -151,6 +151,7 @@ class InferenceEngine:
         # program (forward, generate bucket, prefill bucket, fused decode,
         # pool init) becomes a compile event with an arg fingerprint
         self.compile_plane = None
+        self._tracer = get_tracer()     # the slot programs' phase records
         n_params = sum(int(np.prod(s.shape))
                        for s in jax.tree.leaves(param_shapes))
         log_dist(f"InferenceEngine initialized: params={n_params/1e6:.1f}M "
@@ -726,8 +727,6 @@ class InferenceEngine:
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
         bucket = min(_next_pow2(t), max_len)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :t] = prompt
         fkey = ("slot_prefill", bucket, max_len) + \
             (("q8",) if quantized else ())
         fn = self._slot_fns.get(fkey)
@@ -735,6 +734,9 @@ class InferenceEngine:
             pool_shardings = self._pool_shardings(num_slots, max_len,
                                                   quantize=quantized)
 
+            # the module name this gives, ``jit_pf``, is how
+            # chipbench/workloads/*.json finds the prefill programs in a
+            # device trace (tests/unit/test_phases.py pins it)
             def pf(params, ids, pool, slot_idx, last_idx, temp, top_k,
                    top_p, seed):
                 mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
@@ -750,16 +752,34 @@ class InferenceEngine:
             fn = self._slot_fns[fkey] = jax.jit(pf, in_shardings=(
                 self.param_shardings, None, pool_shardings, None, None, None,
                 None, None, None), out_shardings=(pool_shardings, None))
-        pf_args = (self.params, jnp.asarray(ids), pool, jnp.int32(slot),
-                   jnp.int32(t - 1), jnp.float32(temperature),
-                   jnp.int32(top_k), jnp.float32(top_p), jnp.int32(seed))
-        self._observe_compile("slot_prefill", fn, pf_args,
-                              names=("params", "ids", "pool", "slot",
-                                     "last_idx", "temperature", "top_k",
-                                     "top_p", "seed"))
-        with self.mesh:
-            pool, tok = fn(*pf_args)
-        return pool, int(tok)
+        pool, tok = self._slot_prefill_call(
+            "slot_prefill", fn, pool, slot, prompt, bucket,
+            (np.int32(t - 1), np.float32(temperature), np.int32(top_k),
+             np.float32(top_p), np.int32(seed)),
+            ("last_idx", "temperature", "top_k", "top_p", "seed"))
+        with self._tracer.phase("serve/prefill_wait"):
+            tok = int(tok)
+        return pool, tok
+
+    def _slot_prefill_call(self, label, fn, pool, slot, tokens, bucket,
+                           scalars, names):
+        """Shared tail of the three slot prefills, as two phase records:
+        ``serve/prefill_prep`` (tokens, bucket) — right-pad ``tokens`` to
+        ``bucket``, put the ids and every scalar on the device, observe
+        the compile — then ``serve/prefill_dispatch`` (bucket), the call
+        of ``fn(params, ids, pool, slot, *scalars)``."""
+        tr = self._tracer
+        t = tokens.shape[0]
+        with tr.phase("serve/prefill_prep", t, bucket):
+            ids = np.zeros((1, bucket), np.int32)
+            ids[0, :t] = tokens
+            args = (self.params, jnp.asarray(ids), pool, jnp.int32(slot),
+                    *map(jnp.asarray, scalars))
+            self._observe_compile(label, fn, args,
+                                  names=("params", "ids", "pool", "slot")
+                                  + names)
+        with tr.phase("serve/prefill_dispatch", bucket), self.mesh:
+            return fn(*args)
 
     def slot_suffix_prefill(self, pool, slot: int, tokens, start_pos: int,
                             temperature: float = 0.0, top_k: int = 0,
@@ -788,8 +808,6 @@ class InferenceEngine:
                 f"suffix bucket [{start_pos}, {start_pos + bucket}) exceeds "
                 f"max_len={max_len}; plan the reuse offset with "
                 f"prefix_cache.reuse_plan")
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :t] = tokens
         fkey = ("slot_suffix", bucket, max_len) + \
             (("q8",) if quantized else ())
         fn = self._slot_fns.get(fkey)
@@ -811,17 +829,15 @@ class InferenceEngine:
             fn = self._slot_fns[fkey] = jax.jit(spf, in_shardings=(
                 self.param_shardings, None, pool_shardings, None, None, None,
                 None, None, None, None), out_shardings=(pool_shardings, None))
-        spf_args = (self.params, jnp.asarray(ids), pool, jnp.int32(slot),
-                    jnp.int32(start_pos), jnp.int32(t - 1),
-                    jnp.float32(temperature), jnp.int32(top_k),
-                    jnp.float32(top_p), jnp.int32(seed))
-        self._observe_compile("slot_suffix_prefill", fn, spf_args,
-                              names=("params", "ids", "pool", "slot",
-                                     "start_pos", "last_idx", "temperature",
-                                     "top_k", "top_p", "seed"))
-        with self.mesh:
-            pool, tok = fn(*spf_args)
-        return pool, int(tok)
+        pool, tok = self._slot_prefill_call(
+            "slot_suffix_prefill", fn, pool, slot, tokens, bucket,
+            (np.int32(start_pos), np.int32(t - 1), np.float32(temperature),
+             np.int32(top_k), np.float32(top_p), np.int32(seed)),
+            ("start_pos", "last_idx", "temperature", "top_k", "top_p",
+             "seed"))
+        with self._tracer.phase("serve/prefill_wait"):
+            tok = int(tok)
+        return pool, tok
 
     def slot_chunk_prefill(self, pool, slot: int, tokens, start_pos: int):
         """Write ONE CHUNK of a prompt's K/V into slot ``slot`` at cache
@@ -852,8 +868,6 @@ class InferenceEngine:
             raise ValueError(
                 f"chunk bucket [{start_pos}, {start_pos + bucket}) exceeds "
                 f"max_len={max_len}; size chunks so every bucket fits")
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :t] = tokens
         fkey = ("slot_chunk", num_slots, bucket, max_len) + \
             (("q8",) if quantized else ())
         fn = self._slot_fns.get(fkey)
@@ -870,13 +884,9 @@ class InferenceEngine:
             fn = self._slot_fns[fkey] = jax.jit(cpf, in_shardings=(
                 self.param_shardings, None, pool_shardings, None, None),
                 out_shardings=pool_shardings, donate_argnums=(2,))
-        cpf_args = (self.params, jnp.asarray(ids), pool, jnp.int32(slot),
-                    jnp.int32(start_pos))
-        self._observe_compile("slot_chunk_prefill", fn, cpf_args,
-                              names=("params", "ids", "pool", "slot",
-                                     "start_pos"))
-        with self.mesh:
-            return fn(*cpf_args)
+        return self._slot_prefill_call(
+            "slot_chunk_prefill", fn, pool, slot, tokens, bucket,
+            (np.int32(start_pos),), ("start_pos",))
 
     def slot_chunk_executables(self, num_slots: int, max_len: int,
                                bucket: int,
@@ -1006,6 +1016,9 @@ class InferenceEngine:
                                                   quantize=quantized)
             from .speculative import row_keys, sample_rows
 
+            # the module name this gives, ``jit_dec``, is how
+            # chipbench/workloads/*.json finds the decode program in a
+            # device trace (tests/unit/test_phases.py pins it)
             def dec(params, pool, toks, positions, temps, top_ks, top_ps,
                     seeds):
                 if quantized:
@@ -1038,25 +1051,30 @@ class InferenceEngine:
                 None, None),
                 out_shardings=(pool_shardings, None),
                 donate_argnums=(1,))
-        n = len(np.asarray(toks).reshape(-1))
-        if top_ks is None:
-            top_ks = np.zeros((n,), np.int32)
-        if top_ps is None:
-            top_ps = np.ones((n,), np.float32)
-        if seeds is None:
-            seeds = np.zeros((n,), np.int32)
-        dec_args = (self.params, pool, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    jnp.asarray(seeds, jnp.int32))
-        self._observe_compile("slot_decode", fn, dec_args,
-                              names=("params", "pool", "toks", "positions",
-                                     "temps", "top_ks", "top_ps", "seeds"))
-        with self.mesh:
+        tr = self._tracer
+        with tr.phase("serve/decode_prep"):
+            n = len(np.asarray(toks).reshape(-1))
+            if top_ks is None:
+                top_ks = np.zeros((n,), np.int32)
+            if top_ps is None:
+                top_ps = np.ones((n,), np.float32)
+            if seeds is None:
+                seeds = np.zeros((n,), np.int32)
+            dec_args = (self.params, pool, jnp.asarray(toks, jnp.int32),
+                        jnp.asarray(positions, jnp.int32),
+                        jnp.asarray(temps, jnp.float32),
+                        jnp.asarray(top_ks, jnp.int32),
+                        jnp.asarray(top_ps, jnp.float32),
+                        jnp.asarray(seeds, jnp.int32))
+            self._observe_compile("slot_decode", fn, dec_args,
+                                  names=("params", "pool", "toks",
+                                         "positions", "temps", "top_ks",
+                                         "top_ps", "seeds"))
+        with tr.phase("serve/decode_dispatch"), self.mesh:
             pool, nxt = fn(*dec_args)
-        return pool, np.asarray(nxt)
+        with tr.phase("serve/decode_wait"):
+            nxt = np.asarray(nxt)
+        return pool, nxt
 
     def slot_decode_executables(self, num_slots: int, max_len: int,
                                 quantized: Optional[bool] = None) -> int:

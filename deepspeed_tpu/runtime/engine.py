@@ -404,6 +404,7 @@ class DeepSpeedEngine:
         # structured tracer (telemetry/): fwd/bwd/step spans, comm spans,
         # MFU + recompile-watchdog counters; disabled = zero-cost no-ops
         self.tracer = configure_tracer(cfg.telemetry)
+        self.tracer.watch_gc(self)      # until close()
         # goodput ledger (telemetry/goodput.py): wall-clock bucket
         # accounting — productive step vs compile/recompile/checkpoint/
         # sentinel/preemption/data-wait badput; rides telemetry.enabled
@@ -1184,7 +1185,14 @@ class DeepSpeedEngine:
     # fused path: train_batch (the PipelineEngine-compatible entrypoint)
     # ------------------------------------------------------------------
     def train_batch(self, data_iter=None, batch=None):
-        """Run one full global step (gas × micro) as one compiled program."""
+        """Run one full global step (gas × micro) as one compiled program.
+        The step's host time is on the tracer's phase ring: ``train/step``
+        whole, and inside it ``train/input``, ``train/dispatch``,
+        ``train/readback`` and ``train/post``."""
+        with self.tracer.phase("train/step", self.global_steps):
+            return self._train_batch(data_iter, batch)
+
+    def _train_batch(self, data_iter, batch):
         assert self.optimizer is not None
         cfg = self._config
         self._check_preemption()
@@ -1205,9 +1213,13 @@ class DeepSpeedEngine:
                 # deterministic slow-step injection: sleep well past the
                 # k×EMA trigger whatever this machine's step time is
                 time.sleep(0.05 + 5.0 * rec.ema_ms / 1e3)
-        if batch is None:
-            batch = self._next_gas_batch(data_iter)
-        batch = self._apply_curriculum(batch)
+        tr = self.tracer
+        with tr.phase("train/input"):
+            if batch is None:
+                batch = self._next_gas_batch(data_iter)
+            batch = self._apply_curriculum(batch)
+            if self._param_runner is None:
+                batch = self._to_device_batch(batch)
         if self._param_runner is not None:
             self.tput_timer.start()
             g_iv = self._ledger.track("productive_step")
@@ -1221,7 +1233,6 @@ class DeepSpeedEngine:
             self._post_step(metrics)
             self.tput_timer.stop(global_step=True)
             return metrics["loss"]
-        batch = self._to_device_batch(batch)
         self.tput_timer.start()
         rng = jax.random.fold_in(self._base_rng, self.global_steps)
         self._maybe_profile_flops(batch, rng)
@@ -1229,9 +1240,9 @@ class DeepSpeedEngine:
         loss_mul = self._loss_mul()
         if self.eigenvalue is not None:
             self._last_eig_batch = (jax.tree.map(lambda x: x[0], batch), rng)
-        tr = self.tracer
         step_span = tr.span("train_batch", cat="train",
-                            args={"step": self.global_steps})
+                            args={"step": self.global_steps}
+                            if tr.enabled else None)
         g_iv = self._ledger.track("productive_step")
         fn = None
         cp_ev = None      # pending compile-ledger event (compile plane)
@@ -1275,16 +1286,16 @@ class DeepSpeedEngine:
                 self._maybe_telemetry_flops(
                     fn, (self.params, self.opt_state, self.scaler_state,
                          batch, lr, rng, theta, loss_mul))
-                cp_ev = self._observe_compile(
-                    "train_batch", fn,
-                    (self.params, self.opt_state, self.scaler_state, batch,
-                     lr, rng, theta, loss_mul),
-                    names=("params", "opt_state", "scaler_state", "batch",
-                           "lr", "rng", "pld_theta", "loss_mul"),
-                    donated=(0, 1, 2))
-                t_cp = time.perf_counter() if cp_ev is not None else 0.0
-                with tr.span("dispatch", cat="train"):
-                    with self.mesh:
+                with tr.phase("train/dispatch"):
+                    cp_ev = self._observe_compile(
+                        "train_batch", fn,
+                        (self.params, self.opt_state, self.scaler_state,
+                         batch, lr, rng, theta, loss_mul),
+                        names=("params", "opt_state", "scaler_state",
+                               "batch", "lr", "rng", "pld_theta", "loss_mul"),
+                        donated=(0, 1, 2))
+                    t_cp = time.perf_counter() if cp_ev is not None else 0.0
+                    with tr.span("dispatch", cat="train"), self.mesh:
                         (self.params, self.opt_state, self.scaler_state,
                          metrics) = fn(self.params, self.opt_state,
                                        self.scaler_state, batch, lr, rng,
@@ -1307,7 +1318,8 @@ class DeepSpeedEngine:
         # the first sight is read BEFORE _telemetry_step_end registers fn
         first_sight = fn is not None and not self._watchdog.seen(fn)
         rc_before = self._watchdog.recompiles
-        self._telemetry_step_end(fn, step_span)
+        with tr.phase("train/post"):
+            self._telemetry_step_end(fn, step_span)
         if fn is not None and not tr.enabled and \
                 (rec is not None or self._hostagg is not None):
             # the watchdog normally rides _telemetry_step_end; keep the
@@ -1772,7 +1784,14 @@ class DeepSpeedEngine:
                      f"{self.global_steps}; recompiling", ranks=[0])
             self._compile_fns()
         self.global_samples += self._config.train_batch_size
-        overflow = bool(metrics.get("overflow", False))
+        # the step's first host read of its outputs: the host waits here
+        # for the device
+        with self.tracer.phase("train/readback"):
+            overflow = bool(metrics.get("overflow", False))
+        with self.tracer.phase("train/post"):
+            self._post_step_books(metrics, overflow)
+
+    def _post_step_books(self, metrics, overflow):
         sentinel_action = self._observe_sentinel(metrics)
         if sentinel_action in ("skip", "rollback") and \
                 self._ledger_step_iv is not None:
@@ -1899,6 +1918,7 @@ class DeepSpeedEngine:
         if self._recorder is not None:
             self._recorder.close()
         self.tracer.release_counters(self)
+        self.tracer.unwatch_gc(self)
         if release_ledger:
             from ..telemetry.goodput import configure_ledger
             configure_ledger(enabled=False)

@@ -64,6 +64,7 @@ class ServingEngine:
         from ..telemetry.trace import configure_tracer
         self.tracer = configure_tracer(config.telemetry) \
             if config.telemetry is not None else configure_tracer()
+        self.tracer.watch_gc(self)      # until shutdown()
         from ..telemetry.goodput import configure_ledger, get_ledger
         tcfg = config.telemetry
         if tcfg is not None:
@@ -341,14 +342,18 @@ class ServingEngine:
         rec = self._recorder
         t0 = time.perf_counter() if rec is not None else 0.0
         bucket = "serving_drain" if self._draining else "serving_step"
-        with self._ledger.track(bucket):
-            in_flight = self.scheduler.tick()
-        self.metrics.flush()
-        if self._hbm is not None and \
-                self.metrics.ticks % self._hbm_interval == 0:
-            self._update_hbm()
-        if rec is not None:
-            self._flight_record((time.perf_counter() - t0) * 1e3)
+        tr = self.tracer
+        with tr.phase("serve/tick") as tick:
+            with self._ledger.track(bucket):
+                in_flight = self.scheduler.tick()
+            with tr.phase("serve/bookkeeping"):
+                self.metrics.flush()
+                if self._hbm is not None and \
+                        self.metrics.ticks % self._hbm_interval == 0:
+                    self._update_hbm()
+                if rec is not None:
+                    self._flight_record((time.perf_counter() - t0) * 1e3)
+            tick.a, tick.b = self.metrics.ticks, self.active_requests
         return in_flight
 
     def _update_hbm(self):
@@ -522,6 +527,7 @@ class ServingEngine:
             self.engine.compile_plane = None   # detach from the shared
                                                # InferenceEngine
         self.tracer.release_counters(self)
+        self.tracer.unwatch_gc(self)
 
     def _traces_in_flight(self):
         """Trace ids of every request still moving through THIS replica

@@ -135,6 +135,9 @@ class Request:
     #: tick number of this request's last chunk (a freshly admitted
     #: chunked request must not take a second chunk in the same tick)
     prefill_tick: int = -1
+    #: ``perf_counter_ns`` at enqueue: where this request's
+    #: ``serve/queue_wait`` phase record starts (never the injectable clock)
+    enqueue_ns: int = 0
 
     @property
     def tenant(self) -> str:
@@ -382,6 +385,7 @@ class ContinuousBatchingScheduler:
                 f"retry with backoff")
         now = self.clock()
         request.submit_time = now
+        request.enqueue_ns = time.perf_counter_ns()
         timeout = (request.sampling.timeout_s
                    if request.sampling.timeout_s is not None
                    else self.config.request_timeout_s)
@@ -454,27 +458,30 @@ class ContinuousBatchingScheduler:
         never the prompt length."""
         self._tick_no += 1
         now = self.clock()
-        self._expire(now)
-        self._admit_handoffs(now)
-        budget = (self.chunked.chunk_tokens if self.chunked is not None
-                  else None)
-        budget = self._admit(now, budget)
+        tr = self.tracer
+        with tr.phase("serve/admit", 0, len(self.queue)) as admit:
+            self._expire(now)
+            self._admit_handoffs(now)
+            budget = (self.chunked.chunk_tokens if self.chunked is not None
+                      else None)
+            budget, admit.a = self._admit(now, budget)
         self._advance_prefills(now, budget)
         self._decode()
-        self.metrics.record_tick(len(self.queue), self.pool.utilization)
-        if self.prefix_cache is not None:
-            self.metrics.record_prefix_cache(self.prefix_cache)
-        if self.cost is not None:
-            # close the tick's books: HBM residency for every occupied
-            # slot (decoding or mid-chunked-prefill), then the overhead
-            # residual — tick wall minus everything attributed above —
-            # so per-request costs + overhead sum to serving wall-clock
-            # by construction
-            occupants = [self.cost.record_for(self.pool.requests[s])
-                         for s in self.pool.active_slots]
-            occupants += [self.cost.record_for(r)
-                          for r in self.prefilling.values()]
-            self.cost.end_tick(self.clock() - now, occupants)
+        with tr.phase("serve/bookkeeping"):
+            self.metrics.record_tick(len(self.queue), self.pool.utilization)
+            if self.prefix_cache is not None:
+                self.metrics.record_prefix_cache(self.prefix_cache)
+            if self.cost is not None:
+                # close the tick's books: HBM residency for every occupied
+                # slot (decoding or mid-chunked-prefill), then the overhead
+                # residual — tick wall minus everything attributed above —
+                # so per-request costs + overhead sum to serving wall-clock
+                # by construction
+                occupants = [self.cost.record_for(self.pool.requests[s])
+                             for s in self.pool.active_slots]
+                occupants += [self.cost.record_for(r)
+                              for r in self.prefilling.values()]
+                self.cost.end_tick(self.clock() - now, occupants)
         return (len(self.queue) + len(self.handoff_queue) +
                 len(self.pool.active_slots) + len(self.prefilling))
 
@@ -649,7 +656,8 @@ class ContinuousBatchingScheduler:
         ``handoff_sink`` instead of binding for decode. ``budget``
         (chunked mode) is the tick's prefill-token budget; each
         admission spends its actual prefill work against it, and the
-        remainder is returned for the in-flight chunk advance.
+        remainder is returned for the in-flight chunk advance, beside the
+        number of requests admitted.
         Admissions run BEFORE the chunk advance so a DRR-favored small
         tenant's TTFT is one tick, not one whale prefill."""
         admitted = 0
@@ -658,11 +666,14 @@ class ContinuousBatchingScheduler:
                 and (budget is None or budget > 0):
             slot = self._alloc_slot()
             if slot is None:
-                return budget
+                return budget, admitted
             req = self._pop_live(now)
             if req is None:
                 self.pool.free(slot)
-                return budget
+                return budget, admitted
+            tr.record_phase("serve/queue_wait", req.enqueue_ns,
+                            time.perf_counter_ns(), req.request_id,
+                            int(req.prompt.size))
             ctx = req.trace
             if ctx is not None:
                 ctx.mark("admitted")
@@ -690,7 +701,7 @@ class ContinuousBatchingScheduler:
             if budget is not None:
                 budget -= spent
             admitted += 1
-        return budget
+        return budget, admitted
 
     def _start_chunked(self, slot: int, req: Request, hit) -> int:
         """Begin a chunked admission: optional prefix-reuse lane copy,
@@ -795,33 +806,34 @@ class ContinuousBatchingScheduler:
         """Shared tail of every prefill path (inline or final chunk):
         record TTFT, deliver the first token, then bind for decode /
         hand off / finish."""
-        ctx = req.trace
-        if ctx is not None:
-            ctx.mark("first_token")
-        t_first = self.clock()
-        req.state = RequestState.RUNNING
-        req.first_token_time = t_first
-        self.metrics.record_ttft(t_first - req.submit_time,
-                                 tenant=req.tenant)
-        if self.cost is not None:
-            # the first token is sampled BY the prefill: its cost is in
-            # the prefill charge, but it still counts as an emitted
-            # token, so tokens-per-chip-second sees every token
-            rec = self.cost.record_for(req)
-            rec.tokens += 1
-            self.cost._tenant(rec.tenant).tokens += 1
-        self._deliver(req, first)
-        if self._should_finish(req, first):
-            self._finish(req, RequestState.FINISHED, t_first)
-            self._release_slot(slot, req)
-        elif self.role == "prefill":
-            self._hand_off(slot, req, first)
-        else:
-            self.pool.bind(slot, req, len(req.prompt), first,
-                           req.sampling)
-            if self.spec is not None:
-                self.draft_cache = self.engine.draft_prefill(
-                    self.draft, self.draft_cache, slot, req.prompt)
+        with self.tracer.phase("serve/first_token", req.request_id):
+            ctx = req.trace
+            if ctx is not None:
+                ctx.mark("first_token")
+            t_first = self.clock()
+            req.state = RequestState.RUNNING
+            req.first_token_time = t_first
+            self.metrics.record_ttft(t_first - req.submit_time,
+                                     tenant=req.tenant)
+            if self.cost is not None:
+                # the first token is sampled BY the prefill: its cost is in
+                # the prefill charge, but it still counts as an emitted
+                # token, so tokens-per-chip-second sees every token
+                rec = self.cost.record_for(req)
+                rec.tokens += 1
+                self.cost._tenant(rec.tenant).tokens += 1
+            self._deliver(req, first)
+            if self._should_finish(req, first):
+                self._finish(req, RequestState.FINISHED, t_first)
+                self._release_slot(slot, req)
+            elif self.role == "prefill":
+                self._hand_off(slot, req, first)
+            else:
+                self.pool.bind(slot, req, len(req.prompt), first,
+                               req.sampling)
+                if self.spec is not None:
+                    self.draft_cache = self.engine.draft_prefill(
+                        self.draft, self.draft_cache, slot, req.prompt)
 
     def _prefill_into(self, slot: int, req: Request, hit) -> int:
         """Full prefill, or the prefix-reuse fast path when the radix
@@ -877,7 +889,8 @@ class ContinuousBatchingScheduler:
                            "prompt_len": int(req.prompt.size),
                            "replica": self.replica_name,
                            **(req.trace.span_args()
-                              if req.trace is not None else {})}):
+                              if req.trace is not None else {})}
+                     if tr.enabled else None):
             # slot_prefill returns the first token as a python int —
             # already device-synced, so the span duration is honest
             self.pool.cache, first = self.engine.slot_prefill(
@@ -944,13 +957,15 @@ class ContinuousBatchingScheduler:
             return
         if self.spec is not None:
             return self._decode_speculative(active)
-        toks, positions, temps, top_ks, top_ps, seeds = \
-            self.pool.decode_arrays()
+        tr = self.tracer
+        with tr.phase("serve/decode_prep", len(active)):
+            toks, positions, temps, top_ks, top_ps, seeds = \
+                self.pool.decode_arrays()
         t0 = self.clock()
-        with self.tracer.span("decode_step", cat="serving",
-                              args={"n_active": len(active),
-                                    "tick": self._tick_no,
-                                    "replica": self.replica_name}):
+        with tr.span("decode_step", cat="serving",
+                     args={"n_active": len(active), "tick": self._tick_no,
+                           "replica": self.replica_name}
+                     if tr.enabled else None):
             # slot_decode_step returns host ndarrays (already synced)
             self.pool.cache, nxt = self.engine.slot_decode_step(
                 self.pool.cache, toks, positions, temps,
@@ -965,21 +980,24 @@ class ContinuousBatchingScheduler:
                 dt, [(self.cost.record_for(self.pool.requests[s]), 1)
                      for s in active])
         now = self.clock()
-        for slot in active:
-            req = self.pool.requests[slot]
-            tok = int(nxt[slot])
-            self.pool.lengths[slot] += 1      # fed token's K/V is in cache
-            self.pool.pending[slot] = tok
-            finishing = self._should_finish(req, tok, pending=1)
-            if finishing and req.trace is not None:
-                # the token loop ends here; what follows (final delivery,
-                # bookkeeping) is the critical path's "stream" tail
-                req.trace.mark("decode_done")
-            self._deliver(req, tok)
-            self.metrics.record_tenant_tokens(req.tenant)
-            if finishing:
-                self._finish(req, RequestState.FINISHED, now)
-                self._release_slot(slot, req)
+        with tr.phase("serve/deliver", len(active)) as deliver:
+            for slot in active:
+                req = self.pool.requests[slot]
+                tok = int(nxt[slot])
+                self.pool.lengths[slot] += 1  # fed token's K/V is in cache
+                self.pool.pending[slot] = tok
+                finishing = self._should_finish(req, tok, pending=1)
+                if finishing and req.trace is not None:
+                    # the token loop ends here; what follows (final
+                    # delivery, bookkeeping) is the critical path's
+                    # "stream" tail
+                    req.trace.mark("decode_done")
+                self._deliver(req, tok)
+                self.metrics.record_tenant_tokens(req.tenant)
+                if finishing:
+                    self._finish(req, RequestState.FINISHED, now)
+                    self._release_slot(slot, req)
+                    deliver.b += 1
 
     def _decode_speculative(self, active):
         """One speculative tick: the draft proposes k tokens per slot

@@ -34,9 +34,8 @@ from .disttrace import (TraceContext, FleetAggregator, merge_chrome_traces,
 from .scorecard import (SCORECARD_KIND, INVARIANTS, check_invariants,
                         fold_scorecard, diff_scorecards, write_scorecard)
 from .perfplane import (ANATOMY_KIND, PerfPlane, anatomy_from_hlo,
-                        measured_anatomy_from_trace, reconcile_anatomy,
-                        diff_anatomy, check_anatomy_invariants,
-                        write_anatomy)
+                        reconcile_anatomy, diff_anatomy,
+                        check_anatomy_invariants, write_anatomy)
 
 __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
            "configure_tracer", "chrome_trace", "write_chrome_trace",
@@ -52,5 +51,5 @@ __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
            "SCORECARD_KIND", "INVARIANTS", "check_invariants",
            "fold_scorecard", "diff_scorecards", "write_scorecard",
            "ANATOMY_KIND", "PerfPlane", "anatomy_from_hlo",
-           "measured_anatomy_from_trace", "reconcile_anatomy",
-           "diff_anatomy", "check_anatomy_invariants", "write_anatomy"]
+           "reconcile_anatomy", "diff_anatomy", "check_anatomy_invariants",
+           "write_anatomy"]

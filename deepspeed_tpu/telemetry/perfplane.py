@@ -8,26 +8,23 @@ time**: every step/tick decomposes into named buckets — ``attn``,
 ``kv_read`` / ``kv_write`` (the KV-pool traffic ROADMAP item 2's paged
 pool must beat), ``sample`` / ``verify`` (the decode tail), ``embed`` /
 ``head``, ``moe``, one ``coll_<op>`` bucket per collective kind, and
-``other`` — with **two backends**:
+``other`` — by a stdlib-only per-op walk of the compiled HLO text
+(:func:`anatomy_from_hlo`). Each instruction is classified by the
+``jax.named_scope`` tokens XLA preserves in its ``op_name`` metadata
+(the same scopes the flops profiler reads from jaxprs), priced under
+an alpha-beta device model (compute = max(flops/peak, bytes/hbm_bw);
+collectives = bytes/link_bw + latency, discounted by the module's
+dependency-level ``static_overlap_fraction`` — so *de-overlapping a
+schedule inflates the exposed ``coll_*`` ms even on CPU*). Runs in
+tier-1 with no backend. Measured device time is not read here: the
+reduction of a profiler trace is ``chipbench/trace.py`` (busy time as a
+union of intervals, self time by operation).
 
-- the **static path** (:func:`anatomy_from_hlo`): a stdlib-only per-op
-  walk of the compiled HLO text. Each instruction is classified by the
-  ``jax.named_scope`` tokens XLA preserves in its ``op_name`` metadata
-  (the same scopes the flops profiler reads from jaxprs), priced under
-  an alpha-beta device model (compute = max(flops/peak, bytes/hbm_bw);
-  collectives = bytes/link_bw + latency, discounted by the module's
-  dependency-level ``static_overlap_fraction`` — so *de-overlapping a
-  schedule inflates the exposed ``coll_*`` ms even on CPU*). Runs in
-  tier-1 with no backend.
-- the **measured path** (:func:`measured_anatomy_from_trace`): the same
-  bucket taxonomy over a ``jax.profiler`` device trace ("XLA Ops" lane
-  durations), plus the ``host_gap`` bucket (wall window minus device
-  busy) the static path cannot see.
-
-:func:`reconcile_anatomy` joins the two into a roofline report: per
-bucket arithmetic intensity, memory-bound flag against the device
-ridge, and predicted-vs-measured skew — the number STANDING CHIP DEBT
-says to calibrate on hardware (ROADMAP item 5).
+:func:`reconcile_anatomy` is the roofline report: per bucket
+arithmetic intensity, memory-bound flag against the device ridge, and
+— against a measured ``{"buckets_ms": ...}`` dict a caller supplies —
+predicted-vs-measured skew, the number STANDING CHIP DEBT says to
+calibrate on hardware (ROADMAP item 5).
 
 Sums are exact **by construction**: a program's ``total_ms`` is
 *defined* as the float sum of its bucket ms values in sorted bucket
@@ -49,8 +46,6 @@ with zero third-party deps — ``hlo_cost.py`` is pulled in by file path
 when the package is not importable, the ``ds_tpu_soakdiff`` pattern.
 """
 
-import glob
-import gzip
 import json
 import math
 import os
@@ -76,7 +71,7 @@ except ImportError:      # file-path load (bin/ds_tpu_perfdiff, stdlib-only)
     collect_schedule_overlap = _hc.collect_schedule_overlap
 
 __all__ = ["ANATOMY_KIND", "PHASE_BUCKETS", "DEVICE_MODEL",
-           "anatomy_from_hlo", "measured_anatomy_from_trace",
+           "anatomy_from_hlo",
            "reconcile_anatomy", "diff_anatomy", "format_diff",
            "check_anatomy_invariants", "write_anatomy", "PerfPlane"]
 
@@ -388,57 +383,6 @@ def anatomy_from_hlo(hlo_text: str,
             round(membound / total_ms, 6) if total_ms > 0 else 0.0,
         "device_model": dm,
     }
-
-
-# ---------------------------------------------------------------------------
-# measured path (jax.profiler device traces)
-# ---------------------------------------------------------------------------
-
-def measured_anatomy_from_trace(trace_dir: str) -> Optional[Dict[str, Any]]:
-    """Bucket the device time of a ``jax.profiler`` trace directory with
-    the SAME taxonomy as the static path, plus ``host_gap`` = wall
-    window minus device-busy time. Returns None when no trace files are
-    found. Multi-phase events (a fusion whose name carries two scopes)
-    go to the highest-precedence phase — consistent with the static
-    fusion rule."""
-    files = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                      recursive=True)
-    if not files:
-        return None
-    buckets: Dict[str, float] = {}
-    t_min, t_max, busy = None, None, 0.0
-    for path in sorted(files):
-        with gzip.open(path, "rt") as f:
-            doc = json.load(f)
-        events = doc.get("traceEvents", [])
-        xla_tids = set()
-        for e in events:
-            if e.get("ph") == "M" and e.get("name") == "thread_name" and \
-                    "XLA Ops" in str((e.get("args") or {}).get("name", "")):
-                xla_tids.add((e.get("pid"), e.get("tid")))
-        for e in events:
-            if e.get("ph") != "X" or \
-                    (e.get("pid"), e.get("tid")) not in xla_tids:
-                continue
-            dur = float(e.get("dur", 0.0))
-            ts = float(e.get("ts", 0.0))
-            t_min = ts if t_min is None else min(t_min, ts)
-            t_max = ts + dur if t_max is None else max(t_max, ts + dur)
-            busy += dur
-            text = str(e.get("name", "")) + " " + " ".join(
-                str(v) for v in (e.get("args") or {}).values())
-            coll = next((c for c in COLLECTIVES if c in text), None)
-            if coll is not None:
-                name = f"coll_{coll.replace('-', '_')}"
-            else:
-                name = _classify_scope(text) or "other"
-            buckets[name] = buckets.get(name, 0.0) + dur
-    wall = (t_max - t_min) if t_min is not None else 0.0
-    out = {name: round(us / 1e3, 6) for name, us in buckets.items()}
-    out["host_gap"] = round(max(0.0, wall - busy) / 1e3, 6)
-    total = float(sum(out[name] for name in sorted(out)))
-    return {"buckets_ms": out, "total_ms": total,
-            "wall_ms": round(wall / 1e3, 6)}
 
 
 def reconcile_anatomy(static: Dict[str, Any],

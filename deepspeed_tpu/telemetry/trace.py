@@ -8,7 +8,7 @@ explicit sync points: ``sp.sync_on(outputs)`` blocks on the step's outputs
 before the end timestamp is taken (the CUDA-event analogue of
 utils/timer.py's ``stop(sync=True)``).
 
-Three record kinds:
+Four record kinds:
 
 - complete spans (``ph='X'``): nested host intervals — fwd/bwd/step,
   dispatch, prefill, decode ticks. Nesting is depth-tracked per thread.
@@ -21,24 +21,32 @@ Three record kinds:
   dump see it all. Monitor-EVENT fan-out stays per-producer: the engine
   and ``ServingMetrics`` buffer their own ``(tag, value, step)`` batches
   for ``MonitorMaster.write_events`` (a shared event queue would let two
-  engines in one process drain each other's events); ``emit()`` +
-  ``drain_events()`` remain as a single-consumer pipeline for scripts.
+  engines in one process drain each other's events).
+- phase records: ``(name, t0_ns, t1_ns, a, b)`` tuples — where a serving
+  tick and a train step spend their host time (``serve/...``,
+  ``train/...``, ``gc``), in a fixed ring of their own. ALWAYS recorded,
+  whatever ``enabled`` says, so a running process can be asked
+  (``get_tracer().phases()``) without a restart; while a ``jax.profiler``
+  trace is being taken each is also a ``dstpu/<name>`` annotation on the
+  profiler's clock.
 
 Disabled is the default and costs nothing: ``span()`` returns a shared
 no-op singleton — no ``Span`` object is ever allocated (asserted by
-tests/unit/test_telemetry.py). Counters stay live regardless, since the
-monitor pipeline must work without tracing.
+tests/unit/test_telemetry.py). Counters and phase records stay live
+regardless, since the monitor pipeline and the question "what was the
+host doing in that gap" must work without tracing.
 
 Exporters (Chrome trace JSON for Perfetto, metrics snapshot, Prometheus
 text) live in telemetry/export.py; the ``MonitorMaster`` sink in
 telemetry/monitor_sink.py.
 """
 
+import gc
+import itertools
 import os
 import threading
 import time
-from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
            "configure_tracer"]
@@ -140,11 +148,47 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+#: one phase record: (name, t0_ns, t1_ns, a, b) — ``perf_counter_ns``
+#: stamps and two ints whose meaning belongs to the name
+PhaseRecord = Tuple[str, int, int, int, int]
+
+
+class _Phase:
+    """An open phase: what ``Tracer.phase`` hands out. ``a`` and ``b`` may
+    be set until exit (a tick learns how many slots it left active only
+    at its end). Lives for the ``with`` block; the ring keeps the tuple."""
+
+    __slots__ = ("_tracer", "name", "a", "b", "_t0", "_annotation")
+
+    def __init__(self, tracer, name: str, a: int, b: int):
+        self._tracer = tracer
+        self.name = name
+        self.a = a
+        self.b = b
+
+    def __enter__(self):
+        cls = self._tracer._profiler_annotation()
+        self._annotation = None
+        if cls is not None:
+            self._annotation = cls("dstpu/" + self.name)
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._tracer.record_phase(self.name, self._t0,
+                                  time.perf_counter_ns(), self.a, self.b)
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
 
 class Tracer:
-    """Per-process structured tracer: span ring buffer + counter pipeline."""
+    """Per-process structured tracer: span ring buffer, counter pipeline
+    and the always-on ring of phase records."""
 
-    def __init__(self, buffer_size: int = 65536, enabled: bool = False):
+    def __init__(self, buffer_size: int = 65536, enabled: bool = False,
+                 phase_buffer_size: int = 32768):
         self.enabled = enabled
         self.sync_spans = True
         self._cap = max(16, int(buffer_size))
@@ -161,7 +205,19 @@ class Tracer:
         # wins: a tag two co-resident engines both write belongs to
         # whichever wrote it last, and only that one's close() removes it.
         self._counter_owners: Dict[str, int] = {}
-        self._pending: "deque" = deque(maxlen=8192)
+        # phase records: a ring apart from the spans', written whether or
+        # not ``enabled``. ``next()`` on the count hands every record its
+        # own slot in one step that neither another thread nor a gc
+        # callback firing between two bytecodes can split, so the hot
+        # path takes no lock.
+        self._phase_cap = max(16, int(phase_buffer_size))
+        self._phase_ring: List[Optional[PhaseRecord]] = \
+            [None] * self._phase_cap
+        self._phase_seq = itertools.count()
+        self._phase_total = 0
+        self._annotation_cls = None     # jax.profiler.TraceAnnotation, lazily
+        self._gc_owners: set = set()
+        self._gc_t0 = 0
 
     # ------------------------------------------------------------ configure
     def configure(self, config=None, **overrides):
@@ -263,23 +319,83 @@ class Tracer:
         """Spans overwritten by ring wraparound."""
         return max(0, self._total - self._cap)
 
-    # -------------------------------------------------------------- counters
-    def emit(self, tag: str, value: float, step: Optional[int] = None):
-        """Update the gauge AND queue a monitor event on the process-global
-        pipeline — a convenience for scripts with ONE drain_events()
-        consumer. Library producers (the engine, ServingMetrics) use
-        ``set_counter`` plus their own event buffers instead, so
-        co-resident producers can't steal each other's events. Works with
-        tracing disabled (gauges must not depend on span recording)."""
-        self._counters[tag] = (value, step)
-        self._pending.append((tag, value, 0 if step is None else step))
+    # ---------------------------------------------------------------- phases
+    def phase(self, name: str, a: int = 0, b: int = 0) -> _Phase:
+        """Open a nested host phase: ``with tracer.phase("serve/admit") as
+        ph: ...; ph.a = admitted``. Recorded whether or not ``enabled``.
+        Phases nest by their stamps alone — a reader gives each moment to
+        the innermost phase open then — and one phase may be recorded in
+        several pieces of the same name, which a reader adds up."""
+        return _Phase(self, name, a, b)
 
+    def record_phase(self, name: str, t0_ns: int, t1_ns: int,
+                     a: int = 0, b: int = 0):
+        """Record a finished interval from two ``time.perf_counter_ns()``
+        stamps — the plain call for intervals that outlive a stack frame
+        (a request's wait in the queue)."""
+        i = next(self._phase_seq)
+        self._phase_ring[i % self._phase_cap] = (name, t0_ns, t1_ns, a, b)
+        # last writer wins: a racing writer can leave this one low until
+        # the next record, never high
+        self._phase_total = i + 1
+
+    def phases(self) -> List[PhaseRecord]:
+        """The recorded phases, oldest first by their end stamp (at most
+        ``phase_buffer_size``; older ones are overwritten)."""
+        recs = [r for r in list(self._phase_ring) if r is not None]
+        recs.sort(key=lambda r: r[2])
+        return recs
+
+    @property
+    def phases_total(self) -> int:
+        """Phase records ever written (since the last ``clear``)."""
+        return self._phase_total
+
+    @property
+    def phases_dropped(self) -> int:
+        """Phase records overwritten by ring wraparound."""
+        return max(0, self._phase_total - self._phase_cap)
+
+    def _profiler_annotation(self):
+        """``jax.profiler.TraceAnnotation`` while a profiler trace is being
+        taken (one flag check), else ``None``."""
+        cls = self._annotation_cls
+        if cls is None:
+            try:
+                from jax.profiler import TraceAnnotation as cls
+            except ImportError:
+                cls = False
+            self._annotation_cls = cls
+        return cls if cls and cls.is_enabled() else None
+
+    def watch_gc(self, owner: Any):
+        """Record garbage collections as ``gc`` phases (generation, objects
+        collected) while any ``owner`` — an engine — is open: every
+        generation-2 collection, and any that took over 1 ms."""
+        if not self._gc_owners:
+            gc.callbacks.append(self._on_gc)
+        self._gc_owners.add(id(owner))
+
+    def unwatch_gc(self, owner: Any):
+        self._gc_owners.discard(id(owner))
+        if not self._gc_owners and self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
+
+    def _on_gc(self, when: str, info: Dict[str, int]):
+        if when == "start":
+            self._gc_t0 = time.perf_counter_ns()
+            return
+        t1 = time.perf_counter_ns()
+        if info["generation"] == 2 or t1 - self._gc_t0 > 1_000_000:
+            self.record_phase("gc", self._gc_t0, t1, info["generation"],
+                              info["collected"])
+
+    # -------------------------------------------------------------- counters
     def set_counter(self, tag: str, value: float, step: Optional[int] = None,
                     owner: Any = None):
-        """Gauge-only update (no queued monitor event) — what the engines
-        and the TelemetryMonitor sink use (the sink re-queueing events
-        would loop the pipeline back into itself). ``owner`` ties the tag
-        to a closable producer for ``release_counters``."""
+        """Latest-value gauge update; works with tracing disabled (gauges
+        must not depend on span recording). ``owner`` ties the tag to a
+        closable producer for ``release_counters``."""
         self._counters[tag] = (value, step)
         if owner is not None:
             self._counter_owners[tag] = id(owner)
@@ -307,12 +423,6 @@ class Tracer:
         val = self._counters.get(tag)
         return val[0] if val is not None else default
 
-    def drain_events(self):
-        """Take all pending (tag, value, step) monitor events."""
-        out = list(self._pending)
-        self._pending.clear()
-        return out
-
     # ------------------------------------------------------------------ misc
     def clear(self):
         with self._lock:
@@ -321,7 +431,9 @@ class Tracer:
             self._total = 0
         self._counters.clear()
         self._counter_owners.clear()
-        self._pending.clear()
+        self._phase_ring = [None] * self._phase_cap
+        self._phase_seq = itertools.count()
+        self._phase_total = 0
 
 
 class RecompileWatchdog:

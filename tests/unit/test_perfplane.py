@@ -17,7 +17,6 @@ itself, pins with --update-baseline, and rejects non-anatomy docs.
 """
 
 import copy
-import gzip
 import json
 import os
 import subprocess
@@ -131,43 +130,6 @@ def test_roofline_reconciliation():
     attn = next(r for r in rows if r["bucket"] == "attn")
     assert attn["measured_ms"] > 0
     assert attn["skew"] == pytest.approx(0.5, rel=1e-2)
-
-
-def test_measured_anatomy_from_synthetic_trace(tmp_path):
-    """The measured path buckets a jax.profiler trace ("XLA Ops" lane)
-    with the same taxonomy; host_gap is the wall window not covered by
-    device-busy time."""
-    events = [
-        {"ph": "M", "pid": 1, "tid": 7, "name": "thread_name",
-         "args": {"name": "/device:TPU:0 XLA Ops"}},
-        {"ph": "M", "pid": 1, "tid": 9, "name": "thread_name",
-         "args": {"name": "python host"}},
-        # 2ms attention fusion, 1ms all-gather, then a 1ms gap to the
-        # 0.5ms mlp op -> host_gap 1ms
-        {"ph": "X", "pid": 1, "tid": 7, "ts": 0.0, "dur": 2000.0,
-         "name": "fusion.1", "args": {"long_name": "transformer/attn/qk"}},
-        {"ph": "X", "pid": 1, "tid": 7, "ts": 2000.0, "dur": 1000.0,
-         "name": "all-gather.3", "args": {}},
-        {"ph": "X", "pid": 1, "tid": 7, "ts": 4000.0, "dur": 500.0,
-         "name": "fusion.2", "args": {"long_name": "transformer/mlp/up"}},
-        # host-lane event: ignored (not in the XLA Ops lane)
-        {"ph": "X", "pid": 1, "tid": 9, "ts": 0.0, "dur": 9000.0,
-         "name": "attn python"},
-    ]
-    d = tmp_path / "plugins" / "profile"
-    d.mkdir(parents=True)
-    with gzip.open(d / "host.trace.json.gz", "wt") as f:
-        json.dump({"traceEvents": events}, f)
-    meas = pp.measured_anatomy_from_trace(str(tmp_path))
-    assert meas["buckets_ms"]["attn"] == pytest.approx(2.0)
-    assert meas["buckets_ms"]["coll_all_gather"] == pytest.approx(1.0)
-    assert meas["buckets_ms"]["mlp"] == pytest.approx(0.5)
-    assert meas["buckets_ms"]["host_gap"] == pytest.approx(1.0)
-    assert meas["wall_ms"] == pytest.approx(4.5)
-    resum = float(sum(meas["buckets_ms"][n]
-                      for n in sorted(meas["buckets_ms"])))
-    assert resum == meas["total_ms"]
-    assert pp.measured_anatomy_from_trace(str(tmp_path / "empty")) is None
 
 
 # ------------------------------------------------------------- the gate

@@ -97,18 +97,14 @@ def test_disabled_tracer_allocates_no_spans():
         tr.configure(enabled=prev)
 
 
-def test_counters_pipeline_emit_and_drain(tracer):
-    tracer.emit("a", 1.0, 0)
-    tracer.emit("a", 2.0, 1)
-    tracer.emit("b", 5.0, 1)
-    assert tracer.counters()["a"] == (2.0, 1)
-    events = tracer.drain_events()
-    assert events == [("a", 1.0, 0), ("a", 2.0, 1), ("b", 5.0, 1)]
-    assert tracer.drain_events() == []
-    # set_counter (the monitor-sink mirror) must NOT re-queue
+def test_counters_are_latest_value_gauges(tracer):
+    tracer.set_counter("a", 1.0, 0)
+    tracer.set_counter("a", 2.0, 1)
     tracer.set_counter("c", 3.0)
-    assert tracer.drain_events() == []
+    assert tracer.counters()["a"] == (2.0, 1)
     assert tracer.counters()["c"] == (3.0, None)
+    assert tracer.counter_value("a") == 2.0
+    assert tracer.counter_value("missing", -1) == -1
 
 
 # ------------------------------------------------------------- chrome export
@@ -138,7 +134,7 @@ def test_chrome_trace_round_trip(tracer, tmp_path):
 
 
 def test_prometheus_dump_format(tracer):
-    tracer.emit("serving/ttft_ms", 12.5)
+    tracer.set_counter("serving/ttft_ms", 12.5)
     with tracer.span("fwd"):
         pass
     text = prometheus_dump(tracer)
@@ -450,9 +446,8 @@ def test_prometheus_monitor_sink(tmp_path, tracer):
     master.close()
     text = open(tmp_path / "run.prom").read()
     assert 'dstpu_metric{tag="loss"} 0.5' in text
-    # sink mirrors into gauges without re-queueing (no feedback loop)
+    # sink mirrors into gauges
     assert tracer.counters()["loss"] == (0.5, 10)
-    assert tracer.drain_events() == []
 
 
 # ------------------------------------------------------------- timer fixes
