@@ -23,12 +23,13 @@ from typing import Dict, List, Optional, Sequence
 
 from .hlo_audit_rules import HloArtifact
 
-__all__ = ["lower_train_step", "lower_decode_step", "lower_pipe_step",
-           "lower_moe_step", "lower_spec_verify_step", "lower_spec_draft_step",
-           "default_artifacts", "ARTIFACT_NAMES"]
+__all__ = ["lower_train_step", "lower_decode_step", "lower_prefill_step",
+           "lower_pipe_step", "lower_moe_step", "lower_spec_verify_step",
+           "lower_spec_draft_step", "default_artifacts", "ARTIFACT_NAMES"]
 
 ARTIFACT_NAMES = ("train_step_zero3", "decode_with_slots", "pipe_step",
-                  "moe_step", "spec_verify", "spec_draft")
+                  "moe_step", "spec_verify", "spec_draft", "slot_prefill",
+                  "slot_suffix_prefill", "slot_copy_lane", "slot_insert_lane")
 
 #: model dims per size knob: (n_layer, n_embd, n_head, seq)
 _SIZES = {"tiny": (4, 64, 4, 32), "bench": (8, 512, 8, 128)}
@@ -197,71 +198,138 @@ def lower_moe_step(size: str = "tiny", ep: int = 4,
         engine.close()
 
 
+class _LowerBeforeCall:
+    """Stands in for an InferenceEngine's compile plane while one slot
+    program is called: lowers the program observed under ``label`` with
+    the very arguments the call is about to hand it. The engine observes
+    BEFORE its call, so a pool the program consumes is still live here."""
+
+    def __init__(self, label: str):
+        self.label = label
+        self.lowered = None     # (stablehlo, hlo, [(argument name, leaves)])
+
+    def observe(self, label, fn, args, names=None, mesh=None):
+        if label != self.label:
+            return
+        with mesh:
+            lowered = fn.lower(*args)
+            self.lowered = (lowered.as_text(), lowered.compile().as_text(),
+                            list(zip(names, _leaf_counts(*args))))
+
+
+def _slot_engine(max_len: int):
+    import deepspeed_tpu
+    from ..models.gpt2 import GPT2Config, GPT2Model
+
+    _reset_mesh()
+    model = GPT2Model(GPT2Config(vocab_size=128, n_positions=max_len * 2,
+                                 n_embd=64, n_layer=2, n_head=4,
+                                 pad_vocab_to_multiple=1, dtype="float32"))
+    return deepspeed_tpu.init_inference(model, config={"dtype": "float32"})
+
+
+def _lower_slot_program(engine, call, label: str, name: str,
+                        donation_min_bytes: int, meta: Dict) -> HloArtifact:
+    """Run ``call()`` (one InferenceEngine slot program, compile-plane
+    label ``label``) and package the program it ran. Roles follow the
+    names the engine gives its arguments: ``*params`` are the read-only
+    weights, ``*pool`` is the KV pool — state in, state out in every
+    slot program that returns one, so the one donatable role — and the
+    rest is io."""
+    from .. import comm
+
+    per_before = comm.comm_per_op_stats()
+    plane = engine.compile_plane = _LowerBeforeCall(label)
+    try:
+        call()
+    finally:
+        engine.compile_plane = None
+    per_after = comm.comm_per_op_stats()
+    stablehlo, hlo, named = plane.lowered
+    roles = [("weights" if n.endswith("params") else
+              "kv_slots" if n.endswith("pool") else "io", count)
+             for n, count in named]
+    return HloArtifact(
+        name=name,
+        hlo_texts=[hlo],
+        stablehlo=stablehlo,
+        arg_roles=roles,
+        donatable_roles={"kv_slots"},
+        traced_per_op={k: per_after.get(k, 0) - per_before.get(k, 0)
+                       for k in per_after},
+        donation_min_bytes=donation_min_bytes,
+        meta=meta,
+    )
+
+
+def _decode_inputs(num_slots: int):
+    import numpy as np
+    return (np.zeros((num_slots,), np.int32),       # toks
+            np.ones((num_slots,), np.int32),        # positions
+            np.zeros((num_slots,), np.float32),     # temps
+            np.zeros((num_slots,), np.int32),       # top_ks
+            np.ones((num_slots,), np.float32),      # top_ps
+            np.zeros((num_slots,), np.int32))       # seeds
+
+
 def lower_decode_step(num_slots: int = 4, max_len: int = 32,
                       donation_min_bytes: int = 1 << 10) -> HloArtifact:
     """The fused all-slot decode step (``GPT2Model.decode_with_slots``
     under the slot pool) — the serving fleet's steady-state program.
     KV lanes are the donatable role here: an undonated pool doubles
     kv_slots HBM per tick."""
-    import deepspeed_tpu
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from .. import comm
-    from ..models.gpt2 import GPT2Config, GPT2Model
-
-    _reset_mesh()
-    model = GPT2Model(GPT2Config(vocab_size=128, n_positions=max_len * 2,
-                                 n_embd=64, n_layer=2, n_head=4,
-                                 pad_vocab_to_multiple=1, dtype="float32"))
-    engine = deepspeed_tpu.init_inference(model, config={"dtype": "float32"})
+    engine = _slot_engine(max_len)
     pool = engine.init_slot_pool(num_slots, max_len)
-    toks = np.zeros((num_slots,), np.int32)
-    positions = np.ones((num_slots,), np.int32)
-    temps = np.zeros((num_slots,), np.float32)
-    top_ks = np.zeros((num_slots,), np.int32)
-    top_ps = np.ones((num_slots,), np.float32)
-    seeds = np.zeros((num_slots,), np.int32)
-    per_before = comm.comm_per_op_stats()
-    # one call builds (and caches) the compiled step; then lower the same
-    # function for the audit text
-    pool, _ = engine.slot_decode_step(pool, toks, positions, temps)
-    fn = engine._slot_fns[("slot_decode", num_slots, max_len)]
-    args = (engine.params, pool, jnp.asarray(toks), jnp.asarray(positions),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            jnp.asarray(seeds))
-    with engine.mesh:
-        lowered = fn.lower(*args)
-        stablehlo = lowered.as_text()
-        hlo = lowered.compile().as_text()
-    per_after = comm.comm_per_op_stats()
-    counts = _leaf_counts(*args)
-    roles = ["weights", "kv_slots"] + ["io"] * (len(counts) - 2)
-    return HloArtifact(
-        name="decode_with_slots",
-        hlo_texts=[hlo],
-        stablehlo=stablehlo,
-        arg_roles=list(zip(roles, counts)),
-        donatable_roles={"kv_slots"},
-        traced_per_op={k: per_after.get(k, 0) - per_before.get(k, 0)
-                       for k in per_after},
-        donation_min_bytes=donation_min_bytes,
-        meta={"num_slots": num_slots, "max_len": max_len},
-    )
+    toks, positions, temps, *_ = _decode_inputs(num_slots)
+    return _lower_slot_program(
+        engine, lambda: engine.slot_decode_step(pool, toks, positions,
+                                                temps),
+        "slot_decode", "decode_with_slots", donation_min_bytes,
+        {"num_slots": num_slots, "max_len": max_len})
 
 
-def _spec_engine(num_slots: int, max_len: int):
-    import deepspeed_tpu
-    from ..models.gpt2 import GPT2Config, GPT2Model
+#: the programs that write one lane of the pool they are given, by
+#: artifact name: (compile-plane label, the call on (engine, pool)).
+#: ``slot_chunk_prefill`` is not here: its logits head is dead code, jit
+#: prunes the head's weights from the lowered signature, and roles in
+#: flatten order would name the wrong arguments
+#: (tests/unit/test_pool_donation.py holds it to the same rule).
+_LANE_WRITERS = {
+    "slot_prefill": ("slot_prefill", lambda e, pool: e.slot_prefill(
+        pool, 1, list(range(5)))),
+    "slot_suffix_prefill": ("slot_suffix_prefill",
+                            lambda e, pool: e.slot_suffix_prefill(
+                                pool, 1, list(range(5)), 3)),
+    "slot_copy_lane": ("slot_copy", lambda e, pool: e.slot_copy_lane(
+        pool, 0, 1)),
+    "slot_insert_lane": ("slot_insert", lambda e, pool: e.slot_insert_lane(
+        pool, 1, e.slot_extract_lane(pool, 0))),
+}
 
-    _reset_mesh()
-    model = GPT2Model(GPT2Config(vocab_size=128, n_positions=max_len * 2,
-                                 n_embd=64, n_layer=2, n_head=4,
-                                 pad_vocab_to_multiple=1, dtype="float32"))
-    engine = deepspeed_tpu.init_inference(model, config={"dtype": "float32"})
+
+def lower_prefill_step(program: str = "slot_prefill", num_slots: int = 4,
+                       max_len: int = 32, quantize: bool = False,
+                       donation_min_bytes: int = 1 << 10) -> HloArtifact:
+    """One of the programs that change a single lane of the slot pool —
+    the prefill, the suffix prefill, the prefix-reuse lane copy, the
+    hand-off lane insert (``_LANE_WRITERS``). Each takes the pool and
+    returns it, so the pool is the donatable role exactly as in decode:
+    undonated, XLA allocates a second pool and copies all of it to
+    change one lane (ISSUE 28: 5.6 GB and two stalled calls per prefill
+    tick)."""
+    label, call = _LANE_WRITERS[program]
+    engine = _slot_engine(max_len)
+    pool = engine.init_slot_pool(num_slots, max_len, quantize=quantize)
+    return _lower_slot_program(
+        engine, lambda: call(engine, pool), label, program,
+        donation_min_bytes,
+        {"num_slots": num_slots, "max_len": max_len, "quantize": quantize})
+
+
+def _spec_engine(max_len: int):
+    engine = _slot_engine(max_len)
     from ..serving.config import DraftConfig
-    draft = engine.init_draft(DraftConfig(mode="self", layers=1))
-    return engine, draft
+    return engine, engine.init_draft(DraftConfig(mode="self", layers=1))
 
 
 def lower_spec_verify_step(num_slots: int = 4, max_len: int = 32,
@@ -272,44 +340,17 @@ def lower_spec_verify_step(num_slots: int = 4, max_len: int = 32,
     verifying k draft tokens per slot. The TARGET KV pool is the
     donatable role: verify is state-in/state-out per tick exactly like
     decode, so an undonated pool doubles kv_slots HBM."""
-    import jax.numpy as jnp
     import numpy as np
-    from .. import comm
 
-    engine, draft = _spec_engine(num_slots, max_len)
+    engine, _draft = _spec_engine(max_len)
     pool = engine.init_slot_pool(num_slots, max_len)
-    toks = np.zeros((num_slots,), np.int32)
+    toks, positions, *sampling = _decode_inputs(num_slots)
     drafts = np.zeros((num_slots, k), np.int32)
-    positions = np.ones((num_slots,), np.int32)
-    temps = np.zeros((num_slots,), np.float32)
-    top_ks = np.zeros((num_slots,), np.int32)
-    top_ps = np.ones((num_slots,), np.float32)
-    seeds = np.zeros((num_slots,), np.int32)
-    per_before = comm.comm_per_op_stats()
-    pool, _tgt, _acc = engine.slot_verify_step(pool, toks, drafts, positions,
-                                               temps, top_ks, top_ps, seeds)
-    fn = engine._slot_fns[("slot_verify", num_slots, max_len, k)]
-    args = (engine.params, pool, jnp.asarray(toks), jnp.asarray(drafts),
-            jnp.asarray(positions), jnp.asarray(temps), jnp.asarray(top_ks),
-            jnp.asarray(top_ps), jnp.asarray(seeds))
-    with engine.mesh:
-        lowered = fn.lower(*args)
-        stablehlo = lowered.as_text()
-        hlo = lowered.compile().as_text()
-    per_after = comm.comm_per_op_stats()
-    counts = _leaf_counts(*args)
-    roles = ["weights", "kv_slots"] + ["io"] * (len(counts) - 2)
-    return HloArtifact(
-        name="spec_verify",
-        hlo_texts=[hlo],
-        stablehlo=stablehlo,
-        arg_roles=list(zip(roles, counts)),
-        donatable_roles={"kv_slots"},
-        traced_per_op={k2: per_after.get(k2, 0) - per_before.get(k2, 0)
-                       for k2 in per_after},
-        donation_min_bytes=donation_min_bytes,
-        meta={"num_slots": num_slots, "max_len": max_len, "k": k},
-    )
+    return _lower_slot_program(
+        engine, lambda: engine.slot_verify_step(pool, toks, drafts,
+                                                positions, *sampling),
+        "slot_verify", "spec_verify", donation_min_bytes,
+        {"num_slots": num_slots, "max_len": max_len, "k": k})
 
 
 def lower_spec_draft_step(num_slots: int = 4, max_len: int = 32,
@@ -319,45 +360,14 @@ def lower_spec_draft_step(num_slots: int = 4, max_len: int = 32,
     compiled ``lax.scan``). The DRAFT KV pool is the donatable role —
     the draft pool rides the same state-in/state-out contract as the
     target pool, and HLO005 holds both sides to it."""
-    import jax.numpy as jnp
-    import numpy as np
-    from .. import comm
-
-    engine, draft = _spec_engine(num_slots, max_len)
+    engine, draft = _spec_engine(max_len)
     dpool = engine.init_draft_pool(draft, num_slots, max_len)
-    toks = np.zeros((num_slots,), np.int32)
-    positions = np.ones((num_slots,), np.int32)
-    temps = np.zeros((num_slots,), np.float32)
-    top_ks = np.zeros((num_slots,), np.int32)
-    top_ps = np.ones((num_slots,), np.float32)
-    seeds = np.zeros((num_slots,), np.int32)
-    per_before = comm.comm_per_op_stats()
-    dpool, _drafts = engine.slot_draft_propose(draft, dpool, toks, positions,
-                                               temps, top_ks, top_ps, seeds,
-                                               k)
-    fn = engine._slot_fns[("slot_draft", num_slots, max_len, k, draft.key)]
-    args = (draft.params, dpool, jnp.asarray(toks), jnp.asarray(positions),
-            jnp.asarray(temps), jnp.asarray(top_ks), jnp.asarray(top_ps),
-            jnp.asarray(seeds))
-    with engine.mesh:
-        lowered = fn.lower(*args)
-        stablehlo = lowered.as_text()
-        hlo = lowered.compile().as_text()
-    per_after = comm.comm_per_op_stats()
-    counts = _leaf_counts(*args)
-    roles = ["weights", "kv_slots"] + ["io"] * (len(counts) - 2)
-    return HloArtifact(
-        name="spec_draft",
-        hlo_texts=[hlo],
-        stablehlo=stablehlo,
-        arg_roles=list(zip(roles, counts)),
-        donatable_roles={"kv_slots"},
-        traced_per_op={k2: per_after.get(k2, 0) - per_before.get(k2, 0)
-                       for k2 in per_after},
-        donation_min_bytes=donation_min_bytes,
-        meta={"num_slots": num_slots, "max_len": max_len, "k": k,
-              "draft": "self(layers=1)"},
-    )
+    return _lower_slot_program(
+        engine, lambda: engine.slot_draft_propose(
+            draft, dpool, *_decode_inputs(num_slots), k),
+        "slot_draft", "spec_draft", donation_min_bytes,
+        {"num_slots": num_slots, "max_len": max_len, "k": k,
+         "draft": "self(layers=1)"})
 
 
 def default_artifacts(size: str = "tiny",
@@ -372,6 +382,8 @@ def default_artifacts(size: str = "tiny",
         "moe_step": lambda: lower_moe_step(size),
         "spec_verify": lambda: lower_spec_verify_step(),
         "spec_draft": lambda: lower_spec_draft_step(),
+        **{name: (lambda name=name: lower_prefill_step(name))
+           for name in _LANE_WRITERS},
     }
     names = include or ARTIFACT_NAMES
     return [builders[n]() for n in names]

@@ -203,10 +203,9 @@ class InferenceEngine:
 
     def _observe_compile(self, label, fn, args, names=None):
         """Compile-ledger hook: no-op unless the serving layer attached a
-        ledger. Observes BEFORE the call — the fused decode step donates
-        its pool (its args must be read while still live), and the other
-        serving programs don't donate, so before-the-call is the one
-        ordering that works for all of them."""
+        ledger. Observes BEFORE the call: every slot program that returns
+        a pool donates the one it is given, so its args can only be read
+        while they are still live."""
         cp = self.compile_plane
         if cp is None:
             return
@@ -645,9 +644,18 @@ class InferenceEngine:
 
     @staticmethod
     def _pool_dims(pool):
-        """(num_slots, max_len, quantized) from any pool flavor."""
+        """(num_slots, max_len, quantized) from any pool flavor. Every
+        program that returns a pool consumes the one it was given
+        (``donate_argnums``): a pool that was handed over already is
+        refused here, by name, before XLA refuses its buffers."""
         quantized = InferenceEngine._is_quantized_pool(pool)
         leaf = jax.tree.leaves(pool.q if quantized else pool)[0]
+        if leaf.is_deleted():
+            raise RuntimeError(
+                "this KV pool was consumed by an earlier slot_* call (or by "
+                "one that raised after its dispatch): every pool program "
+                "donates its pool, so rebind the pool from the call's "
+                "return and never reuse the argument")
         return int(leaf.shape[1]), int(leaf.shape[-2]), quantized
 
     def _read_lane(self, pool, slot_idx, quantized):
@@ -749,9 +757,16 @@ class InferenceEngine:
                                   last_idx + 1, vocab)
                 return pool, tok
 
+            # the pool is donated, here and in every program below that
+            # returns one: aliased to the output, _write_lane's
+            # dynamic_update_slice changes one lane in place; undonated,
+            # XLA allocates a second pool and copies all of it, and the
+            # runtime holds the next call until the old one is free
+            # (docs/serving.md, "Who owns the pool")
             fn = self._slot_fns[fkey] = jax.jit(pf, in_shardings=(
                 self.param_shardings, None, pool_shardings, None, None, None,
-                None, None, None), out_shardings=(pool_shardings, None))
+                None, None, None), out_shardings=(pool_shardings, None),
+                donate_argnums=(2,))
         pool, tok = self._slot_prefill_call(
             "slot_prefill", fn, pool, slot, prompt, bucket,
             (np.int32(t - 1), np.float32(temperature), np.int32(top_k),
@@ -828,7 +843,8 @@ class InferenceEngine:
 
             fn = self._slot_fns[fkey] = jax.jit(spf, in_shardings=(
                 self.param_shardings, None, pool_shardings, None, None, None,
-                None, None, None, None), out_shardings=(pool_shardings, None))
+                None, None, None, None), out_shardings=(pool_shardings, None),
+                donate_argnums=(2,))
         pool, tok = self._slot_prefill_call(
             "slot_suffix_prefill", fn, pool, slot, tokens, bucket,
             (np.int32(start_pos), np.int32(t - 1), np.float32(temperature),
@@ -927,7 +943,7 @@ class InferenceEngine:
                         leaf, _lane_slice(leaf, src_idx), dst_idx), pool)
 
             fn = self._slot_fns[fkey] = jax.jit(
-                cp, out_shardings=pool_shardings)
+                cp, out_shardings=pool_shardings, donate_argnums=(0,))
         cp_args = (pool, jnp.int32(src), jnp.int32(dst))
         self._observe_compile("slot_copy", fn, cp_args,
                               names=("pool", "src", "dst"))
@@ -990,7 +1006,7 @@ class InferenceEngine:
                     lambda pc, mc: _lane_update(pc, mc, idx), pool, lane)
 
             fn = self._slot_fns[fkey] = jax.jit(
-                ins, out_shardings=pool_shardings)
+                ins, out_shardings=pool_shardings, donate_argnums=(0,))
         ins_args = (pool, lane, jnp.int32(slot))
         self._observe_compile("slot_insert", fn, ins_args,
                               names=("pool", "lane", "slot"))

@@ -20,11 +20,11 @@ bare recompile count.
 Event detection is fingerprint-driven: a changed signature IS the
 recompile cause the diff names, and the jit cache size is sampled as a
 backstop for recompiles with an unchanged signature (static-argument or
-weak-type changes). ``observe()`` runs before the call on the training
-path (the train step donates its inputs, so fingerprints must be taken
-while the arrays are alive; ``fn.lower`` only reads avals and never
-consumes donated buffers) and works equally after the call on the
-serving path (slot programs don't donate).
+weak-type changes). ``observe()`` runs before the call on both paths:
+the train step donates its inputs and every serving slot program that
+returns a KV pool donates the one it is given, so fingerprints must be
+taken while the arrays are alive (``fn.lower`` only reads avals and
+never consumes donated buffers).
 
 Analysis capture: ``cost_analysis`` comes from the *lowered* stage (no
 backend compile — global-program FLOPs). ``memory_analysis`` needs a

@@ -1,7 +1,9 @@
-"""Ahead-of-time compiles of the Pallas attention kernels for a described
+"""Ahead-of-time compiles of the Pallas attention kernels, and of the slot
+prefill over a pool of the serving cell's lane shape, for a described
 TPU v5e (no chip attached): Mosaic and the TPU compiler accept what the
-interpret-mode tests cannot see — tiling, scoped VMEM, HBM fit. Nothing
-runs; a compile that passes is not a chip run.
+interpret-mode tests cannot see — tiling, scoped VMEM, HBM fit, whether a
+donated pool stays aliased. Nothing runs; a compile that passes is not a
+chip run.
 
 The topology is described inside a module-scoped fixture (never at import):
 only the xdist worker that is handed this file loads the TPU library.
@@ -104,3 +106,52 @@ def test_block_sparse_fwd_bwd(one_chip, t):
     assert np.asarray(layout).any()
     _compile(lambda q, k, v: sparse_attention_pallas(q, k, v, layout, block),
              one_chip, (2, h, t, 64), jnp.bfloat16)
+
+
+@pytest.mark.parametrize("quantized", (False, True), ids=("fp", "q8"))
+def test_slot_prefill_writes_its_lane_in_place(one_chip, quantized):
+    """The chip's compiler keeps the alias the engine asks for
+    (``donate_argnums``): over a 28-slot pool of ``opt-1.3b.serve-chat``'s
+    lane shape (32 heads x 1024 columns x 64, bf16 or int8 + scales; two
+    layers) the whole pool is aliased to the output and the program's
+    temporaries are a lane's size, not a pool's. XLA drops an alias
+    silently where the layouts of input and output differ; that would show
+    here as ``alias_size_in_bytes`` 0 and, on the chip, as a second pool
+    copied per prefill (ISSUE 28)."""
+    import deepspeed_tpu
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes, quantize_pool
+    from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+
+    slots, max_len, bucket = 28, 1024, 128
+    model = OPTModel(OPTConfig(vocab_size=512, n_positions=max_len,
+                               n_embd=2048, n_layer=2, n_head=32,
+                               dtype="bfloat16"))
+    engine = deepspeed_tpu.init_inference(
+        model, config={"dtype": "bfloat16", "max_tokens": max_len})
+    # one call on a one-slot pool makes the engine build its program
+    tiny = engine.init_slot_pool(1, max_len)
+    if quantized:
+        tiny = quantize_pool(tiny)
+    tiny, _ = engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
+    fn = engine._slot_fns[("slot_prefill", 1, max_len)
+                          + (("q8",) if quantized else ())]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    i32, f32 = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    # pf(params, ids, pool, slot_idx, last_idx, temp, top_k, top_p, seed)
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        params, on_chip((1, bucket), jnp.int32), pool, i32, i32, f32, i32,
+        f32, i32).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert mem.temp_size_in_bytes < pool_nbytes(pool) // slots * 2
+    first = len(jax.tree.leaves(params)) + 1
+    assert donated_params_from_hlo(compiled.as_text()) == set(
+        range(first, first + len(jax.tree.leaves(pool))))

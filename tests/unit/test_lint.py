@@ -394,27 +394,65 @@ def test_train_artifact_shape(real_artifacts):
     assert recs and module_num_partitions(train.hlo_texts[0]) == 8
 
 
-def test_decode_artifact_pool_donated(real_artifacts):
-    """The PR's donation fix, pinned: every KV-lane argument of the
-    fused decode step is donated (the auditor found them undonated —
-    a pool-sized HBM double per tick — and the fix lives in
-    inference/engine.py slot_decode_step)."""
+def _assert_pool_donated(art):
+    """Every kv_slots argument of ``art`` is marked donated in the
+    StableHLO signature AND aliased to an output by the compiled module
+    (XLA drops an alias silently where shardings or layouts differ).
+    Returns the number of pool leaves."""
     from deepspeed_tpu.analysis import collect_donation
-    decode = real_artifacts[1]
-    args = collect_donation(decode.stablehlo)
-    off = decode.arg_roles[0][1]
-    kv = args[off:off + decode.arg_roles[1][1]]
-    assert kv and all(a["donated"] for a in kv)
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    args = collect_donation(art.stablehlo)
+    kv, off = [], 0
+    for role, count in art.arg_roles:
+        if role == "kv_slots":
+            kv += args[off:off + count]
+        off += count
+    assert kv and all(a["donated"] for a in kv), art.name
+    assert {a["index"] for a in kv} <= \
+        donated_params_from_hlo(art.hlo_texts[0]), art.name
+    return len(kv)
 
 
-def test_spec_artifacts_pools_donated(real_artifacts):
-    """ISSUE 12 acceptance: the speculative verify step donates the
-    TARGET pool and the draft-propose step donates the DRAFT pool —
-    speculation must not re-introduce the pool-sized HBM double the
-    decode-step donation fix removed."""
-    from deepspeed_tpu.analysis import collect_donation
-    for art in real_artifacts[2:]:
-        args = collect_donation(art.stablehlo)
-        off = art.arg_roles[0][1]
-        kv = args[off:off + art.arg_roles[1][1]]
-        assert kv and all(a["donated"] for a in kv), art.name
+@pytest.mark.parametrize("index,name", [(1, "decode_with_slots"),
+                                        (2, "spec_verify"),
+                                        (3, "spec_draft")])
+def test_serving_artifact_pool_donated(real_artifacts, index, name):
+    """The donation fixes, pinned: every KV-lane argument of the fused
+    decode step (the auditor found it undonated — a pool-sized HBM
+    double per tick) and of the speculative verify (TARGET pool) and
+    draft-propose (DRAFT pool) steps is donated and stays aliased."""
+    art = real_artifacts[index]
+    assert art.name == name
+    _assert_pool_donated(art)
+
+
+@pytest.mark.parametrize("quantize", (False, True), ids=("fp", "q8"))
+@pytest.mark.parametrize("program", ["slot_prefill", "slot_suffix_prefill",
+                                     "slot_copy_lane", "slot_insert_lane"])
+def test_lane_writer_artifact_clean_and_aliased(program, quantize):
+    """ISSUE 28: every program that takes the pool and returns it audits
+    clean under HLO005, fp and int8 pool alike, and XLA kept one alias
+    per pool leaf — the lane write is in place, not a second pool."""
+    from deepspeed_tpu.analysis.artifacts import lower_prefill_step
+    art = lower_prefill_step(program, quantize=quantize)
+    findings = run_hlo_audit([art])
+    assert findings == [], "\n".join(
+        f"{f.waiver_key}: {f.message}" for f in findings)
+    assert _assert_pool_donated(art) == (4 if quantize else 2)
+
+
+def test_hlo005_names_an_undonated_prefill_pool(monkeypatch):
+    """The rule bites: the same prefill program jitted without
+    ``donate_argnums`` (what ISSUE 28 found) is an HLO005 error on each
+    pool leaf."""
+    import jax
+    from deepspeed_tpu.analysis.artifacts import lower_prefill_step
+    jit = jax.jit
+    monkeypatch.setattr(
+        jax, "jit", lambda fun, **kw: jit(
+            fun, **{k: v for k, v in kw.items() if k != "donate_argnums"}))
+    findings = run_hlo_audit([lower_prefill_step()])
+    assert [f.rule for f in findings] == ["HLO005", "HLO005"]
+    assert all("kv_slots" in f.message for f in findings)
+
+
