@@ -152,6 +152,10 @@ class InferenceEngine:
         # pool init) becomes a compile event with an arg fingerprint
         self.compile_plane = None
         self._tracer = get_tracer()     # the slot programs' phase records
+        # a model with a routed expert layer: slot_prefill and
+        # slot_decode_step read its routing stats back with the tokens
+        self._routed = bool(getattr(model, "routed_experts", False))
+        self._routing = None
         n_params = sum(int(np.prod(s.shape))
                        for s in jax.tree.leaves(param_shapes))
         log_dist(f"InferenceEngine initialized: params={n_params/1e6:.1f}M "
@@ -748,14 +752,16 @@ class InferenceEngine:
             def pf(params, ids, pool, slot_idx, last_idx, temp, top_k,
                    top_p, seed):
                 mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
-                logits, mini = model.apply_with_cache(params, ids, mini,
-                                                      jnp.int32(0))
+                logits, mini, *stats = model.apply_with_cache(
+                    params, ids, mini, jnp.int32(0), routing=self._routed)
                 pool = self._write_lane(pool, mini, slot_idx, quantized)
                 last = jnp.take(logits[0], last_idx, axis=0)
                 # the first token is FED at column last_idx + 1
                 tok = _sample_one(last, temp, top_k, top_p, seed,
                                   last_idx + 1, vocab)
-                return pool, tok
+                # routed experts: [token, touched, largest], one read-back
+                return pool, \
+                    jnp.concatenate([tok[None], *stats]) if stats else tok
 
             # the pool is donated, here and in every program below that
             # returns one: aliased to the output, _write_lane's
@@ -773,8 +779,25 @@ class InferenceEngine:
              np.float32(top_p), np.int32(seed)),
             ("last_idx", "temperature", "top_k", "top_p", "seed"))
         with self._tracer.phase("serve/prefill_wait"):
-            tok = int(tok)
-        return pool, tok
+            tok = self._read_back(np.asarray(tok).reshape(-1), 1)
+        return pool, int(tok[0])
+
+    def _read_back(self, out, n):
+        """What a slot program read back: the first ``n`` entries are its
+        tokens; a model with routed experts appends (experts touched,
+        largest count any expert got), summed over layers — kept for
+        ``take_routing``."""
+        if not self._routed:
+            return out
+        self._routing = (int(out[n]), int(out[n + 1]))
+        return out[:n]
+
+    def take_routing(self):
+        """(experts touched, largest count) of the last ``slot_prefill`` or
+        ``slot_decode_step`` of a model with routed experts, once; ``None``
+        after any other call and for a dense model."""
+        routing, self._routing = self._routing, None
+        return routing
 
     def _slot_prefill_call(self, label, fn, pool, slot, tokens, bucket,
                            scalars, names):
@@ -1042,8 +1065,9 @@ class InferenceEngine:
                     fp = dequantize_pool(pool, self.dtype)
                 else:
                     fp = pool
-                logits, fp = model.decode_with_slots(
-                    params, toks[:, None], fp, positions)
+                logits, fp, *stats = model.decode_with_slots(
+                    params, toks[:, None], fp, positions,
+                    routing=self._routed)
                 # the sampled token will be FED at column positions + 1
                 # ("sample" scope: the perf plane buckets this tail apart
                 # from the model forward it follows)
@@ -1055,7 +1079,8 @@ class InferenceEngine:
                 # round-trip of every column this step did not write exact,
                 # so old tokens never re-accumulate quantization error
                 pool = quantize_pool(fp) if quantized else fp
-                return pool, nxt
+                # routed experts: the stats ride behind the tokens
+                return pool, jnp.concatenate([nxt, *stats]) if stats else nxt
 
             # donate the pool: decode is state-in/state-out per tick, and
             # an undonated pool keeps TWO pool-sized buffers live across
@@ -1089,7 +1114,7 @@ class InferenceEngine:
         with tr.phase("serve/decode_dispatch"), self.mesh:
             pool, nxt = fn(*dec_args)
         with tr.phase("serve/decode_wait"):
-            nxt = np.asarray(nxt)
+            nxt = self._read_back(np.asarray(nxt), num_slots)
         return pool, nxt
 
     def slot_decode_executables(self, num_slots: int, max_len: int,

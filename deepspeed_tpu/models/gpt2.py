@@ -290,7 +290,10 @@ class GPT2Model(ModelSpec):
 
     def _decode_block(self, x, layer_params, attn_fn, start_pos,
                       positions=None, extra=None):
-        """One block on the KV-cache decode path (no dropout/rng)."""
+        """One block on the KV-cache decode path (no dropout/rng). Returns
+        x; a family with a routed expert layer returns ``(x, exp_counts)``
+        (rows each expert got), which the cache forwards sum up over layers
+        for ``routing=True`` callers."""
         with jax.named_scope("attn"):
             x = self._attn_sublayer(x, layer_params, None, False,
                                     attn_fn=attn_fn, start_pos=start_pos,
@@ -298,6 +301,25 @@ class GPT2Model(ModelSpec):
         with jax.named_scope("mlp"):
             x, _ = self._mlp_sublayer(x, layer_params, None, False)
         return x
+
+    @staticmethod
+    def _split_routing(out):
+        """``_decode_block``'s return as ``(x, stats)``: stats is None for a
+        dense block, else int32 [2] = (experts that got a row, the largest
+        count any one expert got) of this layer."""
+        if not isinstance(out, tuple):
+            return out, None
+        x, counts = out
+        return x, jnp.stack([jnp.sum(counts > 0), jnp.max(counts)]
+                            ).astype(jnp.int32)
+
+    @staticmethod
+    def _cache_return(logits, cache, stats, routing):
+        """(logits, cache), and for ``routing=True`` the layers' routing
+        stats summed to int32 [2] (None from a dense model)."""
+        if not routing:
+            return logits, cache
+        return logits, cache, None if stats is None else stats.sum(axis=0)
 
     # ---- per-layer constants (scanned alongside the stacked params) ----
     def _layer_extras(self):
@@ -608,7 +630,7 @@ class GPT2Model(ModelSpec):
         return None
 
     def apply_with_cache(self, params, input_ids, cache, start_pos,
-                         pad_counts=None):
+                         pad_counts=None, routing=False):
         """Forward with KV cache. input_ids: [B, T] (prompt for prefill,
         [B, 1] for decode); start_pos: traced scalar — tokens occupy cache
         columns [start_pos, start_pos+T). ``pad_counts`` [B]: number of
@@ -616,7 +638,8 @@ class GPT2Model(ModelSpec):
         cache columns below pad_counts[b] are masked out and logical
         positions shift down by pad_counts[b] (ALiBi needs no shift: a
         per-row constant is softmax-invariant). Returns (logits [B,T,V],
-        new_cache)."""
+        new_cache); with ``routing=True`` also the routed expert layers'
+        stats (``_cache_return``)."""
         cfg = self.config
         b, t = input_ids.shape
         max_len = cache["k"].shape[-2]
@@ -677,20 +700,21 @@ class GPT2Model(ModelSpec):
                 return reference_attention(q, kq, vq, causal=False, mask=mask,
                                            bias=bias)
 
-            return self._decode_block(x, layer_params, cached_attn,
-                                      start_pos, positions=positions,
-                                      extra=extra), \
-                (new_kv["k"], new_kv["v"])
+            x, stats = self._split_routing(self._decode_block(
+                x, layer_params, cached_attn, start_pos,
+                positions=positions, extra=extra))
+            return x, (new_kv["k"], new_kv["v"], stats)
 
         xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
             else (params["blocks"], cache["k"], cache["v"], extras)
-        x, (new_k, new_v) = lax.scan(body, x, xs)
+        x, (new_k, new_v, stats) = lax.scan(body, x, xs)
         x = self._final_norm(params, x)
         logits = x @ self._unembed_weight(params, compute_dtype).T
         head_b = self._head_bias(params, logits.dtype)
         if head_b is not None:
             logits = logits + head_b
-        return logits, {"k": new_k, "v": new_v}
+        return self._cache_return(logits, {"k": new_k, "v": new_v}, stats,
+                                  routing)
 
     def chunk_prefill_with_cache(self, params, input_ids, cache, start_pos):
         """K/V-write-only forward for chunked prefill: one chunk of a
@@ -708,7 +732,8 @@ class GPT2Model(ModelSpec):
                                                start_pos)
         return cache
 
-    def decode_with_slots(self, params, input_ids, cache, positions):
+    def decode_with_slots(self, params, input_ids, cache, positions,
+                          routing=False):
         """One decode token per batch row with PER-ROW cache positions — the
         continuous-batching serving step (deepspeed_tpu/serving/): each row
         of ``cache`` is an independent decode SLOT at its own sequence
@@ -721,7 +746,8 @@ class GPT2Model(ModelSpec):
         (shared dynamic_update_slice column), the per-row write is a masked
         select over the column axis — static shapes, no gather/scatter, so
         the step compiles exactly once per (S, max_len). Returns
-        (logits [S, 1, V], new_cache)."""
+        (logits [S, 1, V], new_cache); ``routing`` as in
+        ``apply_with_cache``."""
         b, t = input_ids.shape
         if t != 1:
             raise ValueError(f"decode_with_slots is single-token: got T={t}")
@@ -767,20 +793,21 @@ class GPT2Model(ModelSpec):
                 return reference_attention(q, kq, vq, causal=False, mask=mask,
                                            bias=bias)
 
-            return self._decode_block(x, layer_params, cached_attn,
-                                      jnp.int32(0), positions=pos2d,
-                                      extra=extra), \
-                (new_kv["k"], new_kv["v"])
+            x, stats = self._split_routing(self._decode_block(
+                x, layer_params, cached_attn, jnp.int32(0), positions=pos2d,
+                extra=extra))
+            return x, (new_kv["k"], new_kv["v"], stats)
 
         xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
             else (params["blocks"], cache["k"], cache["v"], extras)
-        x, (new_k, new_v) = lax.scan(body, x, xs)
+        x, (new_k, new_v, stats) = lax.scan(body, x, xs)
         x = self._final_norm(params, x)
         logits = x @ self._unembed_weight(params, compute_dtype).T
         head_b = self._head_bias(params, logits.dtype)
         if head_b is not None:
             logits = logits + head_b
-        return logits, {"k": new_k, "v": new_v}
+        return self._cache_return(logits, {"k": new_k, "v": new_v}, stats,
+                                  routing)
 
     def verify_with_slots(self, params, input_ids, cache, positions):
         """Multi-token block forward with PER-ROW cache positions — the
@@ -855,10 +882,10 @@ class GPT2Model(ModelSpec):
                 return reference_attention(q, kq, vq, causal=False, mask=mask,
                                            bias=bias)
 
-            return self._decode_block(x, layer_params, cached_attn,
-                                      jnp.int32(0), positions=pos2d,
-                                      extra=extra), \
-                (new_kv["k"], new_kv["v"])
+            x, _ = self._split_routing(self._decode_block(
+                x, layer_params, cached_attn, jnp.int32(0), positions=pos2d,
+                extra=extra))
+            return x, (new_kv["k"], new_kv["v"])
 
         xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
             else (params["blocks"], cache["k"], cache["v"], extras)

@@ -34,6 +34,7 @@ class GPT2MoEConfig(GPT2Config):
 
 
 class GPT2MoEModel(GPT2Model):
+    routed_experts = True     # the cache forwards hand routing stats on
 
     def __init__(self, config: GPT2MoEConfig = GPT2MoEConfig()):
         super().__init__(config)
@@ -62,28 +63,31 @@ class GPT2MoEModel(GPT2Model):
         return params
 
     # ----------------------------------------------------------------- block
-    def _mlp_sublayer(self, x, p, rng, train, serve=False):
+    def _mlp_sublayer(self, x, p, rng, train):
         cfg = self.config
         ln2 = _layer_norm(x, p["ln2_scale"], p["ln2_bias"],
                           cfg.layer_norm_epsilon)
-        if serve:
-            # capacity-free routing (no drops, no noise): the reference's
-            # MoE inference semantics (ops/transformer/inference/
-            # moe_inference.py:160); shares the training gate/expert params
-            y, l_aux, _ = self.moe.apply_dense(p["moe"], ln2)
-        else:
-            y, l_aux, _ = self.moe.apply(p["moe"], ln2, rng=rng, train=train)
+        y, l_aux, _ = self.moe.apply(p["moe"], ln2, rng=rng, train=train)
         return x + self._dropout(y, rng, train, 1), l_aux
 
     def _decode_block(self, x, layer_params, attn_fn, start_pos,
                       positions=None, extra=None):
-        """KV-cache decode block: attention from the base class, MoE FFN
-        through the capacity-free serving path."""
-        x = self._attn_sublayer(x, layer_params, None, False, attn_fn=attn_fn,
-                                start_pos=start_pos, positions=positions,
-                                extra=extra)
-        x, _ = self._mlp_sublayer(x, layer_params, None, False, serve=True)
-        return x
+        """KV-cache decode block: attention from the base class, the MoE
+        FFN routed and dropless (no capacity, no noise): the reference's
+        MoE inference semantics (ops/transformer/inference/
+        moe_inference.py:160) on the training gate/expert params. Returns
+        (x, exp_counts)."""
+        cfg = self.config
+        p = layer_params
+        with jax.named_scope("attn"):
+            x = self._attn_sublayer(x, p, None, False, attn_fn=attn_fn,
+                                    start_pos=start_pos, positions=positions,
+                                    extra=extra)
+        with jax.named_scope("moe"):
+            ln2 = _layer_norm(x, p["ln2_scale"], p["ln2_bias"],
+                              cfg.layer_norm_epsilon)
+            y, _, counts = self.moe.apply_routed(p["moe"], ln2)
+        return x + y, counts
 
     # ------------------------------------------------------------- sharding
     def partition_rules(self):

@@ -143,6 +143,11 @@ class LlamaModel(GPT2Model):
         return keep
 
     # ----------------------------------------------------------------- block
+    def _qk_norm(self, q, k, p):
+        """Hook on the whole q and k projections ([B, T, H*hd]) before they
+        are split into heads and rotated; OLMoE normalises them here."""
+        return q, k
+
     def _attn_sublayer(self, x, p, rng, train, attn_fn=None, start_pos=0,
                        positions=None, extra=None):
         cfg = self.config
@@ -151,6 +156,7 @@ class LlamaModel(GPT2Model):
         ln1 = _rms_norm(x, p["ln1_scale"], cfg.layer_norm_epsilon)
         qkv = ln1 @ p["qkv_w"].astype(ln1.dtype)
         q, k, v = jnp.split(qkv, [h * hd, (h + hk) * hd], axis=-1)
+        q, k = self._qk_norm(q, k, p)
         q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, hk, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, hk, hd).transpose(0, 2, 1, 3)

@@ -253,31 +253,24 @@ def topk_gating(logits: jnp.ndarray,
     return l_aux, combine, dispatch, exp_counts
 
 
-def topk_weights(logits: jnp.ndarray, k: int) -> Tuple[jnp.ndarray,
-                                                       jnp.ndarray]:
-    """Capacity-free top-k combine weights: [S, E] with the same gate
-    semantics as ``topk_gating`` (argmax loop with -inf re-masking; raw
-    gate prob for k=1, renormalized picked gates for k>=2) but NO
-    capacity/slot machinery — every token keeps all its picks. Returns
-    (weights [S, E] f32, exp_counts [E] i32)."""
-    s, e = logits.shape
+def topk_route(logits: jnp.ndarray, k: int,
+               renormalize: Optional[bool] = None) -> Tuple[jnp.ndarray,
+                                                            jnp.ndarray]:
+    """Capacity-free top-k routing: every token keeps all its picks.
+    ``logits`` [S, E] → (weights [S, k] f32, experts [S, k] i32, best
+    first). The weights are the softmax (float32, over all E) at the picked
+    experts; ``renormalize`` divides them by their sum over the k picks.
+    ``None`` is the gate semantics of ``topk_gating`` (DeepSpeed's: raw
+    probability for k = 1, renormalized for k >= 2); OLMoE
+    (``norm_topk_prob`` false) passes ``False``."""
     gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    masked = logits.astype(jnp.float32)
-    picks = []
-    gate_sum = jnp.zeros((s,), jnp.float32)
-    exp_counts = jnp.zeros((e,), jnp.int32)
-    for _ in range(k):
-        idx = jnp.argmax(masked, axis=-1)
-        mask = _one_hot(idx, e)
-        gate_val = jnp.sum(gates * mask, axis=-1)
-        picks.append((mask, gate_val))
-        gate_sum = gate_sum + gate_val
-        exp_counts = exp_counts + jnp.sum(mask, axis=0).astype(jnp.int32)
-        masked = jnp.where(mask > 0, -jnp.inf, masked)
-    denom = jnp.ones_like(gate_sum) if k == 1 else \
-        jnp.maximum(gate_sum, jnp.finfo(jnp.float32).eps)
-    w = sum(mask * (gate_val / denom)[:, None] for mask, gate_val in picks)
-    return w, exp_counts
+    w, idx = lax.top_k(gates, k)
+    if renormalize is None:
+        renormalize = k > 1
+    if renormalize:
+        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True),
+                            jnp.finfo(jnp.float32).eps)
+    return w, idx.astype(jnp.int32)
 
 
 class TopKGate:
@@ -368,29 +361,34 @@ class MOELayer:
             y = maybe_constraint(y, (DATA_AXIS, EXPERT_AXIS), None)
         return y.reshape(*lead, m), l_aux, exp_counts
 
-    def apply_dense(self, params, x, rng=None, train=False):
-        """Capacity-free serving path (the reference's MoE-inference
+    def apply_routed(self, params, x, renormalize=None):
+        """Routed, dropless serving path (the reference's MoE-inference
         semantics, reference ops/transformer/inference/moe_inference.py:160
-        — route every token, drop nothing): evaluate ALL experts on all
-        tokens and combine with ``topk_weights``. Costs E/k x the routed
-        FLOPs but has no [S, E, C] one-hot tensors, whose O(S^2·E)
-        dispatch einsum would dominate long-prompt prefill. Same return
-        shape as apply(); l_aux is 0 (no load-balance objective when
-        serving)."""
+        — route every token, drop nothing, no capacity): router matmul and
+        softmax in float32, ``topk_route``, the token-expert pairs sorted
+        by expert, the experts' matmuls as grouped matmuls over the groups
+        (``experts.apply_grouped``), un-sorted and combined in the
+        activations' type. Costs the routed FLOPs and holds no [S, E, C]
+        tensor. Same return shape as apply(): l_aux is 0 (no load-balance
+        objective when serving); exp_counts [E] are the rows each expert
+        got."""
         lead = x.shape[:-1]
         m = x.shape[-1]
         xs = x.reshape(-1, m)                                      # [S, M]
-        logits = xs.astype(jnp.float32) @ params["gate"]["wg"]
-        w, exp_counts = topk_weights(logits, self.gate.k)          # [S, E]
-        e = logits.shape[-1]
-        expert_in = jnp.broadcast_to(xs[None], (e,) + xs.shape)    # [E, S, M]
-        if self.use_sharding_constraints:
-            expert_in = maybe_constraint(expert_in, EXPERT_AXIS, None, None)
-        expert_out = self.experts.apply(params["experts"], expert_in,
-                                        rng=rng, train=train)      # [E, S, M]
-        if self.use_sharding_constraints:
-            expert_out = maybe_constraint(expert_out, EXPERT_AXIS, None, None)
-        y = jnp.einsum("se,esm->sm", w.astype(x.dtype), expert_out)
-        if self.use_sharding_constraints:
-            y = maybe_constraint(y, (DATA_AXIS, EXPERT_AXIS), None)
+        k, e = self.gate.k, self.gate.num_experts
+        logits = xs.astype(jnp.float32) @ \
+            params["gate"]["wg"].astype(jnp.float32)
+        w, idx = topk_route(logits, k, renormalize)                # [S, k]
+        flat = idx.reshape(-1)                                     # [S*k]
+        order = jnp.argsort(flat, stable=True)   # pair ids, by expert
+        exp_counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        with jax.named_scope("moe_experts"):
+            expert_out = self.experts.apply_grouped(
+                params["experts"], xs[order // k], exp_counts,
+                flat[order])                                       # [S*k, M]
+        # un-sort: pair (s, j) sits at row inverse[s*k + j]
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        picked = expert_out[inverse].reshape(-1, k, m)
+        y = jnp.einsum("skm,sk->sm", picked, w.astype(x.dtype))
         return y.reshape(*lead, m), jnp.float32(0.0), exp_counts

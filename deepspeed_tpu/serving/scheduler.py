@@ -897,11 +897,22 @@ class ContinuousBatchingScheduler:
                 self.pool.cache, slot, req.prompt,
                 temperature=sp.temperature, top_k=sp.top_k,
                 top_p=sp.top_p, seed=sp.seed)
+        self._record_routing("serve/moe_prefill")
         if self.cost is not None:
             self.cost.charge_prefill(self.cost.record_for(req),
                                      self.clock() - t0,
                                      int(req.prompt.size))
         return first
+
+    def _record_routing(self, name: str):
+        """A model with routed experts: the program's last call read back
+        (experts touched, largest count any expert got), summed over
+        layers, with its tokens — kept as an instant phase record. A dense
+        model's engine has nothing to take and nothing is recorded."""
+        routing = self.engine.take_routing()
+        if routing is not None:
+            now = time.perf_counter_ns()
+            self.tracer.record_phase(name, now, now, *routing)
 
     def _hand_off(self, slot: int, req: Request, first: int):
         """Prefill role: package the freshly prefilled lane as a
@@ -970,6 +981,7 @@ class ContinuousBatchingScheduler:
             self.pool.cache, nxt = self.engine.slot_decode_step(
                 self.pool.cache, toks, positions, temps,
                 top_ks=top_ks, top_ps=top_ps, seeds=seeds)
+        self._record_routing("serve/moe_decode")
         dt = self.clock() - t0
         self.metrics.record_decode_step(dt, len(active))
         if self.cost is not None:

@@ -39,11 +39,13 @@ def test_moe_decode_matches_dense_forward():
                                np.asarray(dense[:, -1]), atol=2e-4)
 
 
-def test_apply_dense_matches_routed_nodrop():
-    """MOELayer.apply_dense == the routed dispatch path with
+def test_routed_path_matches_the_dense_formula_and_the_nodrop_dispatch():
+    """MOELayer.apply_routed (sorted pairs, grouped matmul) == every expert
+    on every token times its top-k weight (what ``apply_dense`` computed,
+    written out here as the formula) == the capacity dispatch path with
     drop_tokens=False (same gate weights, no capacity) — the serving
     path's numerics oracle."""
-    from deepspeed_tpu.moe.sharded_moe import MOELayer, TopKGate
+    from deepspeed_tpu.moe.sharded_moe import MOELayer, TopKGate, topk_route
     from deepspeed_tpu.moe.experts import ExpertFFN
 
     gate = TopKGate(16, 4, k=2, drop_tokens=False, use_rts=False)
@@ -52,12 +54,20 @@ def test_apply_dense_matches_routed_nodrop():
     params = layer.init(jax.random.PRNGKey(0))
     x = jnp.asarray(np.random.default_rng(2).standard_normal((10, 16)),
                     jnp.float32)
-    y_routed, _, counts_r = layer.apply(params, x, train=False)
-    y_dense, aux, counts_d = layer.apply_dense(params, x)
-    np.testing.assert_allclose(np.asarray(y_dense), np.asarray(y_routed),
+    y_nodrop, _, counts_n = layer.apply(params, x, train=False)
+    y_routed, aux, counts_r = layer.apply_routed(params, x)
+    w, idx = topk_route(x @ params["gate"]["wg"], 2)
+    full = jnp.zeros((10, 4)).at[jnp.arange(10)[:, None], idx].set(w)
+    every = layer.experts.apply(params["experts"],
+                                jnp.broadcast_to(x[None], (4, 10, 16)))
+    y_dense = jnp.einsum("se,esm->sm", full, every)
+    np.testing.assert_allclose(np.asarray(y_routed), np.asarray(y_dense),
                                rtol=1e-5, atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(counts_d),
-                                  np.asarray(counts_r))
+    np.testing.assert_allclose(np.asarray(y_routed), np.asarray(y_nodrop),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(counts_r),
+                                  np.asarray(counts_n))
+    np.testing.assert_allclose(np.asarray(w.sum(-1)), 1.0, rtol=1e-6)
     assert float(aux) == 0.0
 
 

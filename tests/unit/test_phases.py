@@ -185,6 +185,42 @@ def test_tick_with_one_prefill_holds_every_phase(engine, monkeypatch):
     srv.shutdown()
 
 
+def test_routed_model_tick_records_its_routing_and_a_dense_one_none(engine):
+    """A model with routed experts (OLMoE) leaves one ``serve/moe_prefill``
+    record a prefill and one ``serve/moe_decode`` a decode tick, payload
+    (experts touched, largest count any expert got) summed over layers —
+    what ``chipbench/layer_metrics/serve_moe.py`` reads; a dense model's
+    tick (the fixture's) leaves neither."""
+    from deepspeed_tpu.models.olmoe import OLMoEConfig, OLMoEModel
+    model = OLMoEModel(OLMoEConfig(
+        vocab_size=VOCAB, n_positions=64, n_embd=64, n_layer=2, n_head=2,
+        mlp_hidden=32, num_experts=8, top_k=2, dtype="float32"))
+    moe = deepspeed_tpu.init_inference(model, config={"dtype": "float32"})
+    names = {}
+    for label, eng in (("routed", moe), ("dense", engine)):
+        srv = ServingEngine(eng, {"num_slots": 2, "max_model_len": 64})
+        mark = time.perf_counter_ns()
+        srv.submit(_prompt(11), SamplingParams(max_new_tokens=3))
+        srv.step()
+        names[label] = _since(mark)
+        srv.shutdown()
+    assert not [r for r in names["dense"] if r[0].startswith("serve/moe_")]
+    by = {}
+    for r in names["routed"]:
+        by.setdefault(r[0], []).append(r)
+    prefill, = by["serve/moe_prefill"]
+    decode, = by["serve/moe_decode"]
+    # layers x (top_k .. experts) slots; the 16-token bucket's 32 picks
+    assert 2 * 2 <= prefill[3] <= 2 * 8 and 2 * 4 <= prefill[4] <= 2 * 16
+    # the tick routes both pool rows, the empty slot's dummy row too
+    assert 2 * 2 <= decode[3] <= 2 * 4 and 2 * 1 <= decode[4] <= 2 * 2
+    assert prefill[1] == prefill[2] and decode[1] == decode[2]   # instants
+    tick, = by["serve/tick"]
+    assert tick[1] <= prefill[1] <= decode[1] <= tick[2]
+    wait, = by["serve/prefill_wait"]
+    assert wait[2] <= prefill[1]        # taken after the token was read
+
+
 def test_queue_wait_spans_the_ticks_a_request_waited(engine):
     tr = get_tracer()
     srv = ServingEngine(engine, {"num_slots": 1, "max_model_len": 64})
