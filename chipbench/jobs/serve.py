@@ -9,6 +9,13 @@ request arrives; the drain that lets the window's requests finish is outside
 the timed seconds. Where the traffic says so, the window opens on a pool
 already in use: the requests a steady stream would have left running are sent
 during set-up (``warm``).
+
+A traced run of a cell whose file gives ``trace_ticks`` closes its window
+after that many ticks where they come before ``seconds`` have passed, so that
+what the trace costs to stop, load and reduce is the cell's and does not grow
+with the speed of the program under test: the timed seconds end there, and a
+request due later is never sent and enters no count. An untraced run does not
+read the key.
 """
 
 import time
@@ -107,6 +114,9 @@ def measure(ctx, seconds):
     lag, ticks, tick_at, occupancy, live_tokens = [], [], [], [], []
     backlog_mid = None
     clock = time.perf_counter
+    # a traced window is bounded in ticks too; None is never a tick count
+    tick_limit = ctx.cell.get("trace_ticks") if ctx.args.trace else None
+    reached = None if tick_limit is None else False
 
     def unfinished(sent):
         return sum(1 for i in range(sent) if i not in stream.refused
@@ -121,6 +131,10 @@ def measure(ctx, seconds):
             stream.submit(nxt)
             lag.append(clock() - (t0 + reqs[nxt]["due"]))
             nxt += 1
+        if len(ticks) == tick_limit and now - t0 < seconds:
+            seconds, reached = now - t0, True   # the window closes, below
+            del reqs[nxt:]          # due later: never sent, in no count
+            t_drain_end = now + ctx.cell["drain_seconds"]
         if backlog_mid is None and now - t0 >= seconds / 2:
             backlog_mid = unfinished(nxt)
         if now - t0 >= seconds and nxt >= len(reqs):
@@ -171,7 +185,8 @@ def measure(ctx, seconds):
     ctx.log(f"ttft p50/p95 {pct(ttft, 50):.1f}/{pct(ttft, 95):.1f} ms over "
             f"{len(ttft)}  itl p50/p95 {pct(gaps, 50):.2f}/{pct(gaps, 95):.2f}"
             f" ms  generator lag p95 {pct(lag, 95):.2f} ms")
-    return {"serve_tokens_per_s": delivered / seconds,
+    return {"window_s": seconds, "trace_ticks_reached": reached,
+            "serve_tokens_per_s": delivered / seconds,
             "ttft_p95_ms": pct(ttft, 95), "itl_p95_ms": pct(gaps, 95),
             "ttft_p50_ms": pct(ttft, 50), "itl_p50_ms": pct(gaps, 50),
             "generator_lag_ms": pct(lag, 95),

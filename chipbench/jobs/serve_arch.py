@@ -77,7 +77,7 @@ def measure(ctx, seconds):
     (``decode_wait``) or the host's share took the time."""
     t0 = time.perf_counter_ns()
     record = serve.measure(ctx, seconds)
-    _log_phase_medians(ctx, t0, t0 + int(seconds * 1e9))
+    _log_phase_medians(ctx, t0, t0 + int(record["window_s"] * 1e9))
     return record
 
 

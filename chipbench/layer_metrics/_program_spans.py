@@ -124,13 +124,12 @@ def idle_by_unit(trace, placed, device=0):
     """One ``{phase name: idle seconds of the device under it}`` for each
     unit: every idle moment inside the unit goes to the innermost program
     phase open then."""
-    idle = T.subtract([(trace.lo, trace.hi)],
-                      trace.devices[device].busy(trace.lo, trace.hi))
+    idle = trace.idle(device)
     out = []
     for unit in placed.units:
         table = {}
         for s, e, name in placed.pieces(unit):
-            c = T.total(T.clip(idle, s, e))
+            c = idle.seconds(s, e)
             if c:
                 table[name] = table.get(name, 0.0) + c
         out.append(table)

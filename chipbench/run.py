@@ -30,6 +30,7 @@ import json
 import os
 import shutil
 import sys
+import tempfile
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -154,14 +155,20 @@ def main():
         f"cache_misses={cache_misses}")
 
     if args.trace:
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        # a rehearsal leaves nothing in the checkout, and two at once (the
+        # tests' workers) do not share a directory
+        trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") \
+            if args.rehearse else TRACE_DIR
+        shutil.rmtree(trace_dir, ignore_errors=True)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
-        jax.profiler.start_trace(TRACE_DIR, profiler_options=opts)
+        jax.profiler.start_trace(trace_dir, profiler_options=opts)
     before = lowered()
     opened = [ctx.span("window")]
     opened[0].__enter__()
+
+    spent = {}      # seconds of a traced run's parts after set-up, by part
 
     def end_window():
         """Closes the traced window, once. A job whose run goes on after its
@@ -170,20 +177,28 @@ def main():
         if opened:
             opened.pop().__exit__(None, None, None)
             if args.trace:
+                t = time.perf_counter()
                 jax.profiler.stop_trace()
+                spent["stop_trace"] = time.perf_counter() - t
     ctx.end_window = end_window
+    t = time.perf_counter()
     record = job.measure(ctx, args.seconds)
     end_window()
+    spent["measure"] = time.perf_counter() - t - spent.get("stop_trace", 0.0)
     compiles_in_window = lowered() - before
     trace = None
     if args.trace:
         from chipbench import trace as trace_mod
-        trace = trace_mod.load(TRACE_DIR, allow_host_ops=args.rehearse)
-        shutil.rmtree(TRACE_DIR, ignore_errors=True)
+        t = time.perf_counter()
+        trace = trace_mod.load(trace_dir, allow_host_ops=args.rehearse)
+        spent["load"] = time.perf_counter() - t
+        shutil.rmtree(trace_dir, ignore_errors=True)
     record.update(setup_s=setup_s, compile_misses=cache_misses,
                   compiles_in_window=compiles_in_window)
 
+    t = time.perf_counter()
     checks = job.check(ctx, record)
+    spent["check"] = time.perf_counter() - t
     checks.append(("compiles_in_window", compiles_in_window, 0))
     correct = True
     for name, value, limit in checks:
@@ -201,6 +216,7 @@ def main():
 
     cell_name = args.workload
     metrics = {}
+    t = time.perf_counter()
     if args.trace:
         found = readers()
         for m in manifest["per_layer"]:
@@ -241,6 +257,15 @@ def main():
         log("idle seconds by host span: " + json.dumps(trace.idle_by_span(0)))
         log("modules: " + json.dumps(
             trace.devices[0].module_seconds(trace.lo, trace.hi)))
+        spent["readers"] = time.perf_counter() - t
+        held = collections.Counter(name for _, _, name in trace.spans)
+        log("traced run: " + " ".join(
+            f"{k}={spent[k]:.1f}s" for k in
+            ("measure", "stop_trace", "load", "check", "readers")) +
+            f"; the trace holds {sum(len(d.ops) for d in trace.devices)} "
+            f"device events and the spans {json.dumps(held)}; trace_ticks "
+            f"{cell.get('trace_ticks')} reached: "
+            f"{record.get('trace_ticks_reached')}")
     job.close(ctx)
     print(json.dumps(result), flush=True)
     return 0

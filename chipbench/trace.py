@@ -17,6 +17,7 @@ runs; such a trace is never a chip result.
     python3 chipbench/trace.py <dir-or-file>     # what a trace holds, by hand
 """
 
+import bisect
 import glob
 import gzip
 import json
@@ -68,6 +69,38 @@ def clip(intervals, lo, hi):
             if min(e, hi) > max(s, lo)]
 
 
+class Merged:
+    """Merged, sorted intervals (or ``(start, end, ...)`` pieces that do not
+    overlap) with their ends kept beside them, so that "which of them reach
+    into ``[s, e)``" is a bisection and the few that do, not a walk over all.
+    ``seconds`` adds what ``total(clip(intervals, s, e))`` adds, in the same
+    order."""
+
+    def __init__(self, intervals):
+        self.intervals = intervals
+        self._ends = [x[1] for x in intervals]
+
+    def within(self, s, e):
+        """The intervals that overlap ``[s, e)``, in order."""
+        k = bisect.bisect_right(self._ends, s)
+        while k < len(self.intervals) and self.intervals[k][0] < e:
+            yield self.intervals[k]
+            k += 1
+
+    def seconds(self, s, e):
+        return sum(min(x[1], e) - max(x[0], s)
+                   for x in self.within(s, e)) if e > s else 0.0
+
+
+def _kept(obj, key, make):
+    """``make()`` the first time ``obj`` is asked for ``key``, the same
+    result after that: a trace does not change once it is loaded, and every
+    reader asks for its union, its self times and its idle list again."""
+    if key not in obj._keep:
+        obj._keep[key] = make()
+    return obj._keep[key]
+
+
 def self_events(events):
     """``(start, end, name)`` pieces in which ``name`` is the innermost
     running operation (the one that started last): nested events (a
@@ -102,18 +135,27 @@ class Device:
         self.ops = ops              # [(start_s, end_s, op name)]
         self.modules = modules      # [(start_s, end_s, module name)]
         self.async_ops = list(async_ops)    # start-to-done spans
+        self._keep = {}             # what was worked out of ``ops``, once
 
     def busy(self, lo, hi):
-        return clip(union((s, e) for s, e, _ in self.ops), lo, hi)
+        """The union of the operations' intervals inside ``[lo, hi)``; the
+        kept list, not a copy."""
+        every = _kept(self, "union",
+                      lambda: union((s, e) for s, e, _ in self.ops))
+        return _kept(self, ("busy", lo, hi), lambda: clip(every, lo, hi))
 
     def op_self_seconds(self, lo, hi):
-        """{operation name: seconds in which it was the innermost one}."""
-        out = {}
-        for s, e, name in self_events(self.ops):
-            d = min(e, hi) - max(s, lo)
-            if d > 0:
-                out[name] = out.get(name, 0.0) + d
-        return out
+        """{operation name: seconds in which it was the innermost one}; the
+        kept table, not a copy."""
+        def add_up():
+            out = {}
+            for s, e, name in _kept(self, "self",
+                                    lambda: self_events(self.ops)):
+                d = min(e, hi) - max(s, lo)
+                if d > 0:
+                    out[name] = out.get(name, 0.0) + d
+            return out
+        return _kept(self, ("self", lo, hi), add_up)
 
     def module_seconds(self, lo, hi):
         """{module name: (count, seconds)} of programs started in the window."""
@@ -129,6 +171,7 @@ class Trace:
     def __init__(self, devices, spans):
         self.devices = devices      # [Device], by device number
         self.spans = spans          # [(start_s, end_s, name)] host spans
+        self._keep = {}
         win = [x for x in spans if x[2] == "window"]
         if win:
             self.lo, self.hi = win[0][0], win[0][1]
@@ -145,18 +188,29 @@ class Trace:
         """Seconds in which an operation ran, averaged over the devices (or
         on one)."""
         devs = self.devices if device is None else [self.devices[device]]
-        return sum(total(d.busy(self.lo, self.hi)) for d in devs) / len(devs)
+        return _kept(self, ("busy_s", device, self.lo, self.hi), lambda: sum(
+            total(d.busy(self.lo, self.hi)) for d in devs) / len(devs))
+
+    def idle(self, device=0):
+        """The moments of the window in which no operation ran on the
+        device, as ``Merged`` intervals."""
+        return _kept(self, ("idle", device, self.lo, self.hi), lambda: Merged(
+            subtract([(self.lo, self.hi)],
+                     self.devices[device].busy(self.lo, self.hi))))
+
+    def _span_pieces(self):
+        """The benchmark's host spans cut to their innermost one."""
+        return _kept(self, "pieces", lambda: Merged(self_events(
+            [x for x in self.spans if x[2] != "window"])))
 
     def gaps(self, device=0, top=10):
         """The longest idle gaps of a device inside the window, each named by
         the benchmark's host span that covers most of it."""
-        d = self.devices[device]
-        idle = subtract([(self.lo, self.hi)], d.busy(self.lo, self.hi))
-        pieces = self_events([x for x in self.spans if x[2] != "window"])
+        idle, pieces = self.idle(device).intervals, self._span_pieces()
         out = []
         for s, e in sorted(idle, key=lambda g: g[0] - g[1])[:top]:
             cover = {"between_spans": e - s}
-            for ss, se, name in pieces:
+            for ss, se, name in pieces.within(s, e):
                 c = min(e, se) - max(s, ss)
                 if c > 0:
                     cover[name] = cover.get(name, 0.0) + c
@@ -167,13 +221,10 @@ class Trace:
     def idle_by_span(self, device=0):
         """{host span name: idle seconds of the device under it}: every idle
         moment goes to the innermost benchmark span open at that time."""
-        d = self.devices[device]
-        idle = subtract([(self.lo, self.hi)], d.busy(self.lo, self.hi))
-        inner = [x for x in self.spans if x[2] != "window"]
-        pieces = self_events(inner)
-        out = {"between_spans": total(idle)}
-        for s, e, name in pieces:
-            c = total(clip(idle, s, e))
+        idle = self.idle(device)
+        out = {"between_spans": total(idle.intervals)}
+        for s, e, name in self._span_pieces().intervals:
+            c = idle.seconds(s, e)
             if c:
                 out[name] = out.get(name, 0.0) + c
                 out["between_spans"] -= c
