@@ -200,10 +200,28 @@ class InferenceEngine:
         return NamedSharding(self.mesh, P())
 
     def _cache_shardings(self, cache_shapes, rules=None):
+        """Cache-rule shardings for KV-cache leaves, with any mesh axis that
+        does not divide its dimension dropped to replication (a pool's
+        num_slots is operator-chosen and rarely divides the dp axes; the
+        pool's stored rows are ``Hk * hd / 128`` where heads are narrower
+        than 128 lanes, which 'model' need not divide although it divides
+        the heads)."""
         planner = ZeroShardingPlanner(self.mesh_manager, stage=0,
                                       rules=self._cache_rules
                                       if rules is None else rules)
-        return planner.param_shardings(cache_shapes)
+
+        def divides(ax, dim):
+            names = ax if isinstance(ax, (tuple, list)) else (ax,)
+            return dim % math.prod(self.mesh.shape[n] for n in names) == 0
+
+        def fits(sh, leaf):
+            spec = tuple(sh.spec) + (None,) * (len(leaf.shape) - len(sh.spec))
+            return NamedSharding(self.mesh, P(*(
+                ax if ax is not None and divides(ax, dim) else None
+                for ax, dim in zip(spec, leaf.shape))))
+
+        return jax.tree.map(fits, planner.param_shardings(cache_shapes),
+                            cache_shapes)
 
     def _observe_compile(self, label, fn, args, names=None):
         """Compile-ledger hook: no-op unless the serving layer attached a
@@ -596,12 +614,10 @@ class InferenceEngine:
 
     def _pool_shardings(self, num_slots: int, max_len: int,
                         quantize: bool = False, model=None):
-        """Cache-rule shardings for the slot pool, with any mesh axis that
-        does not divide its dimension dropped to replication (num_slots is
-        operator-chosen and rarely divides the dp axes; heads-over-'model'
-        TP is the sharding that matters for serving). With ``quantize``,
-        returns a QuantizedSlotPool of shardings: q leaves keep the fp
-        spec, per-column scale leaves keep it minus the trailing hd axis.
+        """``_cache_shardings`` of the slot pool (heads-over-'model' TP is
+        the sharding that matters for serving). With ``quantize``, returns
+        a QuantizedSlotPool of shardings: q leaves keep the fp spec,
+        per-column scale leaves keep it minus the trailing axis.
         ``model`` overrides the cached model (the speculative DRAFT pool
         follows the draft model's cache rules)."""
         rules = None
@@ -613,23 +629,7 @@ class InferenceEngine:
         shapes = jax.eval_shape(
             lambda: model.init_kv_cache(num_slots, max_len,
                                         dtype=self.dtype))
-        shardings = self._cache_shardings(shapes, rules=rules)
-
-        def axis_size(ax):
-            names = ax if isinstance(ax, (tuple, list)) else (ax,)
-            size = 1
-            for n in names:
-                size *= self.mesh.shape[n]
-            return size
-
-        def fix(sh, leaf):
-            spec = tuple(sh.spec) + (None,) * (len(leaf.shape) - len(sh.spec))
-            kept = tuple(ax if ax is not None and dim % axis_size(ax) == 0
-                         else None
-                         for ax, dim in zip(spec, leaf.shape))
-            return NamedSharding(self.mesh, P(*kept))
-
-        fixed = jax.tree.map(fix, shardings, shapes)
+        fixed = self._cache_shardings(shapes, rules=rules)
         if not quantize:
             return fixed
         from .kv_quant import QuantizedSlotPool
@@ -660,10 +660,10 @@ class InferenceEngine:
                 "one that raised after its dispatch): every pool program "
                 "donates its pool, so rebind the pool from the call's "
                 "return and never reuse the argument")
-        return int(leaf.shape[1]), int(leaf.shape[-2]), quantized
+        return int(leaf.shape[1]), int(leaf.shape[2]), quantized
 
     def _read_lane(self, pool, slot_idx, quantized):
-        """One slot's lane as an fp mini-cache [L, 1, H, max_len, hd]
+        """One slot's lane as an fp mini-cache [L, 1, max_len, H, hd]
         (jit-safe; dequantizes just the lane for quantized pools)."""
         if not quantized:
             return jax.tree.map(lambda leaf: _lane_slice(leaf, slot_idx),
@@ -697,11 +697,13 @@ class InferenceEngine:
 
     def init_slot_pool(self, num_slots: int, max_len: int,
                        quantize: bool = False):
-        """Allocate the slot-pool KV cache [L, num_slots, H, max_len, hd],
-        once, at static shape. ``quantize=True`` allocates it int8 with
-        per-column f32 scales (inference/kv_quant.py) — ~4x the slots per
-        HBM byte; the slot programs transparently branch on the pool
-        type."""
+        """Allocate the slot-pool KV cache [L, num_slots, max_len, H, hd],
+        once, at static shape (token-major: one token's K or V of all
+        heads is one row, so a decode step writes num_slots rows a layer;
+        ``models/gpt2.py:init_kv_cache``). ``quantize=True`` allocates it
+        int8 with per-row f32 scales [L, num_slots, max_len, H]
+        (inference/kv_quant.py) — ~4x the slots per HBM byte; the slot
+        programs transparently branch on the pool type."""
         key = ("slot_pool", num_slots, max_len) + \
             (("q8",) if quantize else ())
         fn = self._slot_fns.get(key)
@@ -1178,8 +1180,7 @@ class InferenceEngine:
         donated. Returns the new draft pool."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         t = prompt.shape[0]
-        num_slots = int(jax.tree.leaves(dpool)[0].shape[1])
-        max_len = int(jax.tree.leaves(dpool)[0].shape[-2])
+        num_slots, max_len, _ = self._pool_dims(dpool)
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
         bucket = min(_next_pow2(t), max_len)
@@ -1218,8 +1219,7 @@ class InferenceEngine:
         that maximizes exact-match acceptance. Draft pool donated.
         Returns (new_dpool, draft_tokens [S, k])."""
         vocab = draft.model.config.vocab_size
-        num_slots = int(jax.tree.leaves(dpool)[0].shape[1])
-        max_len = int(jax.tree.leaves(dpool)[0].shape[-2])
+        num_slots, max_len, _ = self._pool_dims(dpool)
         fkey = ("slot_draft", num_slots, max_len, int(k), draft.key)
         fn = self._slot_fns.get(fkey)
         if fn is None:
@@ -1321,34 +1321,21 @@ class InferenceEngine:
                     cols_ax = jnp.arange(max_len)[None, :]
                     keep = (cols_ax >= positions[:, None]) & \
                         (cols_ax <= (positions + accepts)[:, None])  # [S, C]
-                    if quantized:
-                        # restore in QUANTIZED space: original q/scale
-                        # BYTES are copied verbatim for every non-kept
-                        # column, so rolled-back int8 lanes are bit-exact
-                        # — the untouched-column guarantee by
-                        # construction, immune even to ulp-level
-                        # requantization drift
-                        newq = quantize_pool(fp_new)
 
-                        def rbq(new, old):
-                            return jnp.where(keep[None, :, None, :, None],
-                                             new, old)
+                    def rb(new, old):
+                        # keep [S, C] over a leaf [L, S, C, ...]
+                        return jnp.where(keep.reshape(
+                            (1,) + keep.shape + (1,) * (new.ndim - 3)),
+                            new, old)
 
-                        def rbs(new, old):
-                            return jnp.where(keep[None, :, None, :],
-                                             new, old)
-
-                        from .kv_quant import QuantizedSlotPool
-                        out_pool = QuantizedSlotPool(
-                            q=jax.tree.map(rbq, newq.q, pool.q),
-                            scales=jax.tree.map(rbs, newq.scales,
-                                                pool.scales))
-                    else:
-                        def rb(new, old):
-                            return jnp.where(keep[None, :, None, :, None],
-                                             new, old)
-
-                        out_pool = jax.tree.map(rb, fp_new, fp_old)
+                    # an int8 pool is restored in QUANTIZED space: original
+                    # q/scale BYTES are copied verbatim for every non-kept
+                    # column, so rolled-back int8 lanes are bit-exact — the
+                    # untouched-column guarantee by construction, immune
+                    # even to ulp-level requantization drift
+                    out_pool = jax.tree.map(
+                        rb, quantize_pool(fp_new) if quantized else fp_new,
+                        pool)
                 return out_pool, tgt, accepts.astype(jnp.int32)
 
             fn = self._slot_fns[fkey] = jax.jit(ver, in_shardings=(

@@ -1,8 +1,9 @@
 """Quantized KV slot pool — int8 cache lanes with per-column scales.
 
 The serving slot pool (`serving/kv_slots.py`) is the HBM budget of a
-decode replica: `[L, num_slots, H, max_model_len, hd]` in the model
-dtype, resident for the process lifetime. Storing it int8 multiplies the
+decode replica: `[L, num_slots, max_model_len, H, hd]` in the model
+dtype (token-major: `models/gpt2.py:init_kv_cache`), resident for the
+process lifetime. Storing it int8 multiplies the
 concurrent slots a replica can hold per HBM byte by ~3-4x (1 byte/value
 plus one f32 scale per `hd` values, vs 4 for fp32), which is the
 difference between 8 and 30 concurrent users per replica at the same
@@ -10,8 +11,10 @@ budget — the ZeRO++-style trade (arxiv 2306.10209) applied to KV state
 instead of wire traffic, via the same `ops/quant_core` scale math.
 
 Scale granularity is **per cache column** (one f32 scale per
-`[layer, slot, head, position]`, absmax over the `hd` values of that
-column). Per-column scales are what make an *incrementally written*
+`[layer, slot, position, head]`: the scales are the pool's leaves without
+their trailing axis, `[L, num_slots, max_model_len, H]`; absmax over the
+values of one stored row, which is one head's `hd`, or the `128 // hd`
+heads that share a row where `hd` is under 128). Per-column scales are what make an *incrementally written*
 quantized cache sound: prefill and decode touch whole columns, so a
 write re-quantizes only the columns it produced, and the round-trip
 `quantize(dequantize(q))` of every untouched column is exact (the absmax

@@ -77,5 +77,5 @@ class BloomModel(GPT2Model):
                 jnp.arange(t, dtype=jnp.float32)[None, None, None, :])
 
     def _decode_attn_bias(self, q_pos, k_pos):
-        return (self._slopes[None, :, None, None] *
-                k_pos[None, None].astype(jnp.float32))
+        # [1, H, 1, max_len] (k_pos arrives [1, 1, 1, max_len])
+        return self._slopes[None, :, None, None] * k_pos.astype(jnp.float32)

@@ -110,6 +110,24 @@ def _params_compute_dtype(params, fallback):
             else jnp.dtype(fallback))
 
 
+#: lanes of a TPU vector row: XLA pads a narrower minor dimension up to it
+_LANES = 128
+
+
+def _kv_row_shape(kv_heads: int, head_dim: int):
+    """``(G, W)``: how the KV pool stores one token's ``Hk * hd`` values of a
+    layer. ``(Hk, hd)``; but where a head is narrower than a vector row and
+    a whole number of heads fills one (hd 64: two), that many heads share a
+    stored row, ``(Hk * hd / 128, 128)`` — the same bytes in the same
+    order. On the chip a minor dimension under 128 lanes is padded up to it:
+    the unpacked shape would make the compiler double a bf16 hd-64 pool, or
+    lay it out position-minor and copy all of it to write one token."""
+    pack = _LANES // head_dim if _LANES % head_dim == 0 else 1
+    if kv_heads % pack:
+        pack = 1
+    return kv_heads // pack, head_dim * pack
+
+
 def _layer_norm(x, scale, bias, eps):
     return me.layer_norm(x, scale, bias, eps)
 
@@ -610,18 +628,82 @@ class GPT2Model(ModelSpec):
     # attention with KV-cache append). Functional: the cache is a pytree the
     # caller threads through compiled prefill/decode steps.
     def init_kv_cache(self, batch_size: int, max_len: int, dtype=jnp.bfloat16):
+        """The KV pool: ``k`` and ``v`` leaves ``[L, S, max_len, Hk, hd]``,
+        token-major. One token's K (or V) of all heads is one contiguous
+        row of ``Hk * hd``, a prefill's T tokens are one contiguous block,
+        and a layer's slab ``[S, max_len, Hk, hd]`` is contracted against
+        where it lies. Heads narrower than a vector row are stored
+        ``128 // hd`` to a row (``_kv_row_shape``): the same bytes in the
+        same order. ``_kv_write`` and ``_kv_attend`` are the only code that
+        indexes inside a lane."""
         cfg = self.config
-        shape = (cfg.n_layer, batch_size, self.kv_heads, max_len, cfg.head_dim)
+        shape = (cfg.n_layer, batch_size, max_len) + \
+            _kv_row_shape(self.kv_heads, cfg.head_dim)
         return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
+    @staticmethod
+    def _kv_write(pool, layer, new, start):
+        """Write ``new`` [S, T, Hk, hd] into layer ``layer`` of one pool leaf
+        and return the leaf; nothing but those S * T rows is touched, so a
+        donated pool carried through the layer scan is updated in place.
+        ``start`` is a scalar (every row's block begins at column
+        ``start``: one ``dynamic_update_slice``) or [S] (row s's block
+        begins at ``start[s]``: an indexed update of S * T rows, of which
+        those at or past ``max_len`` are dropped)."""
+        s, t = new.shape[:2]
+        new = new.reshape((s, t) + pool.shape[3:]).astype(pool.dtype)
+        if jnp.ndim(start) == 0:
+            return lax.dynamic_update_slice(pool, new[None],
+                                            (layer, 0, start, 0, 0))
+        cols = start[:, None] + jnp.arange(t)[None, :]            # [S, T]
+        return pool.at[layer, jnp.arange(s)[:, None], cols].set(
+            new, mode="drop", unique_indices=True, indices_are_sorted=True)
+
+    @staticmethod
+    def _kv_attend(q, k_pool, v_pool, layer, mask, bias):
+        """Attention of ``q`` [S, H, T, hd] over every column of layer
+        ``layer``'s slab of the pool, read where it lies: no copy, no
+        transpose, and grouped KV heads are contracted per group, not
+        repeated. ``mask`` (keep) and ``bias`` (additive or None) broadcast
+        to [S, H, T, max_len]. The mathematics of ``reference_attention``:
+        scores in q's dtype, bias, mask and softmax in float32. Where a
+        stored row holds several heads (``_kv_row_shape``), each query is
+        laid into its own head's lanes of a zero row and the output taken
+        from them: the products with the other heads' lanes are exact
+        zeros."""
+        ks = lax.dynamic_index_in_dim(k_pool, layer, 0, keepdims=False)
+        vs = lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
+        s, h, t, hd = q.shape
+        max_len, g, w = ks.shape[1:]
+        pack = w // hd                   # KV heads to a stored row
+        rep = h // (g * pack)            # query heads to a KV head
+        # own[j, j']: head j of a row owns lane block j'
+        own = jnp.eye(pack, dtype=q.dtype)[:, None, None, :, None]
+        qg = q.reshape(s, g, pack, rep, t, 1, hd)
+        qg = (qg * own).reshape(s, g, pack * rep, t, w)
+        logits = jnp.einsum("bgrqw,bkgw->bgrqk", qg, ks.astype(q.dtype)) * \
+            (1.0 / jnp.sqrt(hd))
+        logits = logits.reshape(s, h, t, max_len).astype(jnp.float32)
+        if bias is not None:
+            logits = logits + bias.astype(jnp.float32)
+        logits = jnp.where(mask, logits, -1e30)
+        probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+        out = jnp.einsum("bgrqk,bkgw->bgrqw",
+                         probs.reshape(s, g, pack * rep, t, max_len),
+                         vs.astype(q.dtype))
+        out = out.reshape(s, g, pack, rep, t, pack, hd)
+        return (out * own).sum(axis=5).reshape(s, h, t, hd)
+
     def _decode_attn_mask(self, q_pos, k_pos):
-        """[T, max_len] boolean keep-mask over the KV cache. Sliding-window
-        families tighten it."""
+        """Boolean keep-mask over the cache columns: ``q_pos`` [B|1, 1, T, 1]
+        against ``k_pos`` [1, 1, 1, max_len]. Sliding-window families
+        tighten it."""
         return k_pos <= q_pos
 
     def _decode_attn_bias(self, q_pos, k_pos):
-        """Additive attention bias on the decode path ([H, T, max_len] or
-        None). ALiBi families override."""
+        """Additive attention bias on the cache path (broadcastable to
+        [B, H, T, max_len], or None), from the same ``q_pos`` / ``k_pos``
+        as the mask. ALiBi families override."""
         return None
 
     def _train_attn_bias(self, t):
@@ -629,85 +711,75 @@ class GPT2Model(ModelSpec):
         None). ALiBi families override."""
         return None
 
-    def apply_with_cache(self, params, input_ids, cache, start_pos,
-                         pad_counts=None, routing=False):
-        """Forward with KV cache. input_ids: [B, T] (prompt for prefill,
-        [B, 1] for decode); start_pos: traced scalar — tokens occupy cache
-        columns [start_pos, start_pos+T). ``pad_counts`` [B]: number of
-        LEFT-padding tokens per row (serving batches of uneven prompts) —
-        cache columns below pad_counts[b] are masked out and logical
-        positions shift down by pad_counts[b] (ALiBi needs no shift: a
-        per-row constant is softmax-invariant). Returns (logits [B,T,V],
-        new_cache); with ``routing=True`` also the routed expert layers'
-        stats (``_cache_return``)."""
-        cfg = self.config
-        b, t = input_ids.shape
-        max_len = cache["k"].shape[-2]
+    def _forward_with_cache(self, params, input_ids, cache, start,
+                            pad_counts=None, routing=False):
+        """The one cached forward behind ``apply_with_cache`` (``start`` a
+        scalar), ``decode_with_slots`` and ``verify_with_slots`` (``start``
+        [S], one cache position per row): input_ids [S, T]; row s's token j
+        is written at column ``start + j`` and attends the columns the
+        model's mask keeps at or below it. The pool is carried through the
+        layer scan (not scanned over), each layer writes its S * T rows
+        (``_kv_write``) and reads its slab where it lies (``_kv_attend``):
+        with the pool donated to the jitted program XLA aliases it through
+        the loop, and a step writes the new tokens' K and V and nothing
+        else."""
+        s, t = input_ids.shape
+        max_len = cache["k"].shape[2]
         compute_dtype = self._compute_dtype(params)
-        positions = None
-        if pad_counts is not None:
-            positions = jnp.maximum(
-                (start_pos + jnp.arange(t))[None, :] - pad_counts[:, None], 0)
+        if jnp.ndim(start) != 0:
+            positions = start[:, None] + jnp.arange(t)[None, :]      # [S, T]
+            q_pos = positions[:, None, :, None]
+            start_pos = jnp.int32(0)
+        else:
+            cols = (start + jnp.arange(t))[None, :]                  # [1, T]
+            q_pos = cols[:, None, :, None]
+            start_pos = start
+            positions = None if pad_counts is None else \
+                jnp.maximum(cols - pad_counts[:, None], 0)
         x = self._embed(params, input_ids, start_pos=start_pos,
                         positions=positions)
-
-        # attention mask over the cache: key position <= query position
-        q_pos = start_pos + jnp.arange(t)[:, None]
-        k_pos = jnp.arange(max_len)[None, :]
-        extras = self._layer_extras()
+        k_pos = jnp.arange(max_len)[None, None, None, :]
         pad_valid = None
         if pad_counts is not None:     # left-pad columns are never valid keys
-            pad_valid = jnp.arange(max_len)[None, :] >= pad_counts[:, None]
-        base_mask = None
-        if extras is None:             # layer-independent: compute once
-            base_mask = self._decode_attn_mask(q_pos, k_pos)[None, None]
-            if pad_valid is not None:
-                base_mask = base_mask & pad_valid[:, None, None, :]
-        bias = self._decode_attn_bias(q_pos, k_pos)  # [H, T, max_len] | None
+            pad_valid = (jnp.arange(max_len)[None, :] >=
+                         pad_counts[:, None])[:, None, None, :]
+        extras = self._layer_extras()
 
-        from ..ops.flash_attention import reference_attention
+        def keep_mask(extra):
+            mask = self._decode_attn_mask_ex(q_pos, k_pos, extra)
+            return mask if pad_valid is None else mask & pad_valid
 
-        def body(x, xs):
-            if extras is None:
-                (layer_params, k_cache, v_cache), extra = xs, None
-                mask = base_mask
-            else:
-                layer_params, k_cache, v_cache, extra = xs
-                mask = self._decode_attn_mask_ex(q_pos, k_pos,
-                                                 extra)[None, None]
-                if pad_valid is not None:
-                    mask = mask & pad_valid[:, None, None, :]
+        base_mask = keep_mask(None) if extras is None else None
+        bias = self._decode_attn_bias(q_pos, k_pos)
+
+        def body(carry, xs):
+            x, k_pool, v_pool = carry
+            layer_params, layer, extra = xs
+            mask = base_mask if extras is None else keep_mask(extra)
             new_kv = {}
 
             def cached_attn(q, k, v):
-                # kv_write / kv_read scopes nest inside "attn" and take
-                # precedence in the perf plane's bucket classifier, so
-                # cache traffic is attributed as bytes, not attention math
+                # q, k, v arrive [S, H, T, hd]. kv_write / kv_read scopes
+                # nest inside "attn" and take precedence in the perf
+                # plane's bucket classifier, so cache traffic is
+                # attributed as bytes, not attention math
                 with jax.named_scope("kv_write"):
-                    kc = lax.dynamic_update_slice(
-                        k_cache, k.astype(k_cache.dtype),
-                        (0, 0, start_pos, 0))
-                    vc = lax.dynamic_update_slice(
-                        v_cache, v.astype(v_cache.dtype),
-                        (0, 0, start_pos, 0))
-                new_kv["k"], new_kv["v"] = kc, vc
+                    kp = self._kv_write(k_pool, layer,
+                                        k.transpose(0, 2, 1, 3), start)
+                    vp = self._kv_write(v_pool, layer,
+                                        v.transpose(0, 2, 1, 3), start)
+                new_kv["k"], new_kv["v"] = kp, vp
                 with jax.named_scope("kv_read"):
-                    kq, vq = kc.astype(q.dtype), vc.astype(q.dtype)
-                    if q.shape[1] != kq.shape[1]:    # GQA: repeat kv heads
-                        rep = q.shape[1] // kq.shape[1]
-                        kq = jnp.repeat(kq, rep, axis=1)
-                        vq = jnp.repeat(vq, rep, axis=1)
-                return reference_attention(q, kq, vq, causal=False, mask=mask,
-                                           bias=bias)
+                    return self._kv_attend(q, kp, vp, layer, mask, bias)
 
             x, stats = self._split_routing(self._decode_block(
                 x, layer_params, cached_attn, start_pos,
                 positions=positions, extra=extra))
-            return x, (new_kv["k"], new_kv["v"], stats)
+            return (x, new_kv["k"], new_kv["v"]), stats
 
-        xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
-            else (params["blocks"], cache["k"], cache["v"], extras)
-        x, (new_k, new_v, stats) = lax.scan(body, x, xs)
+        xs = (params["blocks"], jnp.arange(self.config.n_layer), extras)
+        (x, new_k, new_v), stats = lax.scan(
+            body, (x, cache["k"], cache["v"]), xs)
         x = self._final_norm(params, x)
         logits = x @ self._unembed_weight(params, compute_dtype).T
         head_b = self._head_bias(params, logits.dtype)
@@ -715,6 +787,21 @@ class GPT2Model(ModelSpec):
             logits = logits + head_b
         return self._cache_return(logits, {"k": new_k, "v": new_v}, stats,
                                   routing)
+
+    def apply_with_cache(self, params, input_ids, cache, start_pos,
+                         pad_counts=None, routing=False):
+        """Forward with KV cache. input_ids: [B, T] (prompt for prefill,
+        [B, 1] for decode); start_pos: traced scalar — tokens occupy cache
+        columns [start_pos, start_pos+T), one contiguous block a row.
+        ``pad_counts`` [B]: number of LEFT-padding tokens per row (serving
+        batches of uneven prompts) — cache columns below pad_counts[b] are
+        masked out and logical positions shift down by pad_counts[b] (ALiBi
+        needs no shift: a per-row constant is softmax-invariant). Returns
+        (logits [B,T,V], new_cache); with ``routing=True`` also the routed
+        expert layers' stats (``_cache_return``)."""
+        return self._forward_with_cache(params, input_ids, cache, start_pos,
+                                        pad_counts=pad_counts,
+                                        routing=routing)
 
     def chunk_prefill_with_cache(self, params, input_ids, cache, start_pos):
         """K/V-write-only forward for chunked prefill: one chunk of a
@@ -742,72 +829,16 @@ class GPT2Model(ModelSpec):
 
         input_ids [S, 1]; positions [S] (traced): row s's token K/V is
         written at cache column positions[s] and attends columns
-        <= positions[s]. Unlike apply_with_cache's scalar ``start_pos``
-        (shared dynamic_update_slice column), the per-row write is a masked
-        select over the column axis — static shapes, no gather/scatter, so
-        the step compiles exactly once per (S, max_len). Returns
-        (logits [S, 1, V], new_cache); ``routing`` as in
-        ``apply_with_cache``."""
-        b, t = input_ids.shape
+        <= positions[s]. The pool is token-major, so the write is S rows of
+        ``Hk * hd`` a layer at ``[layer, s, positions[s]]`` — shapes are
+        static, the step compiles exactly once per (S, max_len), and no
+        other byte of the pool is written. Returns (logits [S, 1, V],
+        new_cache); ``routing`` as in ``apply_with_cache``."""
+        t = input_ids.shape[1]
         if t != 1:
             raise ValueError(f"decode_with_slots is single-token: got T={t}")
-        max_len = cache["k"].shape[-2]
-        compute_dtype = self._compute_dtype(params)
-        pos2d = positions[:, None]                       # [S, 1]
-        x = self._embed(params, input_ids, positions=pos2d)
-        k_pos = jnp.arange(max_len)[None, :]             # [1, max_len]
-        extras = self._layer_extras()
-        base_mask = None
-        if extras is None:
-            base_mask = self._decode_attn_mask(pos2d, k_pos)[:, None, None, :]
-        bias = self._decode_attn_bias(pos2d, k_pos)
-        write = (k_pos == pos2d)[:, None, :, None]       # [S, 1, max_len, 1]
-
-        from ..ops.flash_attention import reference_attention
-
-        def body(x, xs):
-            if extras is None:
-                (layer_params, k_cache, v_cache), extra = xs, None
-                mask = base_mask
-            else:
-                layer_params, k_cache, v_cache, extra = xs
-                mask = self._decode_attn_mask_ex(pos2d, k_pos,
-                                                 extra)[:, None, None, :]
-            new_kv = {}
-
-            def cached_attn(q, k, v):
-                # per-row masked-select write touches the WHOLE pool lane;
-                # the kv_write/kv_read scopes let the perf plane price it
-                # as HBM bytes (ROADMAP item 2's decode-is-bandwidth-bound
-                # evidence) instead of folding it into attention math
-                with jax.named_scope("kv_write"):
-                    kc = jnp.where(write, k.astype(k_cache.dtype), k_cache)
-                    vc = jnp.where(write, v.astype(v_cache.dtype), v_cache)
-                new_kv["k"], new_kv["v"] = kc, vc
-                with jax.named_scope("kv_read"):
-                    kq, vq = kc.astype(q.dtype), vc.astype(q.dtype)
-                    if q.shape[1] != kq.shape[1]:    # GQA: repeat kv heads
-                        rep = q.shape[1] // kq.shape[1]
-                        kq = jnp.repeat(kq, rep, axis=1)
-                        vq = jnp.repeat(vq, rep, axis=1)
-                return reference_attention(q, kq, vq, causal=False, mask=mask,
-                                           bias=bias)
-
-            x, stats = self._split_routing(self._decode_block(
-                x, layer_params, cached_attn, jnp.int32(0), positions=pos2d,
-                extra=extra))
-            return x, (new_kv["k"], new_kv["v"], stats)
-
-        xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
-            else (params["blocks"], cache["k"], cache["v"], extras)
-        x, (new_k, new_v, stats) = lax.scan(body, x, xs)
-        x = self._final_norm(params, x)
-        logits = x @ self._unembed_weight(params, compute_dtype).T
-        head_b = self._head_bias(params, logits.dtype)
-        if head_b is not None:
-            logits = logits + head_b
-        return self._cache_return(logits, {"k": new_k, "v": new_v}, stats,
-                                  routing)
+        return self._forward_with_cache(params, input_ids, cache, positions,
+                                        routing=routing)
 
     def verify_with_slots(self, params, input_ids, cache, positions):
         """Multi-token block forward with PER-ROW cache positions — the
@@ -820,87 +851,22 @@ class GPT2Model(ModelSpec):
         slot in ONE forward — the trade XLA rewards: T target positions
         for one weight pass instead of T sequential decode dispatches.
 
-        input_ids [S, T]; positions [S] (traced). Like
-        ``decode_with_slots`` the per-row block write is a masked select
-        over the column axis (a one-hot [S, T, max_len] contraction —
-        static shapes, no scatter), so each (S, max_len, T) flavor
-        compiles exactly once. Writes whose column would land at or past
-        ``max_len`` match no column and are dropped; their logits are
-        garbage by construction and the serving layer never consumes
-        them (a request's budget keeps every live position in range).
-        Returns (logits [S, T, V], new_cache). T=1 is semantically
-        ``decode_with_slots`` (which stays the steady-state program —
-        its compiled flavor is pinned by the serving tests)."""
-        b, t = input_ids.shape
-        max_len = cache["k"].shape[-2]
-        compute_dtype = self._compute_dtype(params)
-        pos2d = positions[:, None] + jnp.arange(t)[None, :]   # [S, T]
-        x = self._embed(params, input_ids, positions=pos2d)
-        k_pos = jnp.arange(max_len)[None, None, :]            # [1, 1, max_len]
-        q_pos = pos2d[:, :, None]                             # [S, T, 1]
-        extras = self._layer_extras()
-        base_mask = None
-        if extras is None:
-            base_mask = self._decode_attn_mask(q_pos, k_pos)[:, None]
-        bias = self._decode_attn_bias(q_pos, k_pos)
-        # one-hot block write: token j of row s owns column positions[s]+j
-        write = (jnp.arange(max_len)[None, None, :] ==
-                 pos2d[:, :, None])                           # [S, T, C]
-        wrote = write.any(axis=1)                             # [S, C]
-
-        from ..ops.flash_attention import reference_attention
-
-        def body(x, xs):
-            if extras is None:
-                (layer_params, k_cache, v_cache), extra = xs, None
-                mask = base_mask
-            else:
-                layer_params, k_cache, v_cache, extra = xs
-                mask = self._decode_attn_mask_ex(q_pos, k_pos,
-                                                 extra)[:, None]
-            new_kv = {}
-
-            def cached_attn(q, k, v):
-                # k/v [S, H, T, hd] -> scatter-free block write [S, H, C, hd]
-                with jax.named_scope("kv_write"):
-                    kin = jnp.einsum(
-                        "stc,shtd->shcd", write.astype(jnp.float32),
-                        k.astype(jnp.float32)).astype(k_cache.dtype)
-                    vin = jnp.einsum(
-                        "stc,shtd->shcd", write.astype(jnp.float32),
-                        v.astype(jnp.float32)).astype(v_cache.dtype)
-                    sel = wrote[:, None, :, None]
-                    kc = jnp.where(sel, kin, k_cache)
-                    vc = jnp.where(sel, vin, v_cache)
-                new_kv["k"], new_kv["v"] = kc, vc
-                with jax.named_scope("kv_read"):
-                    kq, vq = kc.astype(q.dtype), vc.astype(q.dtype)
-                    if q.shape[1] != kq.shape[1]:    # GQA: repeat kv heads
-                        rep = q.shape[1] // kq.shape[1]
-                        kq = jnp.repeat(kq, rep, axis=1)
-                        vq = jnp.repeat(vq, rep, axis=1)
-                return reference_attention(q, kq, vq, causal=False, mask=mask,
-                                           bias=bias)
-
-            x, _ = self._split_routing(self._decode_block(
-                x, layer_params, cached_attn, jnp.int32(0), positions=pos2d,
-                extra=extra))
-            return x, (new_kv["k"], new_kv["v"])
-
-        xs = (params["blocks"], cache["k"], cache["v"]) if extras is None \
-            else (params["blocks"], cache["k"], cache["v"], extras)
-        x, (new_k, new_v) = lax.scan(body, x, xs)
-        x = self._final_norm(params, x)
-        logits = x @ self._unembed_weight(params, compute_dtype).T
-        head_b = self._head_bias(params, logits.dtype)
-        if head_b is not None:
-            logits = logits + head_b
-        return logits, {"k": new_k, "v": new_v}
+        input_ids [S, T]; positions [S] (traced). The write is
+        ``decode_with_slots``'s with T rows a slot (S * T rows of
+        ``Hk * hd`` a layer), so each (S, max_len, T) flavor compiles
+        exactly once. Writes whose column would land at or past
+        ``max_len`` are dropped; their logits are garbage by construction
+        and the serving layer never consumes them (a request's budget keeps
+        every live position in range). Returns (logits [S, T, V],
+        new_cache). T=1 is ``decode_with_slots`` bit for bit (which stays
+        the steady-state program — its compiled flavor is pinned by the
+        serving tests)."""
+        return self._forward_with_cache(params, input_ids, cache, positions)
 
     def cache_partition_rules(self):
         """Sharding for the KV cache: heads over 'model' (TP), batch over the
         dp axes."""
-        return [(r"(k|v)$", (None, ("data", "expert"), "model", None, None))]
+        return [(r"(k|v)$", (None, ("data", "expert"), None, "model", None))]
 
     def flops_per_token(self, seq_len: Optional[int] = None):
         """Training FLOPs/token: 6N + attention term (12·L·D·T)."""

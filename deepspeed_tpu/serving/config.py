@@ -424,8 +424,8 @@ class CostConfig(DeepSpeedConfigModel):
 class ServingConfig(DeepSpeedConfigModel):
     """Continuous-batching serving knobs (deepspeed_tpu/serving/)."""
 
-    # slot pool: one statically-shaped KV cache [L, num_slots, H,
-    # max_model_len, hd], allocated once — admission never reshapes it
+    # slot pool: one statically-shaped KV cache [L, num_slots,
+    # max_model_len, H, hd], allocated once — admission never reshapes it
     num_slots: int = 8
     max_model_len: int = 512          # KV-cache columns per slot
 

@@ -58,8 +58,9 @@ def _unflatten_lane(pairs: Dict[str, np.ndarray], quantized: bool):
 class KVHandoff:
     """One completed prefill, ready for a decode pool.
 
-    ``lane`` is a host pytree shaped like one pool slot (``[L, 1, H,
-    max_len, hd]`` leaves, or the q/scales pair for quantized pools);
+    ``lane`` is a host pytree shaped like one pool slot (``[L, 1,
+    max_len, H, hd]`` leaves as ``init_kv_cache`` shapes them, or the
+    q/scales pair for quantized pools; a frame carries each leaf's shape);
     ``kv_len`` says how many columns are valid — the insert copies the
     whole lane and the decode mask never reads past ``kv_len`` until the
     columns are rewritten. ``first_token`` was already sampled (and

@@ -3,7 +3,9 @@
 The TPU answer to GPU paged attention: instead of dynamically growing
 per-request caches (vLLM-style block tables — pointer chasing XLA cannot
 compile to a fixed program), the pool is ONE statically-shaped cache
-``[L, num_slots, H, max_model_len, hd]`` allocated at startup. A request is
+``[L, num_slots, max_model_len, H, hd]`` allocated at startup (token-major:
+one token's K or V of all heads is one row, so a decode tick writes
+``num_slots`` rows a layer and nothing else). A request is
 admitted by claiming a free slot (prefill overwrites the slot's whole lane),
 advanced by the fused all-slot decode step, and retired by returning the
 slot to the free list — no shape ever changes, so the decode step compiles

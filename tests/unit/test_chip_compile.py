@@ -112,8 +112,8 @@ def test_block_sparse_fwd_bwd(one_chip, t):
 def test_slot_prefill_writes_its_lane_in_place(one_chip, quantized):
     """The chip's compiler keeps the alias the engine asks for
     (``donate_argnums``): over a 28-slot pool of ``opt-1.3b.serve-chat``'s
-    lane shape (32 heads x 1024 columns x 64, bf16 or int8 + scales; two
-    layers) the whole pool is aliased to the output and the program's
+    lane shape (1024 columns x 32 heads x 64, stored as 16 rows of 128 a
+    column, bf16 or int8 + scales; two layers) the whole pool is aliased to the output and the program's
     temporaries are a lane's size, not a pool's. XLA drops an alias
     silently where the layouts of input and output differ; that would show
     here as ``alias_size_in_bytes`` 0 and, on the chip, as a second pool
@@ -155,3 +155,48 @@ def test_slot_prefill_writes_its_lane_in_place(one_chip, quantized):
     first = len(jax.tree.leaves(params)) + 1
     assert donated_params_from_hlo(compiled.as_text()) == set(
         range(first, first + len(jax.tree.leaves(pool))))
+
+
+def test_slot_decode_writes_its_rows_in_place(one_chip):
+    """The decode step of ``opt-1.3b.serve-chat``'s pool (28 slots x 1024
+    columns x 32 heads of 64; two layers), compiled for the chip: the
+    donated pool is aliased to the output and carried through the layer
+    loop in the layout it arrives in, so the program's temporaries stay
+    under one lane's bytes (7.4 MB of 16.8; the parent's program, which
+    rewrote every lane, kept 1.41 GB beside this 0.47 GB pool). A pool
+    whose rows are narrower than 128 lanes fails here: the compiler then
+    carries it padded to twice its size and copies all of it in and out of
+    the loop (0.94 GB of temporaries: ``_kv_row_shape``)."""
+    import deepspeed_tpu
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+
+    slots, max_len = 28, 1024
+    model = OPTModel(OPTConfig(vocab_size=512, n_positions=max_len,
+                               n_embd=2048, n_layer=2, n_head=32,
+                               dtype="bfloat16"))
+    engine = deepspeed_tpu.init_inference(
+        model, config={"dtype": "bfloat16", "max_tokens": max_len})
+    tiny = engine.init_slot_pool(1, max_len)
+    assert tiny["k"].shape == (2, 1, max_len, 16, 128)
+    zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+    tiny, _ = engine.slot_decode_step(tiny, zi, zi, zf)
+    fn = engine._slot_fns[("slot_decode", 1, max_len)]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    # dec(params, pool, toks, positions, temps, top_ks, top_ps, seeds)
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        params, pool, vi, vi, vf, vi, vf, vi).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert mem.temp_size_in_bytes < pool_nbytes(pool) // slots
+    first = len(jax.tree.leaves(params))
+    assert donated_params_from_hlo(compiled.as_text()) == {first, first + 1}

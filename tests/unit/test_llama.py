@@ -46,7 +46,7 @@ def test_llama_generates_with_gqa_cache():
     assert out.shape == (1, 12)
     # cache carries n_kv_head (not n_head) heads
     cache = model.init_kv_cache(1, 16)
-    assert cache["k"].shape[2] == TINY.n_kv_head
+    assert cache["k"].shape[3] == TINY.n_kv_head
 
 
 def test_llama_cache_matches_full_forward():

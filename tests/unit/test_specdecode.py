@@ -204,9 +204,9 @@ def test_int8_lane_rollback_exactness(engine):
     # non-speculative decode step
     for tree_b, tree_a in ((before.q, after.q), (before.scales, after.scales)):
         for name in tree_b:
-            b, a = tree_b[name][:, 0], tree_a[name][:, 0]  # [L, H, C(, hd)]
+            b, a = tree_b[name][:, 0], tree_a[name][:, 0]  # [L, C, H(, hd)]
             mask = np.ones(b.shape, bool)
-            mask[:, :, col] = False
+            mask[:, col] = False
             np.testing.assert_array_equal(b[mask], a[mask])
 
 
