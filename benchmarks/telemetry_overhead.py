@@ -19,13 +19,6 @@ router aggregator folding completed paths into dstpu_fleet_path_*
 gauges, flight recorder recording every tick) — and asserts the armed
 fleet's median decode tick stays < 2% slower.
 
-A ninth interleaved mode, "anat", arms the perf plane on top of the
-compile plane: the warmup compile pays one static HLO anatomy pass
-(bucket decomposition + roofline attribution + per-bucket gauges), and
-the steady-state loop — with a stable program, so no recompile and no
-``perf_regression`` trigger — must show the same < 2% overhead,
-because anatomy work only happens at compile-ledger events.
-
 An eighth interleaved comparison, "cost", isolates the cost plane: two
 identical single-replica serving stacks run the same request rounds,
 one with per-request chip-second attribution dark (``cost.enabled``
@@ -78,7 +71,7 @@ THRESHOLD_PCT = float(os.environ.get("TEL_THRESHOLD_PCT", 2.0))
 
 def build_engine(telemetry_enabled: bool, full: bool = False,
                  recorder_dir: str = "", compile_plane: bool = False,
-                 elastic: bool = False, perf_plane: bool = False):
+                 elastic: bool = False):
     model = GPT2Model(GPT2Config(
         vocab_size=256, n_positions=128,
         n_embd=int(os.environ.get("TEL_EMBD", 128)),
@@ -111,11 +104,6 @@ def build_engine(telemetry_enabled: bool, full: bool = False,
         # cadences. Compile events only happen during warmup; what this
         # measures is the steady-state fingerprint + ledger cost.
         "compile_plane": {"enabled": compile_plane},
-        # anat mode: the perf plane armed on top of the compile plane —
-        # every compile-ledger event pays a static HLO anatomy pass, and
-        # the steady-state loop pays... nothing (anatomy only runs at
-        # compile/recompile). This asserts exactly that.
-        "perf_plane": {"enabled": perf_plane},
         # el mode: hostagg heartbeats EVERY step (worst-case cadence)
         # feeding a dark ElasticCoordinator — one gather + one dict
         # inspection per step when no host is missing
@@ -305,18 +293,16 @@ def main():
     # one engine per mode; steps run in INTERLEAVED round-robin blocks so
     # machine drift (thermal, co-tenants) hits all modes equally —
     # sequential loops showed several % of drift, swamping the real cost
-    modes = {"off": (False, False, "", False, False, False),
-             "on": (True, False, "", False, False, False),
-             "full": (True, True, "", False, False, False),
-             "rec": (True, True, rec_dir, False, False, False),
-             "cp": (True, True, cp_dir, True, False, False),
-             "el": (True, True, "", False, True, False),
-             "anat": (True, True, "", True, False, True)}
+    modes = {"off": (False, False, "", False, False),
+             "on": (True, False, "", False, False),
+             "full": (True, True, "", False, False),
+             "rec": (True, True, rec_dir, False, False),
+             "cp": (True, True, cp_dir, True, False),
+             "el": (True, True, "", False, True)}
     engines, times = {}, {name: [] for name in modes}
-    for name, (tel, full, rdir, cp, el, anat) in modes.items():
+    for name, (tel, full, rdir, cp, el) in modes.items():
         engines[name] = build_engine(tel, full=full, recorder_dir=rdir,
-                                     compile_plane=cp, elastic=el,
-                                     perf_plane=anat)
+                                     compile_plane=cp, elastic=el)
     assert engines["full"].statusz is not None and \
         engines["full"].statusz.port > 0
     assert engines["rec"]._recorder is not None
@@ -324,8 +310,7 @@ def main():
         engines["cp"]._hbm is not None
     assert engines["el"]._elastic is not None and \
         engines["el"]._hostagg is not None
-    assert engines["anat"]._perf_plane is not None
-    for name, (tel, full, _rdir, _cp, _el, _anat) in modes.items():  # warmup
+    for name, (tel, full, _rdir, _cp, _el) in modes.items():  # warmup
         _apply_mode(tel, full)
         run_block(engines[name], WARMUP)
 
@@ -333,7 +318,7 @@ def main():
     done = 0
     while done < STEPS:
         n = min(block, STEPS - done)
-        for name, (tel, full, _rdir, _cp, _el, _anat) in modes.items():
+        for name, (tel, full, _rdir, _cp, _el) in modes.items():
             _apply_mode(tel, full)
             run_block(engines[name], n, collect=times[name])
         done += n
@@ -352,15 +337,9 @@ def main():
     # the dark coordinator aggregated every step and never latched
     el = engines["el"]
     assert el._hostagg.last is not None and not el._elastic.pending
-    # the perf plane decomposed the warmup compile and — with a stable
-    # program — tripped no perf_regression trigger
-    pp_summary = engines["anat"]._perf_plane.summary()
-    assert pp_summary["programs_observed"] >= 1
-    assert pp_summary["regressions"] == 0
     t_off, t_on = times["off"], times["on"]
     t_full, t_rec = times["full"], times["rec"]
     t_cp, t_el = times["cp"], times["el"]
-    t_anat = times["anat"]
     for engine in engines.values():
         engine.close()
 
@@ -378,13 +357,11 @@ def main():
     rec_ms = statistics.median(t_rec) * 1e3
     cp_ms = statistics.median(t_cp) * 1e3
     el_ms = statistics.median(t_el) * 1e3
-    anat_ms = statistics.median(t_anat) * 1e3
     overhead_pct = 100.0 * (on_ms - off_ms) / off_ms
     overhead_full_pct = 100.0 * (full_ms - off_ms) / off_ms
     overhead_rec_pct = 100.0 * (rec_ms - off_ms) / off_ms
     overhead_cp_pct = 100.0 * (cp_ms - off_ms) / off_ms
     overhead_el_pct = 100.0 * (el_ms - off_ms) / off_ms
-    overhead_anat_pct = 100.0 * (anat_ms - off_ms) / off_ms
     result = {
         "steps": STEPS,
         "step_ms_tracer_off_p50": round(off_ms, 4),
@@ -403,9 +380,6 @@ def main():
         "overhead_compile_plane_pct": round(overhead_cp_pct, 3),
         "step_ms_elastic_p50": round(el_ms, 4),
         "overhead_elastic_pct": round(overhead_el_pct, 3),
-        "step_ms_anat_p50": round(anat_ms, 4),
-        "step_ms_anat_mean": round(statistics.mean(t_anat) * 1e3, 4),
-        "overhead_anat_pct": round(overhead_anat_pct, 3),
         "serving_tick_ms_dark_p50": round(dt_off_ms, 4),
         "serving_tick_ms_disttrace_p50": round(dt_ms, 4),
         "overhead_disttrace_pct": round(overhead_dt_pct, 3),
@@ -441,10 +415,6 @@ def main():
         f"total observability overhead with per-step heartbeats + a "
         f"dark ElasticCoordinator {overhead_el_pct:.2f}% exceeds the "
         f"{THRESHOLD_PCT}% budget")
-    assert overhead_anat_pct < THRESHOLD_PCT, (
-        f"perf-plane overhead (compile plane + step anatomy armed, no "
-        f"trigger) {overhead_anat_pct:.2f}% exceeds the "
-        f"{THRESHOLD_PCT}% budget")
     assert overhead_dt_pct < THRESHOLD_PCT, (
         f"serving observability overhead with distributed tracing + "
         f"fleet aggregation armed {overhead_dt_pct:.2f}% exceeds the "
@@ -457,7 +427,7 @@ def main():
           f"ledger + statusz server {overhead_full_pct:.2f}%, + flight "
           f"recorder {overhead_rec_pct:.2f}%, + compile plane "
           f"{overhead_cp_pct:.2f}%, + dark elastic coordinator "
-          f"{overhead_el_pct:.2f}%, + perf plane {overhead_anat_pct:.2f}%, "
+          f"{overhead_el_pct:.2f}%, "
           f"serving fleet w/ distributed tracing {overhead_dt_pct:.2f}%, "
           f"cost plane {overhead_cost_pct:.2f}% — all < {THRESHOLD_PCT}%")
 

@@ -107,21 +107,18 @@ def _lower_engine_step(engine, seq: int, name: str,
 
 
 def lower_train_step(size: str = "tiny",
-                     donation_min_bytes: Optional[int] = None,
-                     overlap: bool = True) -> HloArtifact:
+                     donation_min_bytes: Optional[int] = None
+                     ) -> HloArtifact:
     """The bucketed + compressed ZeRO-3 bench train step — the PR-10
     schedule under the PR-6 wire (overlap_schedule on, int8
     hierarchical reduce-scatter): the artifact with the richest
-    collective structure the repo emits. ``overlap=False`` compiles the
-    same step with the overlap schedule disabled — the rigged
-    regression benchmarks/anatomy.py uses to prove ds_tpu_perfdiff
-    fails a de-overlapped program by collective bucket name."""
+    collective structure the repo emits."""
     if donation_min_bytes is None:
         donation_min_bytes = (16 << 10) if size == "tiny" else (1 << 20)
     engine, seq = _train_engine({
         "zero_optimization": {"stage": 3,
                               "stage3_param_persistence_threshold": 0},
-        "overlap_schedule": {"enabled": overlap,
+        "overlap_schedule": {"enabled": True,
                              "bucket_bytes": (64 << 10) if size == "tiny"
                              else (4 << 20)},
         "comm_compression": {"all_gather": "int8", "reduce_scatter": "int8",

@@ -19,8 +19,10 @@ of the same rules). Sampling: greedy / temperature / top-k, with EOS
 short-circuit semantics matching HF generate defaults.
 """
 
+import inspect
 import math
 from collections import OrderedDict
+from contextlib import nullcontext
 from typing import Any, Dict, Optional
 
 import jax
@@ -36,6 +38,17 @@ from ..parallel.topology import (DeviceMeshManager, default_devices,
 from ..runtime.zero.partition import ZeroShardingPlanner
 from ..utils.logging import log_dist, logger
 from .config import DeepSpeedInferenceConfig
+from .kv_quant import (QuantizedSlotPool, init_pool, insert_lane,
+                       is_quantized_pool, lane_slice, lane_update,
+                       pool_from_fp, pool_to_fp, read_lane, write_lane)
+from .speculative import row_keys, sample_rows, sampling_arrays
+
+# compile-ledger labels of the pool programs whose key in ``_slot_fns``
+# spells its kind shorter; every other kind is its own label
+_POOL_LABELS = {"slot_suffix": "slot_suffix_prefill",
+                "slot_chunk": "slot_chunk_prefill"}
+# a pool program outside a serving tick's accounting: no (prep, dispatch)
+_NO_PHASES = (nullcontext(), nullcontext())
 
 
 def _next_pow2(n: int) -> int:
@@ -47,23 +60,9 @@ def _sample_one(logits_row, temp, top_k, top_p, seed, col, vocab):
     sampled token will be FED at): the key derives only from
     ``(seed, col)``, so serving replays — across ticks, slots, and
     replicas — regenerate the identical token (speculative.row_keys)."""
-    from .speculative import row_keys, sample_rows
     keys = row_keys(seed[None], col[None])
     return sample_rows(logits_row[None], temp[None], top_k[None],
                        top_p[None], keys, vocab)[0]
-
-
-def _lane_slice(leaf, slot_idx):
-    """One slot's lane of a pool leaf (slot axis is 1): ``[d0, 1, ...]``."""
-    start = (0, slot_idx) + (0,) * (leaf.ndim - 2)
-    sizes = (leaf.shape[0], 1) + leaf.shape[2:]
-    return lax.dynamic_slice(leaf, start, sizes)
-
-
-def _lane_update(leaf, lane, slot_idx):
-    """Write a lane back into a pool leaf at slot ``slot_idx``."""
-    start = (0, slot_idx) + (0,) * (leaf.ndim - 2)
-    return lax.dynamic_update_slice(leaf, lane.astype(leaf.dtype), start)
 
 
 class InferenceEngine:
@@ -606,11 +605,17 @@ class InferenceEngine:
     # Entry points for the continuous-batching serving layer
     # (deepspeed_tpu/serving/): a fixed pool of decode slots — batch rows of
     # one statically-shaped KV cache — so admission/retirement of requests
-    # never changes a compiled shape. Three programs: prefill-into-slot
-    # (one per pow2 prompt bucket), the fused all-slot decode step (compiles
-    # EXACTLY once per (num_slots, max_len)), and pool init. All are exempt
-    # from the _fns LRU: evicting the decode step would silently recompile
-    # the serving hot path.
+    # never changes a compiled shape. Every program over a pool (the slot
+    # prefills per pow2 bucket, the lane copies, the fused all-slot decode
+    # step that compiles EXACTLY once per (num_slots, max_len), the pool
+    # inits, and the draft and verify programs of speculative decoding) is
+    # built by ``_pool_program`` and called by ``_pool_call``, which decide
+    # once what each entry point below only names: its key and flavour in
+    # ``_slot_fns``, its shardings, its donation, the names the compile
+    # ledger reads. How a pool is stored (fp or int8) is inference/
+    # kv_quant.py's business: the bodies go through its converters. All
+    # are exempt from the _fns LRU: evicting the decode step would silently
+    # recompile the serving hot path.
 
     def _pool_shardings(self, num_slots: int, max_len: int,
                         quantize: bool = False, model=None):
@@ -632,7 +637,6 @@ class InferenceEngine:
         fixed = self._cache_shardings(shapes, rules=rules)
         if not quantize:
             return fixed
-        from .kv_quant import QuantizedSlotPool
 
         def drop_hd(sh, leaf):
             spec = tuple(sh.spec) + (None,) * (len(leaf.shape) - len(sh.spec))
@@ -642,58 +646,149 @@ class InferenceEngine:
             q=fixed, scales=jax.tree.map(drop_hd, fixed, shapes))
 
     @staticmethod
-    def _is_quantized_pool(pool) -> bool:
-        from .kv_quant import QuantizedSlotPool
-        return isinstance(pool, QuantizedSlotPool)
-
-    @staticmethod
     def _pool_dims(pool):
         """(num_slots, max_len, quantized) from any pool flavor. Every
         program that returns a pool consumes the one it was given
-        (``donate_argnums``): a pool that was handed over already is
-        refused here, by name, before XLA refuses its buffers."""
-        quantized = InferenceEngine._is_quantized_pool(pool)
-        leaf = jax.tree.leaves(pool.q if quantized else pool)[0]
+        (``_pool_program`` donates it): a pool that was handed over already
+        is refused here, by name, before XLA refuses its buffers."""
+        leaf = jax.tree.leaves(pool)[0]
         if leaf.is_deleted():
             raise RuntimeError(
                 "this KV pool was consumed by an earlier slot_* call (or by "
                 "one that raised after its dispatch): every pool program "
                 "donates its pool, so rebind the pool from the call's "
                 "return and never reuse the argument")
-        return int(leaf.shape[1]), int(leaf.shape[2]), quantized
-
-    def _read_lane(self, pool, slot_idx, quantized):
-        """One slot's lane as an fp mini-cache [L, 1, max_len, H, hd]
-        (jit-safe; dequantizes just the lane for quantized pools)."""
-        if not quantized:
-            return jax.tree.map(lambda leaf: _lane_slice(leaf, slot_idx),
-                                pool)
-        from .kv_quant import dequantize_kv
-        return jax.tree.map(
-            lambda qc, sc: dequantize_kv(_lane_slice(qc, slot_idx),
-                                         _lane_slice(sc, slot_idx),
-                                         self.dtype),
-            pool.q, pool.scales)
+        return (int(leaf.shape[1]), int(leaf.shape[2]),
+                is_quantized_pool(pool))
 
     @staticmethod
-    def _write_lane(pool, mini, slot_idx, quantized):
-        """Write an fp mini-cache back into slot ``slot_idx`` (jit-safe;
-        re-quantizes only this lane for quantized pools — per-column
-        scales keep the round-trip of untouched columns exact)."""
-        if not quantized:
-            return jax.tree.map(
-                lambda pc, mc: _lane_update(pc, mc, slot_idx), pool, mini)
-        from .kv_quant import QuantizedSlotPool, quantize_kv
-        pairs = jax.tree.map(quantize_kv, mini)
-        istup = lambda t: isinstance(t, tuple)   # noqa: E731
-        mini_q = jax.tree.map(lambda p: p[0], pairs, is_leaf=istup)
-        mini_s = jax.tree.map(lambda p: p[1], pairs, is_leaf=istup)
-        return QuantizedSlotPool(
-            q=jax.tree.map(lambda pc, mc: _lane_update(pc, mc, slot_idx),
-                           pool.q, mini_q),
-            scales=jax.tree.map(
-                lambda pc, mc: _lane_update(pc, mc, slot_idx),
-                pool.scales, mini_s))
+    def _pool_key(kind, dims, quantized):
+        """A pool program's key in ``_slot_fns``: its kind, the static
+        sizes it was built for, and ``"q8"`` last where its pool is int8
+        (fp and int8 pools are separate programs)."""
+        return (kind, *dims) + (("q8",) if quantized else ())
+
+    def _pool_program(self, kind, dims, shape, outs: Optional[int] = 0,
+                      draft=None):
+        """The one builder of programs over a KV pool, as a decorator of
+        the program's body: ``@self._pool_program(...)`` over ``def
+        dec(params, pool, ...)`` gives the compiled program, the cached
+        one after the first time (the body is then ignored). ``shape`` is
+        the pool's ``(num_slots, max_len, quantized)`` (``_pool_dims``);
+        ``outs`` says how many values the body returns after the pool, or
+        ``None`` where it returns no pool; ``draft`` makes it a program
+        over the draft model's pool and parameters. Decided here and
+        nowhere else:
+
+        - the key (``_pool_key``), under which ``_slot_fns`` keeps it;
+        - the shardings, read off the body's signature: the parameters'
+          at an argument named ``...params``, the pool's at one named
+          ``...pool`` and at the first output, none elsewhere;
+        - donation: a program that returns a pool donates the pool it
+          is given, always. Aliased to the output, a lane write
+          (``dynamic_update_slice``) or a decode step's rows change the
+          pool in place; undonated, XLA allocates a second pool and
+          copies all of it, two pool-sized buffers stay live across the
+          step (the kv_slots HBM doubling ds_tpu_lint's HLO005 flags),
+          and the runtime holds the next call until the old pool is free
+          (docs/serving.md, "Who owns the pool"). Every caller rebinds
+          the pool from the return;
+        - the label and the argument names the compile ledger is given:
+          the body's own parameter names (analysis/artifacts.py maps
+          them to roles).
+
+        The module name of the program is ``jit_<body's name>``: ``jit_pf``
+        and ``jit_dec`` are how chipbench/workloads/*.json find the
+        prefill and decode programs in a device trace
+        (tests/unit/test_phases.py pins them)."""
+        num_slots, max_len, quantized = shape
+        key = self._pool_key(kind, dims, quantized)
+
+        def build(body):
+            fn = self._slot_fns.get(key)
+            if fn is not None:
+                return fn
+            names = tuple(inspect.signature(body).parameters)
+            on_draft = draft is not None
+            pool_sh = self._pool_shardings(
+                num_slots, max_len, quantize=quantized,
+                model=draft.model if on_draft else None)
+            param_sh = draft.param_shardings if on_draft \
+                else self.param_shardings
+            in_sh = tuple(param_sh if n.endswith("params") else
+                          pool_sh if n.endswith("pool") else None
+                          for n in names)
+            if outs is None:        # returns no pool: nothing to alias
+                out_sh, donated = None, ()
+            else:
+                out_sh = (pool_sh,) + (None,) * outs if outs else pool_sh
+                donated = tuple(i for i, n in enumerate(names)
+                                if n.endswith("pool"))
+            fn = self._slot_fns[key] = jax.jit(
+                body, in_shardings=in_sh, out_shardings=out_sh,
+                donate_argnums=donated)
+            fn.label = _POOL_LABELS.get(kind, kind)
+            fn.arg_names = names
+            return fn
+
+        return build
+
+    def _pool_call(self, fn, prep, phases=_NO_PHASES):
+        """Call pool program ``fn`` with the arguments ``prep()`` makes
+        (host arrays put on the device). ``phases`` are the two phase
+        records of a serving tick's program, (prep, dispatch); a program
+        outside the tick's accounting records none."""
+        prep_phase, dispatch_phase = phases
+        with prep_phase:
+            args = prep()
+            # observed BEFORE the call: the program donates its pool, so
+            # its arguments can only be read while they are still live
+            self._observe_compile(fn.label, fn, args, names=fn.arg_names)
+        with dispatch_phase, self.mesh:
+            return fn(*args)
+
+    def slot_executables(self, kind: str, *dims,
+                         quantized: Optional[bool] = None) -> int:
+        """Compiled executables behind the pool program ``(kind, *dims)``
+        (``dims`` as in its key: ``"slot_decode", num_slots, max_len``;
+        ``"slot_chunk", num_slots, bucket, max_len``; ``"slot_verify",
+        num_slots, max_len, k``) — the compile-once evidence the serving
+        tests assert: 1 per pool flavour. ``quantized`` selects one
+        flavour; None sums both."""
+        flavours = (False, True) if quantized is None else (quantized,)
+        fns = (self._slot_fns.get(self._pool_key(kind, dims, q))
+               for q in flavours)
+        return sum(fn._cache_size() for fn in fns if fn is not None)
+
+    def _slot_arrays(self, toks, positions, temps, top_ks, top_ps, seeds):
+        """The six per-slot arrays of a decode-shaped program on the
+        device, in its argument order; a sampling array left ``None`` is
+        the neutral one (greedy, no truncation)."""
+        sampling = (temps, top_ks, top_ps, seeds)
+        if any(x is None for x in sampling):
+            sampling = tuple(
+                d if x is None else x for x, d in zip(
+                    sampling, sampling_arrays(len(np.reshape(toks, -1)))))
+        return (jnp.asarray(toks, jnp.int32),
+                jnp.asarray(positions, jnp.int32),
+                *(jnp.asarray(x, dt) for x, dt in zip(
+                    sampling,
+                    (jnp.float32, jnp.int32, jnp.float32, jnp.int32))))
+
+    def _init_pool(self, num_slots, max_len, quantize=False, draft=None):
+        """An empty pool of the target model, or of ``draft``'s."""
+        if draft is None:
+            kind, dims, model = "slot_pool", (num_slots, max_len), self.module
+        else:
+            kind, dims, model = "draft_pool", \
+                (num_slots, max_len, draft.key), draft.model
+
+        @self._pool_program(kind, dims, (num_slots, max_len, quantize),
+                            draft=draft)
+        def init():
+            return init_pool(model, num_slots, max_len, self.dtype, quantize)
+
+        return self._pool_call(init, lambda: ())
 
     def init_slot_pool(self, num_slots: int, max_len: int,
                        quantize: bool = False):
@@ -703,27 +798,8 @@ class InferenceEngine:
         ``models/gpt2.py:init_kv_cache``). ``quantize=True`` allocates it
         int8 with per-row f32 scales [L, num_slots, max_len, H]
         (inference/kv_quant.py) — ~4x the slots per HBM byte; the slot
-        programs transparently branch on the pool type."""
-        key = ("slot_pool", num_slots, max_len) + \
-            (("q8",) if quantize else ())
-        fn = self._slot_fns.get(key)
-        if fn is None:
-            if quantize:
-                from .kv_quant import quantize_pool
-
-                def build():
-                    return quantize_pool(self.module.init_kv_cache(
-                        num_slots, max_len, dtype=self.dtype))
-            else:
-                def build():
-                    return self.module.init_kv_cache(num_slots, max_len,
-                                                     dtype=self.dtype)
-            fn = self._slot_fns[key] = jax.jit(
-                build, out_shardings=self._pool_shardings(
-                    num_slots, max_len, quantize=quantize))
-        self._observe_compile("slot_pool", fn, ())
-        with self.mesh:
-            return fn()
+        programs take either flavour."""
+        return self._init_pool(num_slots, max_len, quantize)
 
     def slot_prefill(self, pool, slot: int, prompt, temperature: float = 0.0,
                      top_k: int = 0, top_p: float = 1.0, seed: int = 0):
@@ -737,49 +813,31 @@ class InferenceEngine:
         vocab = model.config.vocab_size
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         t = prompt.shape[0]
-        num_slots, max_len, quantized = self._pool_dims(pool)
+        shape = self._pool_dims(pool)
+        max_len = shape[1]
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
         bucket = min(_next_pow2(t), max_len)
-        fkey = ("slot_prefill", bucket, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
 
-            # the module name this gives, ``jit_pf``, is how
-            # chipbench/workloads/*.json finds the prefill programs in a
-            # device trace (tests/unit/test_phases.py pins it)
-            def pf(params, ids, pool, slot_idx, last_idx, temp, top_k,
-                   top_p, seed):
-                mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
-                logits, mini, *stats = model.apply_with_cache(
-                    params, ids, mini, jnp.int32(0), routing=self._routed)
-                pool = self._write_lane(pool, mini, slot_idx, quantized)
-                last = jnp.take(logits[0], last_idx, axis=0)
-                # the first token is FED at column last_idx + 1
-                tok = _sample_one(last, temp, top_k, top_p, seed,
-                                  last_idx + 1, vocab)
-                # routed experts: [token, touched, largest], one read-back
-                return pool, \
-                    jnp.concatenate([tok[None], *stats]) if stats else tok
+        @self._pool_program("slot_prefill", (bucket, max_len), shape, outs=1)
+        def pf(params, ids, pool, slot, last_idx, temperature, top_k,
+               top_p, seed):
+            mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
+            logits, mini, *stats = model.apply_with_cache(
+                params, ids, mini, jnp.int32(0), routing=self._routed)
+            pool = write_lane(pool, mini, slot)
+            last = jnp.take(logits[0], last_idx, axis=0)
+            # the first token is FED at column last_idx + 1
+            tok = _sample_one(last, temperature, top_k, top_p, seed,
+                              last_idx + 1, vocab)
+            # routed experts: [token, touched, largest], one read-back
+            return pool, \
+                jnp.concatenate([tok[None], *stats]) if stats else tok
 
-            # the pool is donated, here and in every program below that
-            # returns one: aliased to the output, _write_lane's
-            # dynamic_update_slice changes one lane in place; undonated,
-            # XLA allocates a second pool and copies all of it, and the
-            # runtime holds the next call until the old one is free
-            # (docs/serving.md, "Who owns the pool")
-            fn = self._slot_fns[fkey] = jax.jit(pf, in_shardings=(
-                self.param_shardings, None, pool_shardings, None, None, None,
-                None, None, None), out_shardings=(pool_shardings, None),
-                donate_argnums=(2,))
         pool, tok = self._slot_prefill_call(
-            "slot_prefill", fn, pool, slot, prompt, bucket,
+            pf, pool, slot, prompt, bucket,
             (np.int32(t - 1), np.float32(temperature), np.int32(top_k),
-             np.float32(top_p), np.int32(seed)),
-            ("last_idx", "temperature", "top_k", "top_p", "seed"))
+             np.float32(top_p), np.int32(seed)))
         with self._tracer.phase("serve/prefill_wait"):
             tok = self._read_back(np.asarray(tok).reshape(-1), 1)
         return pool, int(tok[0])
@@ -801,25 +859,30 @@ class InferenceEngine:
         routing, self._routing = self._routing, None
         return routing
 
-    def _slot_prefill_call(self, label, fn, pool, slot, tokens, bucket,
-                           scalars, names):
-        """Shared tail of the three slot prefills, as two phase records:
-        ``serve/prefill_prep`` (tokens, bucket) — right-pad ``tokens`` to
-        ``bucket``, put the ids and every scalar on the device, observe
-        the compile — then ``serve/prefill_dispatch`` (bucket), the call
-        of ``fn(params, ids, pool, slot, *scalars)``."""
+    def _slot_prefill_call(self, fn, pool, slot, tokens, bucket,
+                           scalars=(), draft=None):
+        """Shared tail of the prefills: right-pad ``tokens`` to ``bucket``,
+        put the ids and every scalar on the device, and call
+        ``fn(params, ids, pool, slot, *scalars)``. The three slot
+        prefills record it as two phases, ``serve/prefill_prep`` (tokens,
+        bucket) up to the compile's observation and
+        ``serve/prefill_dispatch`` (bucket), the call; the draft's
+        prefill (``draft``: its parameters) rides an admission that has
+        recorded its prefill already, and records none."""
         tr = self._tracer
         t = tokens.shape[0]
-        with tr.phase("serve/prefill_prep", t, bucket):
+
+        def prep():
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :t] = tokens
-            args = (self.params, jnp.asarray(ids), pool, jnp.int32(slot),
+            return ((self if draft is None else draft).params,
+                    jnp.asarray(ids), pool, jnp.int32(slot),
                     *map(jnp.asarray, scalars))
-            self._observe_compile(label, fn, args,
-                                  names=("params", "ids", "pool", "slot")
-                                  + names)
-        with tr.phase("serve/prefill_dispatch", bucket), self.mesh:
-            return fn(*args)
+
+        return self._pool_call(
+            fn, prep, _NO_PHASES if draft is not None else
+            (tr.phase("serve/prefill_prep", t, bucket),
+             tr.phase("serve/prefill_dispatch", bucket)))
 
     def slot_suffix_prefill(self, pool, slot: int, tokens, start_pos: int,
                             temperature: float = 0.0, top_k: int = 0,
@@ -838,7 +901,8 @@ class InferenceEngine:
         vocab = model.config.vocab_size
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1)
         t = tokens.shape[0]
-        num_slots, max_len, quantized = self._pool_dims(pool)
+        shape = self._pool_dims(pool)
+        max_len = shape[1]
         if t < 1:
             raise ValueError("suffix must carry at least one token (the "
                              "sampled next token needs a query position)")
@@ -848,34 +912,23 @@ class InferenceEngine:
                 f"suffix bucket [{start_pos}, {start_pos + bucket}) exceeds "
                 f"max_len={max_len}; plan the reuse offset with "
                 f"prefix_cache.reuse_plan")
-        fkey = ("slot_suffix", bucket, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
 
-            def spf(params, ids, pool, slot_idx, start_pos, last_idx, temp,
-                    top_k, top_p, seed):
-                mini = self._read_lane(pool, slot_idx, quantized)
-                logits, mini = model.apply_with_cache(params, ids, mini,
-                                                      start_pos)
-                pool = self._write_lane(pool, mini, slot_idx, quantized)
-                last = jnp.take(logits[0], last_idx, axis=0)
-                tok = _sample_one(last, temp, top_k, top_p, seed,
-                                  start_pos + last_idx + 1, vocab)
-                return pool, tok
+        @self._pool_program("slot_suffix", (bucket, max_len), shape, outs=1)
+        def spf(params, ids, pool, slot, start_pos, last_idx, temperature,
+                top_k, top_p, seed):
+            mini = read_lane(pool, slot, self.dtype)
+            logits, mini = model.apply_with_cache(params, ids, mini,
+                                                  start_pos)
+            pool = write_lane(pool, mini, slot)
+            last = jnp.take(logits[0], last_idx, axis=0)
+            tok = _sample_one(last, temperature, top_k, top_p, seed,
+                              start_pos + last_idx + 1, vocab)
+            return pool, tok
 
-            fn = self._slot_fns[fkey] = jax.jit(spf, in_shardings=(
-                self.param_shardings, None, pool_shardings, None, None, None,
-                None, None, None, None), out_shardings=(pool_shardings, None),
-                donate_argnums=(2,))
         pool, tok = self._slot_prefill_call(
-            "slot_suffix_prefill", fn, pool, slot, tokens, bucket,
+            spf, pool, slot, tokens, bucket,
             (np.int32(start_pos), np.int32(t - 1), np.float32(temperature),
-             np.int32(top_k), np.float32(top_p), np.int32(seed)),
-            ("start_pos", "last_idx", "temperature", "top_k", "top_p",
-             "seed"))
+             np.int32(top_k), np.float32(top_p), np.int32(seed)))
         with self._tracer.phase("serve/prefill_wait"):
             tok = int(tok)
         return pool, tok
@@ -901,7 +954,8 @@ class InferenceEngine:
         model = self.module
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1)
         t = tokens.shape[0]
-        num_slots, max_len, quantized = self._pool_dims(pool)
+        shape = self._pool_dims(pool)
+        num_slots, max_len, _ = shape
         if t < 1:
             raise ValueError("chunk must carry at least one token")
         bucket = min(_next_pow2(t), max_len)
@@ -909,42 +963,17 @@ class InferenceEngine:
             raise ValueError(
                 f"chunk bucket [{start_pos}, {start_pos + bucket}) exceeds "
                 f"max_len={max_len}; size chunks so every bucket fits")
-        fkey = ("slot_chunk", num_slots, bucket, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
 
-            def cpf(params, ids, pool, slot_idx, start_pos):
-                mini = self._read_lane(pool, slot_idx, quantized)
-                mini = model.chunk_prefill_with_cache(params, ids, mini,
-                                                      start_pos)
-                return self._write_lane(pool, mini, slot_idx, quantized)
+        @self._pool_program("slot_chunk", (num_slots, bucket, max_len),
+                            shape)
+        def cpf(params, ids, pool, slot, start_pos):
+            mini = read_lane(pool, slot, self.dtype)
+            mini = model.chunk_prefill_with_cache(params, ids, mini,
+                                                  start_pos)
+            return write_lane(pool, mini, slot)
 
-            fn = self._slot_fns[fkey] = jax.jit(cpf, in_shardings=(
-                self.param_shardings, None, pool_shardings, None, None),
-                out_shardings=pool_shardings, donate_argnums=(2,))
-        return self._slot_prefill_call(
-            "slot_chunk_prefill", fn, pool, slot, tokens, bucket,
-            (np.int32(start_pos),), ("start_pos",))
-
-    def slot_chunk_executables(self, num_slots: int, max_len: int,
-                               bucket: int,
-                               quantized: Optional[bool] = None) -> int:
-        """Compiled-executable count behind the chunk-prefill program for
-        one pow2 bucket flavor — the compile-once evidence the chunked-
-        prefill tests assert (mirrors slot_decode_executables)."""
-        keys = {None: (("slot_chunk", num_slots, bucket, max_len),
-                       ("slot_chunk", num_slots, bucket, max_len, "q8")),
-                False: (("slot_chunk", num_slots, bucket, max_len),),
-                True: (("slot_chunk", num_slots, bucket, max_len, "q8"),)}
-        total = 0
-        for fkey in keys[quantized]:
-            fn = self._slot_fns.get(fkey)
-            if fn is not None:
-                total += fn._cache_size()
-        return total
+        return self._slot_prefill_call(cpf, pool, slot, tokens, bucket,
+                                       (np.int32(start_pos),))
 
     def slot_copy_lane(self, pool, src: int, dst: int):
         """Copy slot ``src``'s whole cache lane over slot ``dst``'s —
@@ -954,89 +983,48 @@ class InferenceEngine:
         shared-prefix boundary; stale donor columns past the new request's
         length are masked until decode overwrites them, exactly like a
         fresh prefill's pad columns."""
-        num_slots, max_len, quantized = self._pool_dims(pool)
-        fkey = ("slot_copy", num_slots, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
+        shape = self._pool_dims(pool)
 
-            def cp(pool, src_idx, dst_idx):
-                return jax.tree.map(
-                    lambda leaf: _lane_update(
-                        leaf, _lane_slice(leaf, src_idx), dst_idx), pool)
+        @self._pool_program("slot_copy", shape[:2], shape)
+        def cp(pool, src, dst):
+            return jax.tree.map(
+                lambda leaf: lane_update(leaf, lane_slice(leaf, src), dst),
+                pool)
 
-            fn = self._slot_fns[fkey] = jax.jit(
-                cp, out_shardings=pool_shardings, donate_argnums=(0,))
-        cp_args = (pool, jnp.int32(src), jnp.int32(dst))
-        self._observe_compile("slot_copy", fn, cp_args,
-                              names=("pool", "src", "dst"))
-        with self.mesh:
-            return fn(*cp_args)
+        return self._pool_call(
+            cp, lambda: (pool, jnp.int32(src), jnp.int32(dst)))
 
     def slot_extract_lane(self, pool, slot: int):
         """Slot ``slot``'s cache lane as a HOST pytree (np arrays) — the
         payload of a KVHandoff (serving/fleet/handoff.py). Quantized pools
         hand off their int8 q + f32 scale slices directly: the wire cost
         of a disaggregated prefill→decode transfer is the quantized lane,
-        not a dequantized copy."""
-        num_slots, max_len, quantized = self._pool_dims(pool)
-        fkey = ("slot_extract", num_slots, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            def ex(pool, idx):
-                return jax.tree.map(lambda leaf: _lane_slice(leaf, idx),
-                                    pool)
+        not a dequantized copy. The one pool program that returns no
+        pool: the pool stays the caller's."""
+        shape = self._pool_dims(pool)
 
-            fn = self._slot_fns[fkey] = jax.jit(ex)
-        ex_args = (pool, jnp.int32(slot))
-        self._observe_compile("slot_extract", fn, ex_args,
-                              names=("pool", "slot"))
-        with self.mesh:
-            lane = fn(*ex_args)
-        return jax.device_get(lane)
+        @self._pool_program("slot_extract", shape[:2], shape, outs=None)
+        def ex(pool, slot):
+            return jax.tree.map(lambda leaf: lane_slice(leaf, slot), pool)
+
+        return jax.device_get(
+            self._pool_call(ex, lambda: (pool, jnp.int32(slot))))
 
     def slot_insert_lane(self, pool, slot: int, lane):
         """Insert a lane (from ``slot_extract_lane``, possibly another
         replica's pool) into slot ``slot``. Handles every quantization
         pairing: fp lanes quantize on the way into a quantized pool,
         quantized lanes dequantize into an fp pool — so a prefill replica
-        and a decode replica need not share a KV storage format."""
-        num_slots, max_len, pool_q = self._pool_dims(pool)
-        lane_q = self._is_quantized_pool(lane)
-        fkey = ("slot_insert", num_slots, max_len, pool_q, lane_q)
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=pool_q)
-            from .kv_quant import (QuantizedSlotPool, dequantize_pool,
-                                   quantize_pool)
+        and a decode replica need not share a KV storage format (one
+        program per pairing: the lane's flavour is part of the key)."""
+        shape = self._pool_dims(pool)
 
-            def ins(pool, lane, idx):
-                if pool_q and not lane_q:
-                    lane = quantize_pool(lane)
-                elif not pool_q and lane_q:
-                    lane = dequantize_pool(lane, self.dtype)
-                if pool_q:
-                    return QuantizedSlotPool(
-                        q=jax.tree.map(
-                            lambda pc, mc: _lane_update(pc, mc, idx),
-                            pool.q, lane.q),
-                        scales=jax.tree.map(
-                            lambda pc, mc: _lane_update(pc, mc, idx),
-                            pool.scales, lane.scales))
-                return jax.tree.map(
-                    lambda pc, mc: _lane_update(pc, mc, idx), pool, lane)
+        @self._pool_program("slot_insert",
+                            shape[:2] + (is_quantized_pool(lane),), shape)
+        def ins(pool, lane, slot):
+            return insert_lane(pool, lane, slot, self.dtype)
 
-            fn = self._slot_fns[fkey] = jax.jit(
-                ins, out_shardings=pool_shardings, donate_argnums=(0,))
-        ins_args = (pool, lane, jnp.int32(slot))
-        self._observe_compile("slot_insert", fn, ins_args,
-                              names=("pool", "lane", "slot"))
-        with self.mesh:
-            return fn(*ins_args)
+        return self._pool_call(ins, lambda: (pool, lane, jnp.int32(slot)))
 
     def slot_decode_step(self, pool, toks, positions, temps, top_ks=None,
                          top_ps=None, seeds=None):
@@ -1048,101 +1036,44 @@ class InferenceEngine:
         ignored by the scheduler. Returns (new_pool, next_tokens [S])."""
         model = self.module
         vocab = model.config.vocab_size
-        num_slots, max_len, quantized = self._pool_dims(pool)
-        fkey = ("slot_decode", num_slots, max_len) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
-            from .speculative import row_keys, sample_rows
+        shape = self._pool_dims(pool)
+        num_slots = shape[0]
 
-            # the module name this gives, ``jit_dec``, is how
-            # chipbench/workloads/*.json finds the decode program in a
-            # device trace (tests/unit/test_phases.py pins it)
-            def dec(params, pool, toks, positions, temps, top_ks, top_ps,
-                    seeds):
-                if quantized:
-                    from .kv_quant import dequantize_pool, quantize_pool
-                    fp = dequantize_pool(pool, self.dtype)
-                else:
-                    fp = pool
-                logits, fp, *stats = model.decode_with_slots(
-                    params, toks[:, None], fp, positions,
-                    routing=self._routed)
-                # the sampled token will be FED at column positions + 1
-                # ("sample" scope: the perf plane buckets this tail apart
-                # from the model forward it follows)
-                with jax.named_scope("sample"):
-                    keys = row_keys(seeds, positions + 1)
-                    nxt = sample_rows(logits[:, -1], temps, top_ks,
-                                      top_ps, keys, vocab)
-                # re-quantize on the way out: per-column scales make the
-                # round-trip of every column this step did not write exact,
-                # so old tokens never re-accumulate quantization error
-                pool = quantize_pool(fp) if quantized else fp
-                # routed experts: the stats ride behind the tokens
-                return pool, jnp.concatenate([nxt, *stats]) if stats else nxt
+        @self._pool_program("slot_decode", shape[:2], shape, outs=1)
+        def dec(params, pool, toks, positions, temps, top_ks, top_ps,
+                seeds):
+            # an int8 pool is seen whole as fp by the step and stored back
+            # on the way out: per-column scales make the round-trip of
+            # every column this step did not write exact
+            logits, fp, *stats = model.decode_with_slots(
+                params, toks[:, None], pool_to_fp(pool, self.dtype),
+                positions, routing=self._routed)
+            # the sampled token will be FED at column positions + 1
+            with jax.named_scope("sample"):
+                keys = row_keys(seeds, positions + 1)
+                nxt = sample_rows(logits[:, -1], temps, top_ks,
+                                  top_ps, keys, vocab)
+            # routed experts: the stats ride behind the tokens
+            return pool_from_fp(fp, pool), \
+                jnp.concatenate([nxt, *stats]) if stats else nxt
 
-            # donate the pool: decode is state-in/state-out per tick, and
-            # an undonated pool keeps TWO pool-sized buffers live across
-            # every step — the kv_slots HBM doubling ds_tpu_lint's
-            # donation auditor (HLO005) flags. Every caller rebinds the
-            # pool from the return (scheduler.py decode tick included).
-            fn = self._slot_fns[fkey] = jax.jit(dec, in_shardings=(
-                self.param_shardings, pool_shardings, None, None, None, None,
-                None, None),
-                out_shardings=(pool_shardings, None),
-                donate_argnums=(1,))
         tr = self._tracer
-        with tr.phase("serve/decode_prep"):
-            n = len(np.asarray(toks).reshape(-1))
-            if top_ks is None:
-                top_ks = np.zeros((n,), np.int32)
-            if top_ps is None:
-                top_ps = np.ones((n,), np.float32)
-            if seeds is None:
-                seeds = np.zeros((n,), np.int32)
-            dec_args = (self.params, pool, jnp.asarray(toks, jnp.int32),
-                        jnp.asarray(positions, jnp.int32),
-                        jnp.asarray(temps, jnp.float32),
-                        jnp.asarray(top_ks, jnp.int32),
-                        jnp.asarray(top_ps, jnp.float32),
-                        jnp.asarray(seeds, jnp.int32))
-            self._observe_compile("slot_decode", fn, dec_args,
-                                  names=("params", "pool", "toks",
-                                         "positions", "temps", "top_ks",
-                                         "top_ps", "seeds"))
-        with tr.phase("serve/decode_dispatch"), self.mesh:
-            pool, nxt = fn(*dec_args)
+        pool, nxt = self._pool_call(
+            dec, lambda: (self.params, pool, *self._slot_arrays(
+                toks, positions, temps, top_ks, top_ps, seeds)),
+            (tr.phase("serve/decode_prep"),
+             tr.phase("serve/decode_dispatch")))
         with tr.phase("serve/decode_wait"):
             nxt = self._read_back(np.asarray(nxt), num_slots)
         return pool, nxt
-
-    def slot_decode_executables(self, num_slots: int, max_len: int,
-                                quantized: Optional[bool] = None) -> int:
-        """Number of compiled executables behind the fused decode step —
-        the serving tests assert this stays at 1 per pool flavor
-        (compile-once decode; fp and quantized pools are separate
-        programs). ``quantized`` selects one flavor; None sums both."""
-        keys = {None: (("slot_decode", num_slots, max_len),
-                       ("slot_decode", num_slots, max_len, "q8")),
-                False: (("slot_decode", num_slots, max_len),),
-                True: (("slot_decode", num_slots, max_len, "q8"),)}
-        total = 0
-        for fkey in keys[quantized]:
-            fn = self._slot_fns.get(fkey)
-            if fn is not None:
-                total += fn._cache_size()
-        return total
 
     # -------------------------------------------- speculative decode protocol
     # Draft-model speculation over the slot pool (inference/speculative.py):
     # a cheap draft proposes K tokens per slot in ONE compiled lax.scan,
     # the target verifies all K in ONE batched verify_with_slots forward,
     # and per-slot accept/rollback of KV columns happens INSIDE the
-    # compiled verify step. Both pools are donated (state-in/state-out per
-    # tick — ds_tpu_lint HLO005 audits the lowered programs).
+    # compiled verify step. Both pools are donated like any other
+    # (``_pool_program``; ds_tpu_lint HLO005 audits the lowered programs).
 
     def init_draft(self, draft_cfg):
         """Build (or fetch the cached) DraftRuntime for ``draft_cfg`` —
@@ -1161,17 +1092,7 @@ class InferenceEngine:
     def init_draft_pool(self, draft, num_slots: int, max_len: int):
         """Allocate the draft model's slot-pool KV cache (fp — the draft
         is already the cheap side of the trade), once, at static shape."""
-        fkey = ("draft_pool", num_slots, max_len, draft.key)
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            fn = self._slot_fns[fkey] = jax.jit(
-                lambda: draft.model.init_kv_cache(num_slots, max_len,
-                                                  dtype=self.dtype),
-                out_shardings=self._pool_shardings(num_slots, max_len,
-                                                   model=draft.model))
-        self._observe_compile("draft_pool", fn, ())
-        with self.mesh:
-            return fn()
+        return self._init_pool(num_slots, max_len, draft=draft)
 
     def draft_prefill(self, draft, dpool, slot: int, prompt):
         """Prefill ``prompt`` into the DRAFT pool's slot lane (pow2
@@ -1180,34 +1101,23 @@ class InferenceEngine:
         donated. Returns the new draft pool."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         t = prompt.shape[0]
-        num_slots, max_len, _ = self._pool_dims(dpool)
+        shape = self._pool_dims(dpool)
+        num_slots, max_len, _ = shape
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
         bucket = min(_next_pow2(t), max_len)
-        ids = np.zeros((1, bucket), np.int32)
-        ids[0, :t] = prompt
-        fkey = ("draft_prefill", bucket, num_slots, max_len, draft.key)
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  model=draft.model)
 
-            def dpf(dparams, ids, dpool, slot_idx):
-                mini = draft.model.init_kv_cache(1, max_len,
-                                                 dtype=self.dtype)
-                _logits, mini = draft.model.apply_with_cache(
-                    dparams, ids, mini, jnp.int32(0))
-                return self._write_lane(dpool, mini, slot_idx, False)
+        @self._pool_program("draft_prefill",
+                            (bucket, num_slots, max_len, draft.key), shape,
+                            draft=draft)
+        def dpf(draft_params, ids, draft_pool, slot):
+            mini = draft.model.init_kv_cache(1, max_len, dtype=self.dtype)
+            _logits, mini = draft.model.apply_with_cache(
+                draft_params, ids, mini, jnp.int32(0))
+            return write_lane(draft_pool, mini, slot)
 
-            fn = self._slot_fns[fkey] = jax.jit(dpf, in_shardings=(
-                draft.param_shardings, None, pool_shardings, None),
-                out_shardings=pool_shardings, donate_argnums=(2,))
-        dpf_args = (draft.params, jnp.asarray(ids), dpool, jnp.int32(slot))
-        self._observe_compile("draft_prefill", fn, dpf_args,
-                              names=("draft_params", "ids", "draft_pool",
-                                     "slot"))
-        with self.mesh:
-            return fn(*dpf_args)
+        return self._slot_prefill_call(dpf, dpool, slot, prompt, bucket,
+                                       draft=draft)
 
     def slot_draft_propose(self, draft, dpool, toks, positions, temps,
                            top_ks, top_ps, seeds, k: int):
@@ -1219,45 +1129,28 @@ class InferenceEngine:
         that maximizes exact-match acceptance. Draft pool donated.
         Returns (new_dpool, draft_tokens [S, k])."""
         vocab = draft.model.config.vocab_size
-        num_slots, max_len, _ = self._pool_dims(dpool)
-        fkey = ("slot_draft", num_slots, max_len, int(k), draft.key)
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  model=draft.model)
-            from .speculative import row_keys, sample_rows
+        shape = self._pool_dims(dpool)
 
-            def prop(dparams, dpool, toks, positions, temps, top_ks,
-                     top_ps, seeds):
-                def body(carry, _):
-                    dpool, tok, pos = carry
-                    logits, dpool = draft.model.decode_with_slots(
-                        dparams, tok[:, None], dpool, pos)
-                    keys = row_keys(seeds, pos + 1)
-                    nxt = sample_rows(logits[:, -1], temps, top_ks, top_ps,
-                                      keys, vocab)
-                    return (dpool, nxt, pos + 1), nxt
+        @self._pool_program("slot_draft", shape[:2] + (int(k), draft.key),
+                            shape, outs=1, draft=draft)
+        def prop(draft_params, draft_pool, toks, positions, temps, top_ks,
+                 top_ps, seeds):
+            def body(carry, _):
+                draft_pool, tok, pos = carry
+                logits, draft_pool = draft.model.decode_with_slots(
+                    draft_params, tok[:, None], draft_pool, pos)
+                keys = row_keys(seeds, pos + 1)
+                nxt = sample_rows(logits[:, -1], temps, top_ks, top_ps,
+                                  keys, vocab)
+                return (draft_pool, nxt, pos + 1), nxt
 
-                (dpool, _, _), drafts = lax.scan(
-                    body, (dpool, toks, positions), None, length=k + 1)
-                return dpool, jnp.transpose(drafts[:k])      # [S, k]
+            (draft_pool, _, _), drafts = lax.scan(
+                body, (draft_pool, toks, positions), None, length=k + 1)
+            return draft_pool, jnp.transpose(drafts[:k])      # [S, k]
 
-            fn = self._slot_fns[fkey] = jax.jit(prop, in_shardings=(
-                draft.param_shardings, pool_shardings, None, None, None,
-                None, None, None),
-                out_shardings=(pool_shardings, None), donate_argnums=(1,))
-        prop_args = (draft.params, dpool, jnp.asarray(toks, jnp.int32),
-                     jnp.asarray(positions, jnp.int32),
-                     jnp.asarray(temps, jnp.float32),
-                     jnp.asarray(top_ks, jnp.int32),
-                     jnp.asarray(top_ps, jnp.float32),
-                     jnp.asarray(seeds, jnp.int32))
-        self._observe_compile("slot_draft", fn, prop_args,
-                              names=("draft_params", "draft_pool", "toks",
-                                     "positions", "temps", "top_ks",
-                                     "top_ps", "seeds"))
-        with self.mesh:
-            dpool, drafts = fn(*prop_args)
+        dpool, drafts = self._pool_call(
+            prop, lambda: (draft.params, dpool, *self._slot_arrays(
+                toks, positions, temps, top_ks, top_ps, seeds)))
         return dpool, np.asarray(drafts)
 
     def slot_verify_step(self, pool, toks, draft_toks, positions, temps,
@@ -1276,110 +1169,63 @@ class InferenceEngine:
         ``target_tokens[s, :accepts[s] + 1]``."""
         model = self.module
         vocab = model.config.vocab_size
-        num_slots, max_len, quantized = self._pool_dims(pool)
+        shape = self._pool_dims(pool)
+        max_len = shape[1]
         draft_toks = np.asarray(draft_toks, np.int32)
         k = int(draft_toks.shape[1])
-        fkey = ("slot_verify", num_slots, max_len, k) + \
-            (("q8",) if quantized else ())
-        fn = self._slot_fns.get(fkey)
-        if fn is None:
-            pool_shardings = self._pool_shardings(num_slots, max_len,
-                                                  quantize=quantized)
-            from .speculative import row_keys, sample_rows
 
-            def ver(params, pool, toks, draft_toks, positions, temps,
-                    top_ks, top_ps, seeds):
-                if quantized:
-                    from .kv_quant import dequantize_pool, quantize_pool
-                    fp_old = dequantize_pool(pool, self.dtype)
-                else:
-                    fp_old = pool
-                block = jnp.concatenate([toks[:, None], draft_toks], axis=1)
-                logits, fp_new = model.verify_with_slots(
-                    params, block, fp_old, positions)      # [S, k+1, V]
-                # target's candidate at offset j would be FED at column
-                # positions + j + 1 — the same key the plain decode path
-                # (and the draft) derives for that position. The "verify"
-                # scope covers sampling + accept math + rollback so the
-                # perf plane prices the whole accept/reject tail as one
-                # bucket distinct from the batched forward above.
-                with jax.named_scope("verify"):
-                    cols = positions[:, None] + 1 + \
-                        jnp.arange(k + 1)[None, :]         # [S, k+1]
-                    tgt = jax.vmap(
-                        lambda lg, cs: sample_rows(
-                            lg, temps, top_ks, top_ps,
-                            row_keys(seeds, cs), vocab),
-                        in_axes=(1, 1), out_axes=1)(logits, cols)
-                    match = (draft_toks == tgt[:, :k]).astype(jnp.int32)
-                    accepts = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
-                # rollback INSIDE the step: only columns this verify
-                # WROTE and the accept prefix covers keep their new
-                # values — everything else (untouched columns AND
-                # rejected writes) restores to the pre-verify lane
-                with jax.named_scope("verify"):
-                    cols_ax = jnp.arange(max_len)[None, :]
-                    keep = (cols_ax >= positions[:, None]) & \
-                        (cols_ax <= (positions + accepts)[:, None])  # [S, C]
+        @self._pool_program("slot_verify", shape[:2] + (k,), shape, outs=2)
+        def ver(params, pool, toks, draft_toks, positions, temps, top_ks,
+                top_ps, seeds):
+            block = jnp.concatenate([toks[:, None], draft_toks], axis=1)
+            logits, fp_new = model.verify_with_slots(
+                params, block, pool_to_fp(pool, self.dtype),
+                positions)                                 # [S, k+1, V]
+            # target's candidate at offset j would be FED at column
+            # positions + j + 1 — the same key the plain decode path
+            # (and the draft) derives for that position
+            with jax.named_scope("verify"):
+                cols = positions[:, None] + 1 + \
+                    jnp.arange(k + 1)[None, :]             # [S, k+1]
+                tgt = jax.vmap(
+                    lambda lg, cs: sample_rows(
+                        lg, temps, top_ks, top_ps,
+                        row_keys(seeds, cs), vocab),
+                    in_axes=(1, 1), out_axes=1)(logits, cols)
+                match = (draft_toks == tgt[:, :k]).astype(jnp.int32)
+                accepts = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
+            # rollback INSIDE the step: only columns this verify
+            # WROTE and the accept prefix covers keep their new
+            # values — everything else (untouched columns AND
+            # rejected writes) restores to the pre-verify lane
+            with jax.named_scope("verify"):
+                cols_ax = jnp.arange(max_len)[None, :]
+                keep = (cols_ax >= positions[:, None]) & \
+                    (cols_ax <= (positions + accepts)[:, None])  # [S, C]
 
-                    def rb(new, old):
-                        # keep [S, C] over a leaf [L, S, C, ...]
-                        return jnp.where(keep.reshape(
-                            (1,) + keep.shape + (1,) * (new.ndim - 3)),
-                            new, old)
+                def rb(new, old):
+                    # keep [S, C] over a leaf [L, S, C, ...]
+                    return jnp.where(keep.reshape(
+                        (1,) + keep.shape + (1,) * (new.ndim - 3)),
+                        new, old)
 
-                    # an int8 pool is restored in QUANTIZED space: original
-                    # q/scale BYTES are copied verbatim for every non-kept
-                    # column, so rolled-back int8 lanes are bit-exact — the
-                    # untouched-column guarantee by construction, immune
-                    # even to ulp-level requantization drift
-                    out_pool = jax.tree.map(
-                        rb, quantize_pool(fp_new) if quantized else fp_new,
-                        pool)
-                return out_pool, tgt, accepts.astype(jnp.int32)
+                # the restore happens in the pool's own storage: for an
+                # int8 pool the original q/scale BYTES are copied verbatim
+                # for every non-kept column, so rolled-back int8 lanes are
+                # bit-exact — the untouched-column guarantee by
+                # construction, immune even to ulp-level requantization
+                # drift
+                out_pool = jax.tree.map(rb, pool_from_fp(fp_new, pool), pool)
+            return out_pool, tgt, accepts.astype(jnp.int32)
 
-            fn = self._slot_fns[fkey] = jax.jit(ver, in_shardings=(
-                self.param_shardings, pool_shardings, None, None, None,
-                None, None, None, None),
-                out_shardings=(pool_shardings, None, None),
-                donate_argnums=(1,))
-        n = len(np.asarray(toks).reshape(-1))
-        if top_ks is None:
-            top_ks = np.zeros((n,), np.int32)
-        if top_ps is None:
-            top_ps = np.ones((n,), np.float32)
-        if seeds is None:
-            seeds = np.zeros((n,), np.int32)
-        ver_args = (self.params, pool, jnp.asarray(toks, jnp.int32),
-                    jnp.asarray(draft_toks, jnp.int32),
-                    jnp.asarray(positions, jnp.int32),
-                    jnp.asarray(temps, jnp.float32),
-                    jnp.asarray(top_ks, jnp.int32),
-                    jnp.asarray(top_ps, jnp.float32),
-                    jnp.asarray(seeds, jnp.int32))
-        self._observe_compile("slot_verify", fn, ver_args,
-                              names=("params", "pool", "toks", "draft_toks",
-                                     "positions", "temps", "top_ks",
-                                     "top_ps", "seeds"))
-        with self.mesh:
-            pool, tgt, accepts = fn(*ver_args)
+        def prep():
+            toks_d, *rest = self._slot_arrays(toks, positions, temps, top_ks,
+                                              top_ps, seeds)
+            return (self.params, pool, toks_d,
+                    jnp.asarray(draft_toks, jnp.int32), *rest)
+
+        pool, tgt, accepts = self._pool_call(ver, prep)
         return pool, np.asarray(tgt), np.asarray(accepts)
-
-    def slot_verify_executables(self, num_slots: int, max_len: int, k: int,
-                                quantized: Optional[bool] = None) -> int:
-        """Compiled-executable count behind the speculative verify step
-        for one K flavor — the pow2-K compile-once evidence the tests
-        assert (mirrors slot_decode_executables)."""
-        keys = {None: (("slot_verify", num_slots, max_len, k),
-                       ("slot_verify", num_slots, max_len, k, "q8")),
-                False: (("slot_verify", num_slots, max_len, k),),
-                True: (("slot_verify", num_slots, max_len, k, "q8"),)}
-        total = 0
-        for fkey in keys[quantized]:
-            fn = self._slot_fns.get(fkey)
-            if fn is not None:
-                total += fn._cache_size()
-        return total
 
     # ------------------------------------------------------------- properties
     @property

@@ -297,45 +297,6 @@ class CompilePlaneConfig(DeepSpeedConfigModel):
 
 
 @dataclasses.dataclass
-class PerfPlaneConfig(DeepSpeedConfigModel):
-    """The ``"perf_plane"`` config block (telemetry/perfplane.py): the
-    step/tick anatomy engine. Disabled (the default) allocates nothing —
-    no PerfPlane object, no per-program anatomies, no ``anat/*`` gauges.
-    Enabling it requires ``compile_plane.enabled`` (+``memory_analysis``,
-    its default): the anatomy is computed from the optimized HLO text the
-    compile ledger already captures per compile event.
-
-    - ``band`` / ``band_floor_ms``: the edge-trigger for the
-      ``perf_regression`` flight bundle — a RECOMPILE whose anatomy
-      shifts any bucket by more than ``band`` (fraction of the previous
-      value) AND more than ``band_floor_ms`` absolute fires a bundle
-      naming the shifted bucket(s). First sight of a label never fires.
-    - ``history``: observed-program records kept for /statusz.
-    - ``device_model``: alpha-beta overrides (``peak_flops``,
-      ``hbm_bandwidth``, ``link_bandwidth``, ``op_latency_s``,
-      ``overlap_efficiency``) — defaults mirror the PR-15 schedule cost
-      model; re-pin from ``calibrate_cost_model`` on hardware."""
-    enabled: bool = False
-    band: float = 0.25
-    band_floor_ms: float = 0.05
-    history: int = 32
-    device_model: Dict[str, Any] = dataclasses.field(default_factory=dict)
-
-    def validate(self):
-        if self.band <= 0:
-            raise ConfigError("perf_plane.band must be > 0")
-        if self.band_floor_ms < 0:
-            raise ConfigError("perf_plane.band_floor_ms must be >= 0")
-        if self.history < 1:
-            raise ConfigError("perf_plane.history must be >= 1")
-        for k in self.device_model:
-            if k not in ("peak_flops", "hbm_bandwidth", "link_bandwidth",
-                         "op_latency_s", "overlap_efficiency"):
-                raise ConfigError(
-                    f"perf_plane.device_model: unknown key {k!r}")
-
-
-@dataclasses.dataclass
 class FlopsProfilerConfig(DeepSpeedConfigModel):
     enabled: bool = False
     profile_step: int = 1
@@ -444,8 +405,6 @@ class DeepSpeedConfig:
         self.hostagg = HostAggConfig.from_dict(pd.get(C.HOSTAGG, {}))
         self.compile_plane = CompilePlaneConfig.from_dict(
             pd.get(C.COMPILE_PLANE, {}))
-        self.perf_plane = PerfPlaneConfig.from_dict(
-            pd.get(C.PERF_PLANE, {}))
         self.flops_profiler = FlopsProfilerConfig.from_dict(pd.get(C.FLOPS_PROFILER, {}))
         self.checkpoint_config = CheckpointConfig.from_dict(pd.get(C.CHECKPOINT, {}))
         # fault tolerance: checkpoint integrity/fallback, preemption
@@ -546,13 +505,6 @@ class DeepSpeedConfig:
             raise ConfigError(
                 "ZeRO stage >= 2 is incompatible with pipeline parallelism "
                 "(reference: engine.py:1414-1417)")
-        if self.perf_plane.enabled and not (
-                self.compile_plane.enabled and
-                self.compile_plane.memory_analysis):
-            raise ConfigError(
-                "perf_plane requires compile_plane.enabled with "
-                "memory_analysis: the anatomy is computed from the "
-                "optimized HLO the compile ledger captures per event")
 
     # -- convenience mirrors of reference engine properties
     @property

@@ -87,7 +87,6 @@ STATUSZ = "statusz"
 FLIGHT_RECORDER = "flight_recorder"
 HOSTAGG = "hostagg"
 COMPILE_PLANE = "compile_plane"
-PERF_PLANE = "perf_plane"
 FLOPS_PROFILER = "flops_profiler"
 RESILIENCE = "resilience"
 

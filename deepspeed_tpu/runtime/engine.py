@@ -472,21 +472,6 @@ class DeepSpeedEngine:
                     floor=cpcfg.overlap_floor, recorder=self._recorder)
             if self._recorder is not None:
                 self._recorder.attach_compile_plane(self._compile_plane)
-        # perf plane (telemetry/perfplane.py): step anatomy per compile
-        # event, anat/* gauges, perf_regression trigger. Rides the
-        # compile ledger's HLO capture; config validation already
-        # guarantees compile_plane is on when this is.
-        self._perf_plane = None
-        ppcfg = cfg.perf_plane
-        if ppcfg.enabled and self._compile_plane is not None:
-            from ..telemetry.perfplane import PerfPlane
-            self._perf_plane = PerfPlane(ppcfg, tracer=self.tracer,
-                                         owner=self,
-                                         recorder=self._recorder)
-            self._compile_plane.attach_perf_plane(self._perf_plane)
-            if self._recorder is not None:
-                self._recorder.add_provider(
-                    "anatomy", self._perf_plane.bundle_section)
         # per-engine monitor-event buffer (bounded: survives a disabled
         # monitor without growing) — NOT the tracer's global queue, so two
         # engines in one process can't drain each other's events
@@ -542,8 +527,6 @@ class DeepSpeedEngine:
             if self._compile_plane is not None:
                 self.statusz.register("compile_plane",
                                       self._compile_plane.summary)
-            if self._perf_plane is not None:
-                self.statusz.register("anatomy", self._perf_plane.summary)
             if self._hbm is not None:
                 self.statusz.register("memory", self._hbm.summary)
             if self._overlap is not None:

@@ -471,12 +471,6 @@ class ServingConfig(DeepSpeedConfigModel):
     # HBM role ledger (params / kv_slots -> dstpu_mem_* gauges)
     compile_plane: Any = None
 
-    # perf_plane (dict -> runtime.config.PerfPlaneConfig): per-program
-    # anatomy over the serving ticks (decode/verify/chunked-prefill
-    # bucket decomposition, dstpu_anat_* gauges, perf_regression
-    # trigger); requires compile_plane.enabled
-    perf_plane: Any = None
-
     # resilience (dict -> resilience.config.ResilienceConfig): with
     # handle_signals, SIGTERM/SIGINT stops admissions and drains in-flight
     # requests at the next tick (running slots complete, queued requests
@@ -575,18 +569,6 @@ class ServingConfig(DeepSpeedConfigModel):
                 self.compile_plane)
         elif self.compile_plane is None:
             self.compile_plane = CompilePlaneConfig()
-        from ..runtime.config import PerfPlaneConfig
-        if isinstance(self.perf_plane, dict):
-            self.perf_plane = PerfPlaneConfig.from_dict(self.perf_plane)
-        elif self.perf_plane is None:
-            self.perf_plane = PerfPlaneConfig()
-        if self.perf_plane.enabled and not (
-                self.compile_plane.enabled and
-                self.compile_plane.memory_analysis):
-            raise ConfigError(
-                "serving.perf_plane requires compile_plane.enabled with "
-                "memory_analysis: the anatomy is computed from the "
-                "optimized HLO the compile ledger captures per event")
         from ..resilience.config import ResilienceConfig
         if isinstance(self.resilience, dict):
             self.resilience = ResilienceConfig.from_dict(self.resilience)

@@ -113,21 +113,6 @@ class ServingEngine:
                 self._hbm_interval = int(cpcfg.hbm_interval_steps)
             if self._recorder is not None:
                 self._recorder.attach_compile_plane(self._compile_plane)
-        # perf plane: tick anatomy per compile event (decode/verify/
-        # chunked-prefill buckets), anat/* gauges, perf_regression
-        # trigger — rides the compile ledger's HLO capture
-        self._perf_plane = None
-        ppcfg = getattr(config, "perf_plane", None)
-        if getattr(ppcfg, "enabled", False) and \
-                self._compile_plane is not None:
-            from ..telemetry.perfplane import PerfPlane
-            self._perf_plane = PerfPlane(ppcfg, tracer=self.tracer,
-                                         owner=self,
-                                         recorder=self._recorder)
-            self._compile_plane.attach_perf_plane(self._perf_plane)
-            if self._recorder is not None:
-                self._recorder.add_provider(
-                    "anatomy", self._perf_plane.bundle_section)
         self.statusz = None
         if getattr(config.statusz, "enabled", False):
             from ..telemetry.statusz import StatuszServer
@@ -139,8 +124,6 @@ class ServingEngine:
             if self._compile_plane is not None:
                 self.statusz.register("compile_plane",
                                       self._compile_plane.summary)
-            if self._perf_plane is not None:
-                self.statusz.register("anatomy", self._perf_plane.summary)
             if self._hbm is not None:
                 self.statusz.register("memory", self._hbm.summary)
         self.scheduler = ContinuousBatchingScheduler(
@@ -652,6 +635,6 @@ class ServingEngine:
         """Compiled-executable count of the fused decode step (the
         compile-once contract: stays 1 across differing prompt lengths),
         for THIS engine's pool flavor (fp vs quantized)."""
-        return self.engine.slot_decode_executables(
-            self.config.num_slots, self.config.max_model_len,
+        return self.engine.slot_executables(
+            "slot_decode", self.config.num_slots, self.config.max_model_len,
             quantized=self.scheduler.pool.quantized)

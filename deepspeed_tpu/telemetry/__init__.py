@@ -33,9 +33,6 @@ from .disttrace import (TraceContext, FleetAggregator, merge_chrome_traces,
                         split_events_by_replica, CRITICAL_PATH_STAGES)
 from .scorecard import (SCORECARD_KIND, INVARIANTS, check_invariants,
                         fold_scorecard, diff_scorecards, write_scorecard)
-from .perfplane import (ANATOMY_KIND, PerfPlane, anatomy_from_hlo,
-                        reconcile_anatomy, diff_anatomy,
-                        check_anatomy_invariants, write_anatomy)
 
 __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
            "configure_tracer", "chrome_trace", "write_chrome_trace",
@@ -49,7 +46,4 @@ __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
            "TraceContext", "FleetAggregator", "merge_chrome_traces",
            "split_events_by_replica", "CRITICAL_PATH_STAGES",
            "SCORECARD_KIND", "INVARIANTS", "check_invariants",
-           "fold_scorecard", "diff_scorecards", "write_scorecard",
-           "ANATOMY_KIND", "PerfPlane", "anatomy_from_hlo",
-           "reconcile_anatomy", "diff_anatomy", "check_anatomy_invariants",
-           "write_anatomy"]
+           "fold_scorecard", "diff_scorecards", "write_scorecard"]

@@ -161,12 +161,6 @@ class CompileLedger:
         self.analysis_compile_ms = 0.0   # total AOT-analysis compile time
         self.last_recompile: Optional[Dict[str, Any]] = None
         self._next_id = 1
-        #: optional telemetry.perfplane.PerfPlane; when attached, every
-        #: analyzed event's HLO gets an anatomy (attach_perf_plane)
-        self._perf_plane = None
-
-    def attach_perf_plane(self, perf_plane):
-        self._perf_plane = perf_plane
 
     # ------------------------------------------------------------ observing
     @staticmethod
@@ -328,14 +322,6 @@ class CompileLedger:
                 "overlap/hlo_static_fraction",
                 ev["overlap"].get("static_overlap_fraction", 0.0),
                 owner=self._owner)
-            if self._perf_plane is not None:
-                # perf plane: bucket anatomy of this exact program,
-                # attached to the event (postmortem bundles embed it)
-                # and gauged; a banded recompile shift fires
-                # perf_regression from inside observe_program
-                self._perf_plane.observe_program(
-                    ev["label"], hlo, kind=ev["kind"], step=ev["step"],
-                    event=ev)
         except Exception as e:
             ev["analysis_error"] = str(e)
 

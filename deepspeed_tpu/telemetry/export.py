@@ -219,7 +219,6 @@ def prometheus_dump(tracer: Optional[Tracer] = None,
     host_lines: List[str] = []
     tenant_series: Dict[str, List[str]] = {}
     cost_series: Dict[str, List[str]] = {}
-    anat_series: Dict[str, List[str]] = {}
     lines.append(f"# TYPE {prefix}_metric gauge")
     for tag, (val, _step) in sorted(tracer.counters().items()):
         try:
@@ -306,23 +305,6 @@ def prometheus_dump(tracer: Optional[Tracer] = None,
             host_lines.append(f"# TYPE {prefix}_spec_{name} gauge")
             host_lines.append(f"{prefix}_spec_{name} {fval}")
             continue
-        if tag.startswith("anat/"):
-            # perf-plane anatomy gauges (telemetry/perfplane.py): one
-            # program=-labeled dstpu_anat_<bucket>_ms family per bucket
-            # so a dashboard stacks a step/tick's time decomposition
-            # with one query; bare anat/<metric> (regressions counter)
-            # exports unlabeled
-            pname, _, metric = tag[len("anat/"):].partition("/")
-            if metric:
-                name = _prom(metric)
-                anat_series.setdefault(name, []).append(
-                    f'{prefix}_anat_{name}{{program="{_prom(pname)}"}} '
-                    f"{fval}")
-            else:
-                name = _prom(pname)
-                host_lines.append(f"# TYPE {prefix}_anat_{name} gauge")
-                host_lines.append(f"{prefix}_anat_{name} {fval}")
-            continue
         if tag.startswith("rollout/"):
             # rollout plane gauges (serving/metrics.py update_rollout):
             # dedicated dstpu_rollout_shift_fraction / _version_skew /
@@ -343,9 +325,6 @@ def prometheus_dump(tracer: Optional[Tracer] = None,
     for name in sorted(cost_series):
         lines.append(f"# TYPE {prefix}_cost_{name} gauge")
         lines.extend(cost_series[name])
-    for name in sorted(anat_series):
-        lines.append(f"# TYPE {prefix}_anat_{name} gauge")
-        lines.extend(anat_series[name])
     aggs = span_aggregates(tracer)
     if aggs:
         lines.append(f"# TYPE {prefix}_span_ms_total counter")

@@ -61,15 +61,11 @@ __all__ = ["FlightRecorder", "TRIGGER_KINDS"]
 #: ``trial_best`` / ``trial_worst`` are fired once per measured autotuning
 #: sweep (autotuning/measure.py) with the winning and losing trial's
 #: goodput table, compile events, and score breakdown embedded — every
-#: tuning decision stays auditable post-hoc. ``perf_regression`` is the
-#: perf twin of ``overlap_drop``: a recompile whose step/tick anatomy
-#: shifts a bucket beyond the perf plane's configured band
-#: (telemetry/perfplane.py), edge-triggered with the shifted bucket
-#: names in the detail.
+#: tuning decision stays auditable post-hoc.
 TRIGGER_KINDS = ("slow_step", "recompile", "sentinel", "slo_burn",
                  "preemption", "straggler", "failover", "overlap_drop",
                  "acceptance_drop", "resize", "rollout_failed",
-                 "trial_best", "trial_worst", "perf_regression", "manual")
+                 "trial_best", "trial_worst", "manual")
 
 
 class FlightRecorder:

@@ -196,7 +196,7 @@ def test_chunk_compile_once_per_pow2_flavor(engine):
     before = set(engine._slot_fns)
     _serve(engine, {"num_slots": 4, "max_model_len": 1024,
                     "max_queue": 8, **CHUNKED}, subs)
-    assert engine.slot_chunk_executables(4, 1024, 64) == 1
+    assert engine.slot_executables("slot_chunk", 4, 64, 1024) == 1
     # chunking compiled NO monolithic prefill flavor: every program the
     # run added stays at/below the chunk bucket (the engine fixture is
     # shared, so compare against the pre-run key set)
@@ -259,7 +259,7 @@ def test_4k_prompt_stall_free_ticks(engine4k):
     # one chunk is 1/16th of the monolithic prefill's work)
     assert max(walls) < mono_spike
     # and the chunk program for this pool compiled exactly once
-    assert engine4k.slot_chunk_executables(2, 4300, chunk) == 1
+    assert engine4k.slot_executables("slot_chunk", 2, chunk, 4300) == 1
     srv.run_until_idle()
     srv.shutdown()
 

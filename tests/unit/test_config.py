@@ -107,6 +107,20 @@ def test_unknown_zero_key_raises():
                         world_size=8)
 
 
+@pytest.mark.parametrize("block", ("no_such_plane", "plane_that_was_removed"))
+def test_a_block_nobody_defines(block):
+    """What a config block meets once its subsystem is deleted and no shim
+    is left (ISSUE 33 removed one so): the strict ``ServingConfig`` refuses
+    it by name; ``DeepSpeedConfig`` reads no top-level block it does not
+    know and keeps no attribute for it."""
+    from deepspeed_tpu.serving.config import ServingConfig
+    with pytest.raises(ConfigError, match=f"unknown config key.*{block}"):
+        ServingConfig.from_dict({"num_slots": 2, block: {"enabled": True}})
+    cfg = DeepSpeedConfig({"train_batch_size": 8, block: {"enabled": True}},
+                          world_size=8)
+    assert not hasattr(cfg, block)
+
+
 def test_zero_plus_plus_knobs_raise():
     """zero_quantized_weights/gradients post-date the reference version and
     have no wired path — accepted config must be active config."""

@@ -221,36 +221,6 @@ def test_moe_gauges_owned_and_released(tracer):
     assert not [t for t in tracer.counters() if t.startswith("moe/")]
 
 
-def test_perfplane_gauges_owned_and_released(tracer):
-    """PR 19: the dstpu_anat_* family (telemetry/perfplane.py PerfPlane
-    per-program anatomy gauges) follows the same owner/retraction
-    contract — live with its producer, gone from /metrics after
-    close()."""
-    from deepspeed_tpu.telemetry import prometheus_dump
-    from deepspeed_tpu.telemetry.perfplane import PerfPlane
-
-    hlo = """HloModule synth
-
-ENTRY %main (p0: f32[128,128]) -> f32[128,128] {
-  %p0 = f32[128,128] parameter(0)
-  %dot.1 = f32[128,128] dot(f32[128,128] %p0, f32[128,128] %p0), lhs_contracting_dims={1}, rhs_contracting_dims={0}, metadata={op_name="jit(step)/attn/qk"}
-  ROOT %ar = f32[128,128] all-reduce(f32[128,128] %dot.1), replica_groups={}
-}
-"""
-    plane = PerfPlane(tracer=tracer)
-    anat = plane.observe_program("step", hlo, kind="compile")
-    assert anat["total_ms"] > 0
-    dump = prometheus_dump(tracer)
-    assert 'dstpu_anat_total_ms{program="step"}' in dump
-    assert 'dstpu_anat_memory_bound_fraction{program="step"}' in dump
-    assert 'dstpu_anat_coll_all_reduce_ms{program="step"}' in dump
-    _assert_all_owned(tracer, "perf plane live")
-    plane.close()
-    dump = prometheus_dump(tracer)
-    assert "dstpu_anat_" not in dump
-    assert not [t for t in tracer.counters() if t.startswith("anat/")]
-
-
 def test_prometheus_dump_reflects_retraction(tracer):
     """The exported text is the user-visible surface of the contract: a
     family present while live must be absent after its producer closes."""

@@ -142,13 +142,22 @@ def test_rigged_vnext_fails_canary_rolls_back_one_bundle(engine, tmp_path):
     verify must catch it, roll back, leave the fleet untouched, and
     fire exactly one rollout_failed bundle with the canary diff and
     burn timeline embedded."""
-    import jax
     router = build_fleet(engine, _fleet_cfg(
         {"flight_recorder": {"enabled": True, "dir": str(tmp_path)}},
         replicas=2))
     _warm(router)
     before = _live(router)
-    bad = jax.tree_util.tree_map(lambda x: x * 1.25 + 0.01, engine.params)
+    # a vNext that greedy decoding can tell apart: the final LayerNorm's
+    # bias points at token 0's embedding, so every token comes out 0. (A
+    # uniform ``x * 1.25 + 0.01`` scales and shifts every logit of this
+    # tied-embedding model alike and left the two replayed requests'
+    # tokens as they were: the rollout ended ``done``.)
+    # Placed as the served bias is: a new layout would be a new program,
+    # which the canary refuses before it compares a token.
+    import jax
+    bad = dict(engine.params)
+    bad["ln_f_bias"] = jax.device_put(1000.0 * engine.params["wte"][0],
+                                      engine.params["ln_f_bias"].sharding)
     ctl = router.start_rollout(
         engine.with_params(bad, engine.weights_version))
     _run_rollout(router, ctl)

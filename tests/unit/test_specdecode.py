@@ -248,7 +248,7 @@ def test_pow2_k_buckets_compile_once(engine):
         srv.run_until_idle()
         assert all(srv.result(r).state is RequestState.FINISHED
                    for r in rids)
-        assert engine.slot_verify_executables(4, 64, 2) == 1
+        assert engine.slot_executables("slot_verify", 4, 64, 2) == 1
         ver_events = [e for e in ledger.events()
                       if e["label"] == "slot_verify"]
         assert len(ver_events) == 1 and ver_events[0]["kind"] == "compile"
@@ -260,7 +260,7 @@ def test_pow2_k_buckets_compile_once(engine):
         rid = srv4.submit(prompts[0], SamplingParams(max_new_tokens=6))
         srv4.run_until_idle()
         assert srv4.result(rid).state is RequestState.FINISHED
-        assert engine.slot_verify_executables(4, 64, 4) == 1
+        assert engine.slot_executables("slot_verify", 4, 64, 4) == 1
         ver_events = [e for e in ledger.events()
                       if e["label"] == "slot_verify"]
         assert len(ver_events) == 2
