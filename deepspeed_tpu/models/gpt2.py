@@ -294,9 +294,11 @@ class GPT2Model(ModelSpec):
         out = hmid @ p["mlp_proj_w"].astype(hmid.dtype) + p["mlp_proj_b"].astype(hmid.dtype)
         return x + self._dropout(out, rng, train, 1), jnp.float32(0.0)
 
-    def _block(self, x, layer_params, rng, train, extra=None):
+    def _block(self, x, layer_params, rng, train, extra=None, **routed):
         """One decoder block. Returns (x, aux_loss) — aux is nonzero only for
         MoE variants. ``extra``: this layer's slice of _layer_extras().
+        ``routed``: ``stacked=(whole, layer)`` from a serving scan whose
+        family left leaves whole (``_scan_split``), for its MLP sublayer.
 
         named_scope phases feed the flops profiler's per-phase attribution
         (and label the XLA fusions in device traces) — they cost nothing at
@@ -304,7 +306,7 @@ class GPT2Model(ModelSpec):
         with jax.named_scope("attn"):
             x = self._attn_sublayer(x, layer_params, rng, train, extra=extra)
         with jax.named_scope("mlp"):
-            return self._mlp_sublayer(x, layer_params, rng, train)
+            return self._mlp_sublayer(x, layer_params, rng, train, **routed)
 
     def _decode_block(self, x, layer_params, attn_fn, start_pos,
                       positions=None, extra=None):
@@ -346,6 +348,16 @@ class GPT2Model(ModelSpec):
         Families with layer-dependent attention (GPT-Neo's alternating
         local/global) return a flag vector; base models return None."""
         return None
+
+    def _scan_split(self, blocks, cached):
+        """``params["blocks"]`` as a layer scan takes it: (the subtree it
+        slices a layer at a time, leaves its body closes over whole, or
+        None). A family with routed experts hands back their matmul leaves
+        (``MOELayer.take_whole``); its block then takes
+        ``stacked=(whole, layer)`` and reads them where they lie.
+        ``cached``: the scan is a cache forward's (else
+        ``hidden_states``' with ``train=False``). Dense: nothing whole."""
+        return blocks, None
 
     def _train_attn_bias_ex(self, t, extra):
         """Layer-aware training attention bias; base defers to the
@@ -389,18 +401,23 @@ class GPT2Model(ModelSpec):
         use_wrappers = train and rng is not None
         t = x.shape[1]
         extras = self._layer_extras()
+        # serving runs the code the cache forwards run; training's scan
+        # slices every leaf
+        blocks, whole = (params["blocks"], None) if train else \
+            self._scan_split(params["blocks"], cached=False)
 
         def body(carry, xs):
             layer_params, extra = xs if extras is not None else (xs, None)
             h, i, aux = carry
             layer_rng = None if rng is None else jax.random.fold_in(rng, i)
+            routed = {} if whole is None else {"stacked": (whole, i)}
 
             def blk(hh):
                 if act_bits is not None:
                     from ..ops.quantizer_ops import fake_quantize
                     hh = fake_quantize(hh, bits=act_bits)
                 return self._block(hh, layer_params, layer_rng, train,
-                                   extra=extra)
+                                   extra=extra, **routed)
 
             run = blk
             if use_wrappers and ltd_keep is not None and ltd_keep < t:
@@ -433,8 +450,7 @@ class GPT2Model(ModelSpec):
             from ..runtime.activation_checkpointing.checkpointing import \
                 get_policy
             body_fn = jax.checkpoint(body, policy=get_policy(cfg.remat_policy))
-        xs = params["blocks"] if extras is None else (params["blocks"],
-                                                      extras)
+        xs = blocks if extras is None else (blocks, extras)
         (x, _, aux_total), _ = lax.scan(
             body_fn, (x, 0, jnp.float32(0.0)), xs,
             unroll=min(max(1, int(getattr(cfg, "scan_unroll", 1))),
@@ -744,6 +760,7 @@ class GPT2Model(ModelSpec):
             pad_valid = (jnp.arange(max_len)[None, :] >=
                          pad_counts[:, None])[:, None, None, :]
         extras = self._layer_extras()
+        blocks, whole = self._scan_split(params["blocks"], cached=True)
 
         def keep_mask(extra):
             mask = self._decode_attn_mask_ex(q_pos, k_pos, extra)
@@ -756,6 +773,7 @@ class GPT2Model(ModelSpec):
             x, k_pool, v_pool = carry
             layer_params, layer, extra = xs
             mask = base_mask if extras is None else keep_mask(extra)
+            routed = {} if whole is None else {"stacked": (whole, layer)}
             new_kv = {}
 
             def cached_attn(q, k, v):
@@ -774,10 +792,10 @@ class GPT2Model(ModelSpec):
 
             x, stats = self._split_routing(self._decode_block(
                 x, layer_params, cached_attn, start_pos,
-                positions=positions, extra=extra))
+                positions=positions, extra=extra, **routed))
             return (x, new_kv["k"], new_kv["v"]), stats
 
-        xs = (params["blocks"], jnp.arange(self.config.n_layer), extras)
+        xs = (blocks, jnp.arange(self.config.n_layer), extras)
         (x, new_k, new_v), stats = lax.scan(
             body, (x, cache["k"], cache["v"]), xs)
         x = self._final_norm(params, x)

@@ -70,8 +70,14 @@ class GPT2MoEModel(GPT2Model):
         y, l_aux, _ = self.moe.apply(p["moe"], ln2, rng=rng, train=train)
         return x + self._dropout(y, rng, train, 1), l_aux
 
+    def _scan_split(self, blocks, cached):
+        if not cached:      # without a cache, eval is the capacity dispatch
+            return blocks, None
+        moe, whole = self.moe.take_whole(blocks["moe"])
+        return {**blocks, "moe": moe}, whole
+
     def _decode_block(self, x, layer_params, attn_fn, start_pos,
-                      positions=None, extra=None):
+                      positions=None, extra=None, stacked=None):
         """KV-cache decode block: attention from the base class, the MoE
         FFN routed and dropless (no capacity, no noise): the reference's
         MoE inference semantics (ops/transformer/inference/
@@ -86,7 +92,8 @@ class GPT2MoEModel(GPT2Model):
         with jax.named_scope("moe"):
             ln2 = _layer_norm(x, p["ln2_scale"], p["ln2_bias"],
                               cfg.layer_norm_epsilon)
-            y, _, counts = self.moe.apply_routed(p["moe"], ln2)
+            y, _, counts = self.moe.apply_routed(p["moe"], ln2,
+                                                 stacked=stacked)
         return x + y, counts
 
     # ------------------------------------------------------------- sharding

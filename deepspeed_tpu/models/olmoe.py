@@ -82,31 +82,35 @@ class OLMoEModel(LlamaModel):
         return (_rms_norm(q, p["q_norm_scale"], eps),
                 _rms_norm(k, p["k_norm_scale"], eps))
 
-    def _routed_mlp(self, x, p):
+    def _scan_split(self, blocks, cached):
+        moe, whole = self.moe.take_whole(blocks["moe"])
+        return {**blocks, "moe": moe}, whole
+
+    def _routed_mlp(self, x, p, stacked=None):
         """(x + experts(RMSNorm(x)), exp_counts): every token routed."""
         cfg = self.config
         ln2 = _rms_norm(x, p["ln2_scale"], cfg.layer_norm_epsilon)
         y, _, counts = self.moe.apply_routed(
-            p["moe"], ln2, renormalize=cfg.norm_topk_prob)
+            p["moe"], ln2, renormalize=cfg.norm_topk_prob, stacked=stacked)
         return x + y, counts
 
-    def _mlp_sublayer(self, x, p, rng, train):
+    def _mlp_sublayer(self, x, p, rng, train, stacked=None):
         if not train:
-            return self._routed_mlp(x, p)[0], jnp.float32(0.0)
+            return self._routed_mlp(x, p, stacked)[0], jnp.float32(0.0)
         cfg = self.config
         ln2 = _rms_norm(x, p["ln2_scale"], cfg.layer_norm_epsilon)
         y, l_aux, _ = self.moe.apply(p["moe"], ln2, rng=rng, train=True)
         return x + self._dropout(y, rng, train, 1), l_aux
 
     def _decode_block(self, x, layer_params, attn_fn, start_pos,
-                      positions=None, extra=None):
+                      positions=None, extra=None, stacked=None):
         """Returns (x, exp_counts): the cache forwards hand the counts on."""
         with jax.named_scope("attn"):
             x = self._attn_sublayer(x, layer_params, None, False,
                                     attn_fn=attn_fn, start_pos=start_pos,
                                     positions=positions, extra=extra)
         with jax.named_scope("moe"):
-            return self._routed_mlp(x, layer_params)
+            return self._routed_mlp(x, layer_params, stacked)
 
     # ------------------------------------------------------------- sharding
     def partition_rules(self):

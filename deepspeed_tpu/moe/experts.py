@@ -11,7 +11,9 @@ tokens (the capacity-based training dispatch). ``apply_grouped`` takes the
 routed, dropless serving layout: ``[N, M]`` rows sorted by expert with
 ``group_sizes [E]`` rows each, and runs every matmul as one grouped matmul
 (``jax.lax.ragged_dot``; on the TPU the compiler makes it one Mosaic kernel
-whose FLOPs are the routed rows', not E times them).
+whose FLOPs are the routed rows', not E times them). With ``layer`` given,
+the matmul leaves are the STACKED ``[L, E, ...]`` leaves of all L layers and
+are read where they lie (``_groups``).
 """
 
 import math
@@ -21,8 +23,37 @@ import jax.numpy as jnp
 from jax import lax
 
 
+def _groups(w, layer, dt):
+    """A grouped matmul's right-hand side. ``w`` [E, K, N] is one layer's
+    leaf. With ``layer`` given it is the stacked leaf [L, E, K, N] of all L
+    layers, read where it lies: seen as L * E groups (a reshape of leading
+    axes, no copy) of which ``_group_sizes`` gives only that layer's E any
+    rows. The TPU kernel visits the row tiles that exist and fetches each
+    one's weight block by group id, so empty groups are never visited; a
+    layer's [E, K, N] sliced out of the stack first is a copy of all of it,
+    since a slice can not fuse into the kernel's custom call (PERF.md,
+    PR 34). Compute follows the parameters' type, so the cast is none; a
+    real one would convert all L layers in every layer."""
+    if layer is not None:
+        w = w.reshape((-1,) + w.shape[2:])
+    return w.astype(dt)
+
+
+def _group_sizes(group_sizes, w, layer):
+    """``group_sizes`` [E] for ``_groups(w, layer, .)``: placed at
+    ``layer * E`` of a zero [L * E] vector where ``w`` is stacked, so the
+    sorted rows keep their offsets."""
+    if layer is None:
+        return group_sizes
+    l, e = w.shape[:2]
+    return lax.dynamic_update_slice(
+        jnp.zeros((l * e,), group_sizes.dtype), group_sizes, (layer * e,))
+
+
 class ExpertFFN:
     """Stacked per-expert 2-layer MLP: [E, M] → [E, F] → [E, M]."""
+
+    matmul_leaves = ("wi", "wo")     # what apply_grouped can take stacked
 
     def __init__(self, model_dim: int, ffn_dim: int, num_experts: int,
                  activation=None, initializer_range: float = 0.02):
@@ -52,14 +83,17 @@ class ExpertFFN:
         y = jnp.einsum("ecf,efm->ecm", h, params["wo"].astype(dt))
         return y + params["bo"][:, None, :].astype(dt)
 
-    def apply_grouped(self, params, x, group_sizes, expert_ids):
+    def apply_grouped(self, params, x, group_sizes, expert_ids, layer=None):
         """x: [N, M] rows sorted by expert, ``group_sizes`` [E] rows per
         expert, ``expert_ids`` [N] each row's expert (for the biases)
-        → [N, M]."""
+        → [N, M]. ``layer``: ``wi`` and ``wo`` are the stacked [L, E, ...]
+        leaves and this is layer ``layer`` of them (the biases stay this
+        layer's own [E, ...])."""
         dt = x.dtype
-        h = lax.ragged_dot(x, params["wi"].astype(dt), group_sizes)
+        sizes = _group_sizes(group_sizes, params["wi"], layer)
+        h = lax.ragged_dot(x, _groups(params["wi"], layer, dt), sizes)
         h = self.activation(h + params["bi"].astype(dt)[expert_ids])
-        y = lax.ragged_dot(h, params["wo"].astype(dt), group_sizes)
+        y = lax.ragged_dot(h, _groups(params["wo"], layer, dt), sizes)
         return y + params["bo"].astype(dt)[expert_ids]
 
 
@@ -67,6 +101,8 @@ class GatedExpertFFN:
     """Stacked per-expert gated (SwiGLU) MLP without biases:
     ``down(silu(gate(x)) * up(x))``, [E, M] → [E, F] → [E, M] — the expert
     of the LLaMA-shaped MoE families (OLMoE, Mixtral)."""
+
+    matmul_leaves = ("w_gate", "w_up", "w_down")
 
     def __init__(self, model_dim: int, ffn_dim: int, num_experts: int,
                  initializer_range: float = 0.02):
@@ -94,10 +130,14 @@ class GatedExpertFFN:
         return jnp.einsum("ecf,efm->ecm", jax.nn.silu(g) * u,
                           params["w_down"].astype(dt))
 
-    def apply_grouped(self, params, x, group_sizes, expert_ids=None):
-        """x: [N, M] rows sorted by expert, ``group_sizes`` [E] → [N, M]."""
+    def apply_grouped(self, params, x, group_sizes, expert_ids=None,
+                      layer=None):
+        """x: [N, M] rows sorted by expert, ``group_sizes`` [E] → [N, M].
+        ``layer``: the three leaves are the stacked [L, E, ...] leaves and
+        this is layer ``layer`` of them."""
         dt = x.dtype
-        g = lax.ragged_dot(x, params["w_gate"].astype(dt), group_sizes)
-        u = lax.ragged_dot(x, params["w_up"].astype(dt), group_sizes)
+        sizes = _group_sizes(group_sizes, params["w_gate"], layer)
+        g = lax.ragged_dot(x, _groups(params["w_gate"], layer, dt), sizes)
+        u = lax.ragged_dot(x, _groups(params["w_up"], layer, dt), sizes)
         return lax.ragged_dot(jax.nn.silu(g) * u,
-                              params["w_down"].astype(dt), group_sizes)
+                              _groups(params["w_down"], layer, dt), sizes)

@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..parallel.constraints import maybe_constraint
+from ..parallel.constraints import active_mesh, maybe_constraint
 from ..parallel.topology import DATA_AXIS, EXPERT_AXIS
 
 
@@ -361,7 +361,30 @@ class MOELayer:
             y = maybe_constraint(y, (DATA_AXIS, EXPERT_AXIS), None)
         return y.reshape(*lead, m), l_aux, exp_counts
 
-    def apply_routed(self, params, x, renormalize=None):
+    def take_whole(self, stacked):
+        """For a layer scan over L such layers, split their params
+        (``stacked``: every leaf [L, ...]) into (what the scan slices a
+        layer at a time, the experts' matmul leaves its body closes over
+        whole and hands ``apply_routed`` as ``stacked=(whole, layer)``).
+        Sliced by the scan, each [E, ...] leaf is copied out of the stack
+        every layer of every call before the grouped matmul may read it:
+        on the chip twice the time of the matmuls themselves (PERF.md, PR
+        34). Decided on what is seen here, and ``(stacked, None)`` keeps
+        the slice: a leaf that is no plain array (an int8
+        ``QuantizedWeight`` dequantises into a fresh buffer anyway, and L
+        layers' worth of it a layer would be L times the work), and a
+        mesh whose ``expert`` axis shards the E of [L, E, ...], which the
+        merged [L * E] axis can not carry."""
+        names = self.experts.matmul_leaves
+        experts = stacked["experts"]
+        mesh = active_mesh()
+        if (mesh is not None and mesh.shape.get(EXPERT_AXIS, 1) > 1) or \
+                not all(isinstance(experts[n], jax.Array) for n in names):
+            return stacked, None
+        rest = {k: v for k, v in experts.items() if k not in names}
+        return {**stacked, "experts": rest}, {n: experts[n] for n in names}
+
+    def apply_routed(self, params, x, renormalize=None, stacked=None):
         """Routed, dropless serving path (the reference's MoE-inference
         semantics, reference ops/transformer/inference/moe_inference.py:160
         — route every token, drop nothing, no capacity): router matmul and
@@ -371,7 +394,9 @@ class MOELayer:
         activations' type. Costs the routed FLOPs and holds no [S, E, C]
         tensor. Same return shape as apply(): l_aux is 0 (no load-balance
         objective when serving); exp_counts [E] are the rows each expert
-        got."""
+        got. ``stacked``: ``(whole, layer)`` from ``take_whole``; the
+        experts' matmul leaves are then read from ``whole`` at ``layer``
+        and ``params`` holds the rest."""
         lead = x.shape[:-1]
         m = x.shape[-1]
         xs = x.reshape(-1, m)                                      # [S, M]
@@ -382,10 +407,14 @@ class MOELayer:
         flat = idx.reshape(-1)                                     # [S*k]
         order = jnp.argsort(flat, stable=True)   # pair ids, by expert
         exp_counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+        experts, layer = params["experts"], None
+        if stacked is not None:
+            whole, layer = stacked
+            experts = {**experts, **whole}
         with jax.named_scope("moe_experts"):
             expert_out = self.experts.apply_grouped(
-                params["experts"], xs[order // k], exp_counts,
-                flat[order])                                       # [S*k, M]
+                experts, xs[order // k], exp_counts, flat[order],
+                layer=layer)                                       # [S*k, M]
         # un-sort: pair (s, j) sits at row inverse[s*k + j]
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))

@@ -200,3 +200,82 @@ def test_slot_decode_writes_its_rows_in_place(one_chip):
     assert mem.temp_size_in_bytes < pool_nbytes(pool) // slots
     first = len(jax.tree.leaves(params))
     assert donated_params_from_hlo(compiled.as_text()) == {first, first + 1}
+
+
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_routed_decode_reads_expert_leaves_in_place(one_chip, program):
+    """The slot decode step and a slot-prefill bucket (128) of an OLMoE of
+    ``olmoe-1b-7b.serve-chat-2k``'s widths (hidden 2048, 64 experts of
+    1024, 8 a token; two layers, 24 slots x 2048, small vocabulary),
+    compiled for the chip: no instruction produces a layer's expert leaf
+    (bf16 [64, 2048, 1024] or [64, 1024, 2048], 268 MB), the temporaries
+    stay far under one, and the grouped matmuls are still the
+    ``ragged-dot`` custom calls the cell's ``moe_kernels.pattern`` finds.
+
+    How the layer loop may reach its expert leaves, 8 layers of three
+    ``lax.ragged_dot`` over 192 rows at these sizes, compiled the same way
+    (ISSUE 34):
+
+    | the loop                                         | temporaries | copies |
+    | ``lax.scan`` over the stacked [8, 64, ...] leaves | 268.6 MB    | ``dynamic-slice_bitcast_fusion`` x 3, each a whole leaf of the layer: 56% of the cell's busy time on the chip (ledger, PR 33) |
+    | unrolled, static slices ``w[l]`` of the stack    | 5.9 GB      | every slice, up front |
+    | the leaves whole, [L * E] groups, sizes at l * E | 0.59 MB     | none: the kernel's weight window is fetched by group id, and empty groups are never visited |
+
+    The third is ``MOELayer.take_whole`` with ``apply_grouped(...,
+    layer=l)``."""
+    import re
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.models.olmoe import OLMoEConfig, OLMoEModel
+    from deepspeed_tpu.parallel import initialize_mesh
+
+    slots, max_len, bucket = 24, 2048, 128
+    model = OLMoEModel(OLMoEConfig(
+        vocab_size=512, n_positions=max_len, n_embd=2048, n_layer=2,
+        n_head=16, mlp_hidden=1024, num_experts=64, top_k=8,
+        dtype="bfloat16"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # zeros on ONE host device: the program is built by one call on a
+    # one-slot pool; 1.6 GB of normal draws, a copy for each of the rig's
+    # eight devices, would buy nothing
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len}),
+        mesh_manager=initialize_mesh(dp=1, devices=jax.devices()[:1]))
+    leaf = engine.params["blocks"]["moe"]["experts"]["w_gate"]
+    assert leaf.shape == (2, 64, 2048, 1024) and leaf.dtype == jnp.bfloat16
+    tiny = engine.init_slot_pool(1, max_len)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    if program == "decode":
+        zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+        engine.slot_decode_step(tiny, zi, zi, zf)
+        fn = engine._slot_fns[("slot_decode", 1, max_len)]
+        args = (params, pool, vi, vi, vf, vi, vf, vi)
+    else:
+        engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
+        fn = engine._slot_fns[("slot_prefill", 1, max_len)]
+        args = (params, on_chip((1, bucket), jnp.int32), pool, i32, i32,
+                f32, i32, f32, i32)
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        *args).compile()
+    text = compiled.as_text()
+    # a prefill's own temporaries: its 2048-column mini cache (16.8 MB) and
+    # 128 rows of attention scores; read 1.9 and 52.7 MB
+    limit = (32 if program == "decode" else 64) * 2 ** 20
+    assert compiled.memory_analysis().temp_size_in_bytes < limit
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = bf16\[64,(?:2048,1024|1024,2048)\]\S* (\S+)\(",
+        text, re.M)
+    assert copied == [], copied
+    assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3

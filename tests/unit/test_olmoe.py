@@ -276,3 +276,167 @@ def test_training_keeps_the_capacity_path_and_its_aux_loss():
         train=True))(params)
     g = grads["blocks"]["moe"]["experts"]["w_down"]
     assert float(jnp.abs(g).sum()) > 0
+
+
+# --- the experts' stacked [L, E, ...] leaves read where they lie (PR 34) ---
+
+def _rows(case, e, rng):
+    """(group_sizes [E], rows) of three routings: spread, some experts
+    empty, every row on one expert."""
+    if case == "spread":
+        sizes = rng.integers(1, 5, e)
+    elif case == "empty experts":
+        sizes = rng.integers(1, 5, e) * (np.arange(e) % 3 == 1)
+    else:
+        sizes = np.zeros(e, np.int64)
+        sizes[e - 2] = 11
+    return jnp.asarray(sizes, jnp.int32), int(sizes.sum())
+
+
+@pytest.mark.parametrize("case", ["spread", "empty experts", "all on one"])
+@pytest.mark.parametrize("experts", [ExpertFFN, GatedExpertFFN])
+def test_stacked_leaves_at_a_layer_equal_that_layers_slice(experts, case):
+    """``apply_grouped(..., layer=l)`` on the [L, E, ...] matmul leaves
+    (biases this layer's own) equals the call on layer l's slices, every
+    l: the groups of the other layers are empty and shift no row."""
+    layers, e = 3, 8
+    ffn = experts(16, 32, e)
+    stacked = jax.vmap(ffn.init)(jax.random.split(jax.random.PRNGKey(7),
+                                                  layers))
+    for i, k in enumerate(("bi", "bo")):
+        if k in stacked:                # biases that a wrong gather shows
+            stacked[k] = jax.random.normal(jax.random.PRNGKey(8 + i),
+                                           stacked[k].shape) * 0.1
+    sizes, n = _rows(case, e, np.random.default_rng(3))
+    ids = jnp.repeat(jnp.arange(e), sizes, total_repeat_length=n)
+    x = jax.random.normal(jax.random.PRNGKey(9), (n, 16))
+    for l in range(layers):
+        sliced = jax.tree.map(lambda a: a[l], stacked)
+        want = ffn.apply_grouped(sliced, x, sizes, ids)
+        whole = {k: stacked[k] if k in ffn.matmul_leaves else v
+                 for k, v in sliced.items()}
+        got = jax.jit(lambda p, l: ffn.apply_grouped(p, x, sizes, ids,
+                                                     layer=l))(whole, l)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+    assert float(jnp.abs(want).max()) > 0
+
+
+@pytest.fixture
+def grouped_calls(monkeypatch):
+    """Every ``apply_grouped`` call made (traced) while the test runs:
+    True where it was handed stacked leaves and a layer."""
+    seen = []
+    for cls in (ExpertFFN, GatedExpertFFN):
+        def spy(self, params, x, sizes, ids=None, layer=None,
+                _real=cls.apply_grouped):
+            stacked = params[self.matmul_leaves[0]].ndim == 4
+            assert stacked == (layer is not None)
+            seen.append(stacked)
+            return _real(self, params, x, sizes, ids, layer=layer)
+        monkeypatch.setattr(cls, "apply_grouped", spy)
+    return seen
+
+
+def _gpt2_moe():
+    from deepspeed_tpu.models.gpt2_moe import GPT2MoEConfig, GPT2MoEModel
+    model = GPT2MoEModel(GPT2MoEConfig(
+        vocab_size=512, n_positions=128, n_embd=32, n_layer=3, n_head=4,
+        num_experts=4, top_k=2, pad_vocab_to_multiple=64))
+    w = model.init(jax.random.PRNGKey(0))
+    for i, k in enumerate(("bi", "bo")):
+        w["blocks"]["moe"]["experts"][k] = jax.random.normal(
+            jax.random.PRNGKey(20 + i),
+            w["blocks"]["moe"]["experts"][k].shape) * 0.1
+    return model, w
+
+
+def _run(model, w, program):
+    """(logits, routing stats or None) of one program of the model."""
+    if program == "forward":            # what ``engine.forward`` runs
+        return model.logits(w, jnp.asarray(IDS), train=False), None
+    cache = model.init_kv_cache(2, 64, dtype=jnp.float32)
+    out, cache, stats = model.apply_with_cache(
+        w, jnp.asarray(IDS[:, :40]), cache, 0, routing=True)
+    if program == "decode":
+        out, cache, stats = model.decode_with_slots(
+            w, jnp.asarray(IDS[:, 40:41]), cache, jnp.array([40, 40]),
+            routing=True)
+    return out, stats
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode", "forward"])
+@pytest.mark.parametrize("family", ["olmoe", "gpt2_moe"])
+def test_whole_leaves_give_the_logits_and_counts_the_slices_gave(
+        family, program, grouped_calls, monkeypatch):
+    """Both cache forwards and the uncached serving forward: the scan
+    closes over the experts' matmul leaves whole, and logits and routing
+    counts are those of the scan that sliced them (``take_whole`` made to
+    keep the slice: the parent's program). GPT-2 MoE without a cache
+    evaluates through the capacity dispatch, which no grouped matmul
+    serves: untouched."""
+    model, w = (tiny(), seeded()) if family == "olmoe" else _gpt2_moe()
+    got, got_stats = _run(model, w, program)
+    whole_calls = list(grouped_calls)
+    del grouped_calls[:]
+    monkeypatch.setattr(MOELayer, "take_whole", lambda self, p: (p, None))
+    want, want_stats = _run(model, w, program)
+    if family == "gpt2_moe" and program == "forward":
+        assert whole_calls == [] and grouped_calls == []
+    else:
+        assert whole_calls and all(whole_calls)
+        assert grouped_calls and not any(grouped_calls)
+    assert rel_rms(got, want) < 1e-6
+    if program != "forward":
+        np.testing.assert_array_equal(np.asarray(got_stats),
+                                      np.asarray(want_stats))
+
+
+@pytest.mark.parametrize("keeps", ["int8", "expert 2"])
+def test_the_slice_stays_for_int8_leaves_and_a_sharded_expert_axis(
+        keeps, grouped_calls):
+    """What ``take_whole`` sees decides, no option: a ``QuantizedWeight``
+    leaf and a mesh whose ``expert`` axis is 2 keep the per-layer slice,
+    and agree with the whole-leaf program on the same numbers (the int8
+    engine's weights dequantised; the same weights on an ``expert`` 1
+    mesh)."""
+    import deepspeed_tpu
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.quantization import is_quantized
+    from deepspeed_tpu.parallel import initialize_mesh
+    fp = {"dtype": "float32", "max_tokens": 64}
+    if keeps == "int8":
+        kept = deepspeed_tpu.init_inference(
+            tiny(), config={**fp, "quant": {"enabled": True}})
+        assert is_quantized(
+            kept.params["blocks"]["moe"]["experts"]["w_down"])
+        w = jax.tree.map(
+            lambda a: a.astype(jnp.float32) if is_quantized(a) else a,
+            kept.params, is_leaf=is_quantized)
+    else:
+        w = seeded()
+        kept = InferenceEngine(
+            tiny(), DeepSpeedInferenceConfig.from_dict(fp), params=w,
+            mesh_manager=initialize_mesh(dp=4, ep=2))
+        spec = kept.params["blocks"]["moe"]["experts"]["w_down"].sharding.spec
+        assert "expert" in tuple(spec), spec
+
+    def drive(engine):
+        logits = np.asarray(engine.forward(IDS[:, :16]))
+        pool = engine.init_slot_pool(2, 64)
+        pool, tok = engine.slot_prefill(pool, 0, IDS[0, :19])
+        pool, nxt = engine.slot_decode_step(
+            pool, np.array([tok, 0], np.int32), np.array([19, 0], np.int32),
+            np.zeros(2, np.float32))
+        return logits, tok, int(nxt[0]), engine.take_routing()
+
+    got = drive(kept)
+    assert grouped_calls and not any(grouped_calls)
+    del grouped_calls[:]
+    whole = InferenceEngine(tiny(), DeepSpeedInferenceConfig.from_dict(fp),
+                            params=w)
+    want = drive(whole)
+    assert grouped_calls and all(grouped_calls)
+    assert rel_rms(got[0], want[0]) < F32_TOL
+    assert got[1:] == want[1:]
