@@ -197,6 +197,32 @@ def phase_kernels(args, sz):
           3, h)
 
 
+    # the experts' grouped matmul at a row count that is no multiple of 8:
+    # four picks of one token, float32. lax.ragged_dot alone reads about 1
+    # off there on the chip (logged, not asserted: it is the compiler's);
+    # apply_grouped pads the rows and must be right (moe/experts.py)
+    from jax import lax
+    from deepspeed_tpu.moe.experts import GatedExpertFFN
+    e, m, f = sz["grouped"]
+    ffn = GatedExpertFFN(m, f, e)
+    with jax.default_matmul_precision("highest"):
+        w = ffn.init(jax.random.fold_in(key, 4))
+        x = jax.random.normal(jax.random.fold_in(key, 5), (4, m), jnp.float32)
+        ids = jnp.asarray([1, 1, e // 2, e - 1])
+        counts = jnp.bincount(ids, length=e).astype(jnp.int32)
+        want = jnp.einsum(
+            "nf,nfm->nm", jax.nn.silu(
+                jnp.einsum("nm,nmf->nf", x, w["w_gate"][ids])) *
+            jnp.einsum("nm,nmf->nf", x, w["w_up"][ids]), w["w_down"][ids])
+        got = jax.jit(ffn.apply_grouped)(w, x, counts)
+        raw = jax.jit(lax.ragged_dot)(x, w["w_gate"], counts)
+        raw_want = jnp.einsum("nm,nmf->nf", x, w["w_gate"][ids])
+    raw_err = float(jnp.abs(raw - raw_want).max() / jnp.abs(raw_want).max())
+    log("kernels", f"grouped matmul, 4 float32 rows over {e} groups of "
+        f"[{m}, {f}] ok  rel_err {_close('grouped', got, want):.2e}  "
+        f"(lax.ragged_dot alone on the same rows: {raw_err:.2e})")
+
+
 # -------------------------------------------------------------------- train
 
 def _fixed_batch(seed, gas, rows, seq, vocab):
@@ -529,7 +555,7 @@ def sizes(rehearse):
     if not rehearse:
         return dict(
             attn=(8, 16, 1024, 64), attn_boundary=(1, 12, 8192, 64),
-            sparse_block=64, sparse_batch=2,
+            sparse_block=64, sparse_batch=2, grouped=(64, 2048, 1536),
             train_model=GPT2_350M, train_seq=1024, train_micro=8,
             train_steps=4,
             serve_model=GPT2_1_3B, serve_max_len=1024, serve_new=32,
@@ -539,7 +565,7 @@ def sizes(rehearse):
                       pad_vocab_to_multiple=128)
     return dict(
         attn=(1, 2, 256, 64), attn_boundary=(1, 2, 256, 64),
-        sparse_block=64, sparse_batch=1,
+        sparse_block=64, sparse_batch=1, grouped=(8, 64, 32),
         train_model=tiny, train_seq=128, train_micro=2,
         train_steps=4,
         serve_model=dataclasses.replace(tiny, n_layer=5),

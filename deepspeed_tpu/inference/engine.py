@@ -117,6 +117,7 @@ class InferenceEngine:
             self.param_shardings = quantized_shardings(self._fp_shardings,
                                                        param_shapes)
         self._recast_fn = None
+        self._leaf_fns = {}      # quantize_resident's programs, by leaf
         #: the checkpoint weights_version these params came from (0 =
         #: unversioned: fresh init or a pre-rollout checkpoint); the
         #: rollout plane compares it across replicas and KV handoffs
@@ -125,10 +126,9 @@ class InferenceEngine:
             if params is not None:
                 self.params = self.recast(params)
             else:
-                self.params = jax.jit(
-                    lambda r: self._finalize_tree(
-                        jax.tree.map(self._cast_leaf, model.init(r))),
-                    out_shardings=self.param_shardings)(rng)
+                self.params = self._quantize_resident(jax.jit(
+                    lambda r: jax.tree.map(self._cast_leaf, model.init(r)),
+                    out_shardings=self._fp_shardings)(rng))
         if config.checkpoint:
             self.load_checkpoint(config.checkpoint)
         if self._quant is not None:
@@ -155,6 +155,9 @@ class InferenceEngine:
         # slot_decode_step read its routing stats back with the tokens
         self._routed = bool(getattr(model, "routed_experts", False))
         self._routing = None
+        # a model whose pool holds a recurrent state beside K and V: the
+        # prefills tell it each prompt's real length
+        self._recurrent = bool(getattr(model, "recurrent_state", ()))
         n_params = sum(int(np.prod(s.shape))
                        for s in jax.tree.leaves(param_shapes))
         log_dist(f"InferenceEngine initialized: params={n_params/1e6:.1f}M "
@@ -167,28 +170,34 @@ class InferenceEngine:
             return x.astype(self.dtype)
         return x
 
-    def _finalize_tree(self, params):
-        """Apply weight-only quantization when configured (jit-safe)."""
+    def _quantize_resident(self, params, cast=None):
+        """Parameters as they are served: themselves, or int8 where
+        weight-only quantization is on, a leaf at a time
+        (``quantize_resident``). ``cast=None``: fp parameters of the
+        engine's own, on the device in the serving layout, consumed."""
         if self._quant is None:
             return params
-        from .quantization import quantize_tree
-        return quantize_tree(params, self._quant.group_size,
-                             self._quant.bits)
+        from .quantization import quantize_resident
+        return quantize_resident(params, self.param_shardings,
+                                 self._quant.group_size, self._quant.bits,
+                                 cast=cast, programs=self._leaf_fns)
 
     def recast(self, params):
         """Cast/re-shard a params tree into the serving layout (quantizing
-        when int8 serving is on) — compiled per input structure; the hybrid
-        engine refreshes fp training params through this after every
-        optimizer step."""
+        when int8 serving is on, through the one leaf-at-a-time path) —
+        compiled per input structure; the hybrid engine refreshes fp
+        training params through this after every optimizer step. The
+        caller's tree is left as it is."""
         from .quantization import is_quantized
-        if self._recast_fn is None:
-            def rc(p):
-                p = jax.tree.map(
-                    lambda x: x if is_quantized(x) else self._cast_leaf(x),
-                    p, is_leaf=is_quantized)
-                return self._finalize_tree(p)
-            self._recast_fn = jax.jit(rc, out_shardings=self.param_shardings)
         with self.mesh:
+            if self._quant is not None:
+                return self._quantize_resident(params, cast=self._cast_leaf)
+            if self._recast_fn is None:
+                self._recast_fn = jax.jit(
+                    lambda p: jax.tree.map(
+                        lambda x: x if is_quantized(x)
+                        else self._cast_leaf(x), p, is_leaf=is_quantized),
+                    out_shardings=self.param_shardings)
             return self._recast_fn(params)
 
     def _batch_sharding(self, batch_size: int):
@@ -273,10 +282,7 @@ class InferenceEngine:
             params = load_params_for_inference(
                 load_dir, tag=tag, like=self._fp_template,
                 shardings=self._fp_shardings, cast=self._cast_leaf)
-            if self._quant is not None:
-                params = jax.jit(self._finalize_tree,
-                                 out_shardings=self.param_shardings)(params)
-        return params
+            return self._quantize_resident(params)
 
     def with_params(self, params, weights_version=None):
         """A shallow engine view sharing this engine's module, mesh,
@@ -651,7 +657,9 @@ class InferenceEngine:
         program that returns a pool consumes the one it was given
         (``_pool_program`` donates it): a pool that was handed over already
         is refused here, by name, before XLA refuses its buffers."""
-        leaf = jax.tree.leaves(pool)[0]
+        # a KV leaf, by name: a pool may hold leaves of another shape beside
+        # K and V (a recurrent state has no max_len), and they sort first
+        leaf = (pool.q if is_quantized_pool(pool) else pool)["k"]
         if leaf.is_deleted():
             raise RuntimeError(
                 "this KV pool was consumed by an earlier slot_* call (or by "
@@ -806,7 +814,9 @@ class InferenceEngine:
         """Prefill ``prompt`` (1-D int array) into ``pool`` slot ``slot`` and
         sample the first generated token. The prompt is right-padded to a
         pow2 bucket (one compile per bucket; pad K/V beyond the prompt is
-        masked until overwritten by decode writes). Sampling is
+        masked until overwritten by decode writes, and a model with a
+        recurrent state is told the real length and stores its state at
+        the prompt's last token: ``_real_length``). Sampling is
         deterministic per ``(seed, position)`` — replay-safe. Returns
         (new_pool, first_token:int)."""
         model = self.module
@@ -824,7 +834,8 @@ class InferenceEngine:
                top_p, seed):
             mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
             logits, mini, *stats = model.apply_with_cache(
-                params, ids, mini, jnp.int32(0), routing=self._routed)
+                params, ids, mini, jnp.int32(0), routing=self._routed,
+                **self._real_length(last_idx))
             pool = write_lane(pool, mini, slot)
             last = jnp.take(logits[0], last_idx, axis=0)
             # the first token is FED at column last_idx + 1
@@ -841,6 +852,25 @@ class InferenceEngine:
         with self._tracer.phase("serve/prefill_wait"):
             tok = self._read_back(np.asarray(tok).reshape(-1), 1)
         return pool, int(tok[0])
+
+    def _real_length(self, last_idx):
+        """What a prefill's body hands the cached forward beside its
+        right-padded bucket: nothing for a model whose pool is K and V
+        (padding columns are masked until overwritten, and its programs
+        stay the ones they were), the row's real length for one with a
+        recurrent state, which must be stored at the last real token."""
+        return {"lengths": (last_idx + 1)[None]} if self._recurrent else {}
+
+    def _lane_from(self, pool, slot, start_pos):
+        """Slot ``slot``'s lane as the mini cache a suffix or chunk prefill
+        goes on from at column ``start_pos``. K and V below it are the
+        lane's; so is a recurrent state, except at column 0, where nothing
+        came before: there it is zero, whatever the slot's last occupant
+        or the dummy rows of the decode ticks since have left in it."""
+        mini = read_lane(pool, slot, self.dtype)
+        for name in getattr(self.module, "recurrent_state", ()):
+            mini[name] = jnp.where(start_pos == 0, 0, mini[name])
+        return mini
 
     def _read_back(self, out, n):
         """What a slot program read back: the first ``n`` entries are its
@@ -896,7 +926,11 @@ class InferenceEngine:
         compile per bucket, shared with every start_pos — the offset is a
         traced scalar); callers size the bucket via
         ``prefix_cache.reuse_plan`` so ``start_pos + bucket <= max_len``.
-        Returns (new_pool, next_token:int)."""
+        A recurrent state goes on from what the lane holds (from nothing
+        at ``start_pos`` 0: ``_lane_from``), so for such a model the lane
+        must have been prefilled up to exactly ``start_pos`` and no decode
+        step may have run over the pool since: a step pushes a row into
+        EVERY slot's state. Returns (new_pool, next_token:int)."""
         model = self.module
         vocab = model.config.vocab_size
         tokens = np.asarray(tokens, dtype=np.int32).reshape(-1)
@@ -916,9 +950,9 @@ class InferenceEngine:
         @self._pool_program("slot_suffix", (bucket, max_len), shape, outs=1)
         def spf(params, ids, pool, slot, start_pos, last_idx, temperature,
                 top_k, top_p, seed):
-            mini = read_lane(pool, slot, self.dtype)
-            logits, mini = model.apply_with_cache(params, ids, mini,
-                                                  start_pos)
+            mini = self._lane_from(pool, slot, start_pos)
+            logits, mini = model.apply_with_cache(
+                params, ids, mini, start_pos, **self._real_length(last_idx))
             pool = write_lane(pool, mini, slot)
             last = jnp.take(logits[0], last_idx, axis=0)
             tok = _sample_one(last, temperature, top_k, top_p, seed,
@@ -963,11 +997,18 @@ class InferenceEngine:
             raise ValueError(
                 f"chunk bucket [{start_pos}, {start_pos + bucket}) exceeds "
                 f"max_len={max_len}; size chunks so every bucket fits")
+        if self._recurrent and t != bucket:
+            raise ValueError(
+                f"a chunk of {t} tokens would be padded to {bucket}: a model "
+                f"with a recurrent state takes whole pow2 chunks (the state "
+                f"is stored at the chunk's last token; a prompt's tail goes "
+                f"through slot_suffix_prefill), one after another with no "
+                f"decode step between them (``slot_suffix_prefill``)")
 
         @self._pool_program("slot_chunk", (num_slots, bucket, max_len),
                             shape)
         def cpf(params, ids, pool, slot, start_pos):
-            mini = read_lane(pool, slot, self.dtype)
+            mini = self._lane_from(pool, slot, start_pos)
             mini = model.chunk_prefill_with_cache(params, ids, mini,
                                                   start_pos)
             return write_lane(pool, mini, slot)

@@ -124,6 +124,54 @@ def quantize_tree(params, group_size: int = 64, bits: int = 8,
         params, is_leaf=lambda x: is_quantized(x))
 
 
+def quantize_resident(params, shardings, group_size: int = 64, bits: int = 8,
+                      predicate=_default_predicate, cast=None, programs=None):
+    """``quantize_tree`` into the serving layout a leaf at a time, each
+    selected leaf through a program of its own, so that the fp tree and
+    the int8 tree are never both whole on the device. One program over
+    the tree holds both, and a program that also MAKES the tree holds
+    every large leaf in float32 across its two passes (absmax, then
+    divide): 18 GB for a 10 GB model on a 16 GB chip; a stacked leaf goes
+    through a slice of its leading (layer) axis at a time for the same
+    reason (a 3.2 GB leaf of experts whole asked for 10.5 GB of
+    temporaries; PERF.md, PR 36). Groups lie along the last axis, so the
+    payload is ``quantize_tree``'s.
+
+    ``cast=None``: ``params`` are the CALLER'S TO GIVE, on the device in
+    the serving layout already (an engine's own init, a loaded
+    checkpoint): each selected leaf is deleted once its program is
+    dispatched (its bytes cannot be aliased to an int8 payload, so
+    donating it frees nothing sooner) and the peak is the tree plus one
+    payload; the other leaves are returned as they are. ``cast`` given
+    (``recast``): ``params`` are anyone's, anywhere (a trainer's, the
+    host's); every leaf goes through ``cast`` into its sharding, the
+    selected ones on into int8 in the same program, and nothing of the
+    caller's is touched. ``shardings``: ``quantized_shardings`` of the
+    tree. ``programs``: a dict the caller keeps, so that a second call
+    (a refresh every optimizer step) compiles nothing."""
+    programs = {} if programs is None else programs
+    consume = cast is None
+
+    def one(kp, x, sh):
+        selected = not is_quantized(x) and predicate(kp, x)
+        if is_quantized(x) or (consume and not selected):
+            return x
+        key = (jax.tree_util.keystr(kp), consume)
+        if key not in programs:
+            def leaf(w):
+                w = w if consume else cast(w)
+                return quantize_leaf(w, group_size, bits) if selected else w
+            programs[key] = jax.jit(
+                (lambda w: jax.lax.map(leaf, w))
+                if selected and x.ndim > 2 else leaf, out_shardings=sh)
+        out = programs[key](x)
+        if consume:
+            x.delete()
+        return out
+    return jax.tree_util.tree_map_with_path(one, params, shardings,
+                                            is_leaf=is_quantized)
+
+
 def quantized_shardings(param_shardings, param_shapes,
                         predicate=_default_predicate):
     """Sharding tree matching ``quantize_tree``'s output structure: q keeps

@@ -175,6 +175,14 @@ class GPT2Model(ModelSpec):
     # re-implementing hidden_states / apply_with_cache / pipeline_spec.
     has_position_table = True   # families without a wpe table set False
     causal_attention = True     # bidirectional towers (CLIP vision) set False
+    #: leaves of ``init_kv_cache`` that hold a RECURRENT state: what a layer
+    #: keeps of a lane is the state after its last token, ``[L', S, n, d]``,
+    #: not a row per token (``_state_shift``). A family that names one must
+    #: be told each row's real length by whoever pads it
+    #: (``_forward_with_cache``'s ``lengths``), and what is valid "up to any
+    #: column" of a KV lane (a shared prefix, a rolled-back draft) is not
+    #: valid of it: ``ServingEngine`` fences those mechanisms
+    recurrent_state = ()
 
     def _compute_dtype(self, params):
         return _params_compute_dtype(params, self.config.dtype)
@@ -359,6 +367,26 @@ class GPT2Model(ModelSpec):
         ``hidden_states``' with ``train=False``). Dense: nothing whole."""
         return blocks, None
 
+    def _scan_layers(self, body, carry, blocks, indexed=False, unroll=1):
+        """Run ``body(carry, xs) -> (carry, out)`` over every layer in
+        order and return ``(carry, outs)``: for a family whose layers are
+        alike, the one ``lax.scan`` over its stacked ``blocks``. ``xs`` is
+        what the two scans of this class hand their bodies:
+        ``layer_params`` (with ``_layer_extras``: ``(layer_params,
+        extra)``) from ``hidden_states``, and with ``indexed`` (the cache
+        forward) ``(layer_params, layer, extra)``, where ``layer`` is the
+        layer's index in the pool's leaves. A family whose layers are of
+        several kinds, each kind with its own stacked tree and its own
+        pool leaves, overrides this (``models/lfm2.py``): ``layer_params``
+        is then whatever its own ``_block`` / ``_decode_block`` take, and
+        ``outs`` the stacked outputs of the layers that gave one."""
+        extras = self._layer_extras()
+        if indexed:
+            xs = (blocks, jnp.arange(self.config.n_layer), extras)
+        else:
+            xs = blocks if extras is None else (blocks, extras)
+        return lax.scan(body, carry, xs, unroll=unroll)
+
     def _train_attn_bias_ex(self, t, extra):
         """Layer-aware training attention bias; base defers to the
         layer-independent hook."""
@@ -450,9 +478,8 @@ class GPT2Model(ModelSpec):
             from ..runtime.activation_checkpointing.checkpointing import \
                 get_policy
             body_fn = jax.checkpoint(body, policy=get_policy(cfg.remat_policy))
-        xs = blocks if extras is None else (blocks, extras)
-        (x, _, aux_total), _ = lax.scan(
-            body_fn, (x, 0, jnp.float32(0.0)), xs,
+        (x, _, aux_total), _ = self._scan_layers(
+            body_fn, (x, 0, jnp.float32(0.0)), blocks,
             unroll=min(max(1, int(getattr(cfg, "scan_unroll", 1))),
                        cfg.n_layer))
 
@@ -727,18 +754,44 @@ class GPT2Model(ModelSpec):
         None). ALiBi families override."""
         return None
 
+    @staticmethod
+    def _state_shift(state, layer, rows, lengths=None):
+        """Push ``rows`` [S, T, d] through layer ``layer`` of a recurrent
+        pool leaf ``state`` [L', S, n, d], which keeps the last ``n`` rows a
+        slot has seen: returns ``(history, leaf)``, the ``n`` rows before
+        the block as they stood, and the leaf with layer ``layer`` holding
+        the last ``n`` rows up to and including row ``lengths[s] - 1`` of
+        the block (``None``: every row is real). What a right-padded
+        prefill feeds after its last real token never enters the state,
+        and cannot be masked out afterwards as a KV column can. Nothing but
+        layer ``layer``'s S * n rows is written, so a donated pool carried
+        through the layers is updated in place."""
+        hist = lax.dynamic_index_in_dim(state, layer, 0, keepdims=False)
+        n, t = hist.shape[1], rows.shape[1]
+        seen = jnp.concatenate([hist, rows.astype(hist.dtype)], axis=1)
+        if lengths is None:
+            last = seen[:, t:]
+        else:
+            last = jax.vmap(lambda row, at: lax.dynamic_slice_in_dim(
+                row, at, n, axis=0))(seen, lengths)
+        return hist, lax.dynamic_update_slice(state, last[None],
+                                              (layer, 0, 0, 0))
+
     def _forward_with_cache(self, params, input_ids, cache, start,
-                            pad_counts=None, routing=False):
+                            pad_counts=None, routing=False, lengths=None):
         """The one cached forward behind ``apply_with_cache`` (``start`` a
         scalar), ``decode_with_slots`` and ``verify_with_slots`` (``start``
         [S], one cache position per row): input_ids [S, T]; row s's token j
         is written at column ``start + j`` and attends the columns the
         model's mask keeps at or below it. The pool is carried through the
-        layer scan (not scanned over), each layer writes its S * T rows
-        (``_kv_write``) and reads its slab where it lies (``_kv_attend``):
-        with the pool donated to the jitted program XLA aliases it through
-        the loop, and a step writes the new tokens' K and V and nothing
-        else."""
+        layers (``_scan_layers``; not scanned over), each layer writes its
+        S * T rows (``_kv_write``) and reads its slab where it lies
+        (``_kv_attend``): with the pool donated to the jitted program XLA
+        aliases it through the loop, and a step writes the new tokens' K
+        and V and nothing else. A family with ``recurrent_state`` leaves
+        is handed ``state_fn(name, rows) -> history`` beside ``attn_fn``
+        (``_state_shift`` on the layer's own index), and ``lengths`` [S]
+        says how many of each row's T tokens are real (``None``: all)."""
         s, t = input_ids.shape
         max_len = cache["k"].shape[2]
         compute_dtype = self._compute_dtype(params)
@@ -770,11 +823,11 @@ class GPT2Model(ModelSpec):
         bias = self._decode_attn_bias(q_pos, k_pos)
 
         def body(carry, xs):
-            x, k_pool, v_pool = carry
+            x, pool = carry
             layer_params, layer, extra = xs
             mask = base_mask if extras is None else keep_mask(extra)
             routed = {} if whole is None else {"stacked": (whole, layer)}
-            new_kv = {}
+            pool = dict(pool)
 
             def cached_attn(q, k, v):
                 # q, k, v arrive [S, H, T, hd]. kv_write / kv_read scopes
@@ -782,32 +835,37 @@ class GPT2Model(ModelSpec):
                 # plane's bucket classifier, so cache traffic is
                 # attributed as bytes, not attention math
                 with jax.named_scope("kv_write"):
-                    kp = self._kv_write(k_pool, layer,
-                                        k.transpose(0, 2, 1, 3), start)
-                    vp = self._kv_write(v_pool, layer,
-                                        v.transpose(0, 2, 1, 3), start)
-                new_kv["k"], new_kv["v"] = kp, vp
+                    pool["k"] = self._kv_write(pool["k"], layer,
+                                               k.transpose(0, 2, 1, 3), start)
+                    pool["v"] = self._kv_write(pool["v"], layer,
+                                               v.transpose(0, 2, 1, 3), start)
                 with jax.named_scope("kv_read"):
-                    return self._kv_attend(q, kp, vp, layer, mask, bias)
+                    return self._kv_attend(q, pool["k"], pool["v"], layer,
+                                           mask, bias)
+
+            if self.recurrent_state:
+                def cached_state(name, rows):
+                    hist, pool[name] = self._state_shift(pool[name], layer,
+                                                         rows, lengths)
+                    return hist
+                routed["state_fn"] = cached_state
 
             x, stats = self._split_routing(self._decode_block(
                 x, layer_params, cached_attn, start_pos,
                 positions=positions, extra=extra, **routed))
-            return (x, new_kv["k"], new_kv["v"]), stats
+            return (x, pool), stats
 
-        xs = (blocks, jnp.arange(self.config.n_layer), extras)
-        (x, new_k, new_v), stats = lax.scan(
-            body, (x, cache["k"], cache["v"]), xs)
+        (x, pool), stats = self._scan_layers(body, (x, dict(cache)), blocks,
+                                             indexed=True)
         x = self._final_norm(params, x)
         logits = x @ self._unembed_weight(params, compute_dtype).T
         head_b = self._head_bias(params, logits.dtype)
         if head_b is not None:
             logits = logits + head_b
-        return self._cache_return(logits, {"k": new_k, "v": new_v}, stats,
-                                  routing)
+        return self._cache_return(logits, pool, stats, routing)
 
     def apply_with_cache(self, params, input_ids, cache, start_pos,
-                         pad_counts=None, routing=False):
+                         pad_counts=None, routing=False, lengths=None):
         """Forward with KV cache. input_ids: [B, T] (prompt for prefill,
         [B, 1] for decode); start_pos: traced scalar — tokens occupy cache
         columns [start_pos, start_pos+T), one contiguous block a row.
@@ -816,10 +874,15 @@ class GPT2Model(ModelSpec):
         masked out and logical positions shift down by pad_counts[b] (ALiBi
         needs no shift: a per-row constant is softmax-invariant). Returns
         (logits [B,T,V], new_cache); with ``routing=True`` also the routed
-        expert layers' stats (``_cache_return``)."""
+        expert layers' stats (``_cache_return``). ``lengths`` [B]: how many
+        of each row's T tokens are real, the rest RIGHT padding (a slot
+        prefill's pow2 bucket). A KV column of padding is masked until it
+        is overwritten and the argument changes nothing there; a family
+        with ``recurrent_state`` stores its state at the last real token,
+        and is always told."""
         return self._forward_with_cache(params, input_ids, cache, start_pos,
                                         pad_counts=pad_counts,
-                                        routing=routing)
+                                        routing=routing, lengths=lengths)
 
     def chunk_prefill_with_cache(self, params, input_ids, cache, start_pos):
         """K/V-write-only forward for chunked prefill: one chunk of a

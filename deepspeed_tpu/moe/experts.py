@@ -50,6 +50,25 @@ def _group_sizes(group_sizes, w, layer):
         jnp.zeros((l * e,), group_sizes.dtype), group_sizes, (layer * e,))
 
 
+def _whole_tiles(x, sizes, expert_ids=None):
+    """``x`` [N, M] with zero rows appended up to a multiple of 8, counted
+    to the last group (their outputs are cut off again; a zero row reads a
+    weight block and adds nothing). On the TPU ``lax.ragged_dot`` over a
+    float32 row count that is NOT a multiple of 8 returns garbage: 1-7 and
+    12 rows read 0.86-1.0 off a gather and einsum, 8, 16 and 32 read
+    2.5e-7, at 64 and at 128 groups of [2048, 1536] (bf16 rows 1-7 are
+    right; chip run, PERF.md, PR 36). Four picks a token of a one-slot
+    pool are such a count. A count that is a multiple already (every
+    serving cell's) is left as it is, and so is its program."""
+    pad = -x.shape[0] % 8
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+        sizes = sizes.at[-1].add(pad)
+        if expert_ids is not None:
+            expert_ids = jnp.pad(expert_ids, (0, pad))
+    return x, sizes, expert_ids
+
+
 class ExpertFFN:
     """Stacked per-expert 2-layer MLP: [E, M] → [E, F] → [E, M]."""
 
@@ -89,12 +108,14 @@ class ExpertFFN:
         → [N, M]. ``layer``: ``wi`` and ``wo`` are the stacked [L, E, ...]
         leaves and this is layer ``layer`` of them (the biases stay this
         layer's own [E, ...])."""
-        dt = x.dtype
-        sizes = _group_sizes(group_sizes, params["wi"], layer)
+        dt, n = x.dtype, x.shape[0]
+        x, sizes, expert_ids = _whole_tiles(
+            x, _group_sizes(group_sizes, params["wi"], layer), expert_ids)
         h = lax.ragged_dot(x, _groups(params["wi"], layer, dt), sizes)
         h = self.activation(h + params["bi"].astype(dt)[expert_ids])
         y = lax.ragged_dot(h, _groups(params["wo"], layer, dt), sizes)
-        return y + params["bo"].astype(dt)[expert_ids]
+        y = y + params["bo"].astype(dt)[expert_ids]
+        return y if y.shape[0] == n else y[:n]
 
 
 class GatedExpertFFN:
@@ -135,9 +156,11 @@ class GatedExpertFFN:
         """x: [N, M] rows sorted by expert, ``group_sizes`` [E] → [N, M].
         ``layer``: the three leaves are the stacked [L, E, ...] leaves and
         this is layer ``layer`` of them."""
-        dt = x.dtype
-        sizes = _group_sizes(group_sizes, params["w_gate"], layer)
+        dt, n = x.dtype, x.shape[0]
+        x, sizes, _ = _whole_tiles(
+            x, _group_sizes(group_sizes, params["w_gate"], layer))
         g = lax.ragged_dot(x, _groups(params["w_gate"], layer, dt), sizes)
         u = lax.ragged_dot(x, _groups(params["w_up"], layer, dt), sizes)
-        return lax.ragged_dot(jax.nn.silu(g) * u,
-                              _groups(params["w_down"], layer, dt), sizes)
+        y = lax.ragged_dot(jax.nn.silu(g) * u,
+                           _groups(params["w_down"], layer, dt), sizes)
+        return y if y.shape[0] == n else y[:n]

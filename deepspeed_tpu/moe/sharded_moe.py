@@ -254,22 +254,41 @@ def topk_gating(logits: jnp.ndarray,
 
 
 def topk_route(logits: jnp.ndarray, k: int,
-               renormalize: Optional[bool] = None) -> Tuple[jnp.ndarray,
+               renormalize: Optional[bool] = None, score: str = "softmax",
+               select_bias: Optional[jnp.ndarray] = None,
+               renorm_eps: Optional[float] = None) -> Tuple[jnp.ndarray,
                                                             jnp.ndarray]:
     """Capacity-free top-k routing: every token keeps all its picks.
     ``logits`` [S, E] → (weights [S, k] f32, experts [S, k] i32, best
-    first). The weights are the softmax (float32, over all E) at the picked
+    first). The weights are the scores (float32, over all E: ``score``
+    ``"softmax"``, or ``"sigmoid"``, each expert's own) at the picked
     experts; ``renormalize`` divides them by their sum over the k picks.
     ``None`` is the gate semantics of ``topk_gating`` (DeepSpeed's: raw
     probability for k = 1, renormalized for k >= 2); OLMoE
-    (``norm_topk_prob`` false) passes ``False``."""
-    gates = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, idx = lax.top_k(gates, k)
+    (``norm_topk_prob`` false) passes ``False``. ``select_bias`` [E] enters
+    the CHOICE and not the weight (the experts are the top k of score +
+    bias, weighted by the score alone: the load-balancing bias of
+    DeepSeek-V3's router, LFM2's ``use_expert_bias``); ``renorm_eps`` is
+    added to the sum the picks are divided by (``None``: the sum is held
+    over float32's epsilon instead)."""
+    logits = logits.astype(jnp.float32)
+    if score == "softmax":
+        gates = jax.nn.softmax(logits, axis=-1)
+    elif score == "sigmoid":
+        gates = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if select_bias is None:
+        w, idx = lax.top_k(gates, k)
+    else:
+        _, idx = lax.top_k(gates + select_bias.astype(jnp.float32), k)
+        w = jnp.take_along_axis(gates, idx, axis=-1)
     if renormalize is None:
         renormalize = k > 1
     if renormalize:
-        w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True),
-                            jnp.finfo(jnp.float32).eps)
+        total = jnp.sum(w, axis=-1, keepdims=True)
+        w = w / (jnp.maximum(total, jnp.finfo(jnp.float32).eps)
+                 if renorm_eps is None else total + renorm_eps)
     return w, idx.astype(jnp.int32)
 
 
@@ -284,7 +303,11 @@ class TopKGate:
                  min_capacity: int = 4,
                  noisy_gate_policy: Optional[str] = None,
                  drop_tokens: bool = True,
-                 use_rts: bool = True):
+                 use_rts: bool = True,
+                 score: str = "softmax",
+                 select_bias: bool = False,
+                 renorm_eps: Optional[float] = None,
+                 scale: float = 1.0):
         assert k >= 1
         self.model_dim = model_dim
         self.num_experts = num_experts
@@ -295,11 +318,23 @@ class TopKGate:
         self.noisy_gate_policy = noisy_gate_policy
         self.drop_tokens = drop_tokens
         self.use_rts = use_rts
+        # the routed serving path's (``MOELayer.apply_routed`` →
+        # ``topk_route``): the score function, a ``bias`` [E] leaf that
+        # enters the choice alone, the epsilon of the renormalisation and a
+        # factor on the weights. The capacity-based ``apply`` is softmax.
+        self.score = score
+        self.select_bias = select_bias
+        self.renorm_eps = renorm_eps
+        self.scale = scale
 
     def init(self, rng):
         scale = 1.0 / math.sqrt(self.model_dim)
-        return {"wg": jax.random.uniform(rng, (self.model_dim, self.num_experts),
-                                         jnp.float32, -scale, scale)}
+        params = {"wg": jax.random.uniform(
+            rng, (self.model_dim, self.num_experts), jnp.float32,
+            -scale, scale)}
+        if self.select_bias:
+            params["bias"] = jnp.zeros((self.num_experts,), jnp.float32)
+        return params
 
     def apply(self, params, x, rng=None, train=True):
         """x: [S, M] → (l_aux, combine [S,E,C], dispatch [S,E,C], counts)."""
@@ -388,7 +423,8 @@ class MOELayer:
         """Routed, dropless serving path (the reference's MoE-inference
         semantics, reference ops/transformer/inference/moe_inference.py:160
         — route every token, drop nothing, no capacity): router matmul and
-        softmax in float32, ``topk_route``, the token-expert pairs sorted
+        score in float32, ``topk_route`` with the gate's score function,
+        selection bias and epsilon, the token-expert pairs sorted
         by expert, the experts' matmuls as grouped matmuls over the groups
         (``experts.apply_grouped``), un-sorted and combined in the
         activations' type. Costs the routed FLOPs and holds no [S, E, C]
@@ -400,10 +436,15 @@ class MOELayer:
         lead = x.shape[:-1]
         m = x.shape[-1]
         xs = x.reshape(-1, m)                                      # [S, M]
-        k, e = self.gate.k, self.gate.num_experts
+        gate = self.gate
+        k, e = gate.k, gate.num_experts
         logits = xs.astype(jnp.float32) @ \
             params["gate"]["wg"].astype(jnp.float32)
-        w, idx = topk_route(logits, k, renormalize)                # [S, k]
+        w, idx = topk_route(logits, k, renormalize, gate.score,
+                            params["gate"].get("bias"),
+                            gate.renorm_eps)                       # [S, k]
+        if gate.scale != 1.0:
+            w = w * gate.scale
         flat = idx.reshape(-1)                                     # [S*k]
         order = jnp.argsort(flat, stable=True)   # pair ids, by expert
         exp_counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)

@@ -279,3 +279,90 @@ def test_routed_decode_reads_expert_leaves_in_place(one_chip, program):
         text, re.M)
     assert copied == [], copied
     assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
+
+
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_hybrid_stack_reads_every_leaf_in_place(one_chip, program):
+    """The slot decode step and a slot-prefill bucket (128) of an LFM2-MoE
+    of ``lfm2-24b-a2b.serve-agent-4k``'s widths (hidden 2048, 32 heads of
+    64 over 8 KV heads, dense FFN 11776, 64 experts of 1536, 4 a token,
+    conv of 3 taps; a dense conv layer, an attention layer and a routed
+    conv layer; 48 slots x 4096, small vocabulary), compiled for the chip.
+    The layers are of several kinds in per-kind stacks and are not one
+    scanned tree (``models/lfm2.py:_scan_layers``), and the constraint is
+    the compiled program: no instruction produces a layer's expert leaf
+    (bf16 [64, 2048, 1536] or [64, 1536, 2048], 403 MB), every pool leaf
+    (K and V of the ONE attention layer, a token's eight KV heads in one
+    stored row of 512; the conv state of the two conv layers) is aliased
+    to the output, the decode step's temporaries stay under 32 MB, and the
+    grouped matmuls are the ``ragged-dot`` custom calls the cell's
+    ``moe_kernels.pattern`` finds. (The cell's own nine layers, 40 slots
+    and whole vocabulary read 16.8 MB for ``jit_dec``, compiled the same
+    way; with K and V stored ``(4, 128)`` a row, as ``_kv_row_shape``
+    gives, the scanned attention layers' slabs are re-laid in HBM, 201 MB
+    of temporaries each: PERF.md, PR 36.)"""
+    import re
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.models.lfm2 import (ATTN, CONV, LFM2MoEConfig,
+                                           LFM2MoEModel)
+    from deepspeed_tpu.parallel import initialize_mesh
+
+    slots, max_len, bucket = 48, 4096, 128
+    model = LFM2MoEModel(LFM2MoEConfig(
+        vocab_size=512, n_positions=max_len, n_layer=3,
+        layer_types=(CONV, ATTN, CONV), num_dense_layers=1,
+        dtype="bfloat16"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    # zeros on ONE host device (2.4 GB of experts): the program is built by
+    # one call on a one-slot pool
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len}),
+        mesh_manager=initialize_mesh(dp=1, devices=jax.devices()[:1]))
+    leaf = engine.params["blocks"]["moe"]["moe"]["experts"]["w_gate"]
+    assert leaf.shape == (2, 64, 2048, 1536) and leaf.dtype == jnp.bfloat16
+    tiny = engine.init_slot_pool(1, max_len)
+    assert {k: v.shape for k, v in tiny.items()} == {
+        "k": (1, 1, max_len, 1, 512), "v": (1, 1, max_len, 1, 512),
+        "conv": (2, 1, 2, 2048)}
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    if program == "decode":
+        zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+        engine.slot_decode_step(tiny, zi, zi, zf)
+        fn = engine._slot_fns[("slot_decode", 1, max_len)]
+        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        first = len(jax.tree.leaves(params))
+    else:
+        engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
+        fn = engine._slot_fns[("slot_prefill", 1, max_len)]
+        args = (params, on_chip((1, bucket), jnp.int32), pool, i32, i32,
+                f32, i32, f32, i32)
+        first = len(jax.tree.leaves(params)) + 1
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        *args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    # a prefill's own temporaries: its 4096-column mini cache (8.4 MB) and
+    # 128 rows of attention scores over it
+    limit = (32 if program == "decode" else 96) * 2 ** 20
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert donated_params_from_hlo(text) == set(range(first, first + 3))
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = bf16\[64,(?:2048,1536|1536,2048)\]\S* (\S+)\(",
+        text, re.M)
+    assert copied == [], copied
+    assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3

@@ -120,3 +120,38 @@ def test_recast_requantizes_fp_refresh(model_and_params):
     re = eng.recast(fresh)
     assert any(is_quantized(x)
                for x in jax.tree.leaves(re, is_leaf=is_quantized))
+    # the one leaf-at-a-time path (``quantize_resident`` with a cast): the
+    # trainer's tree is left alone, a second refresh compiles nothing, and
+    # leaves that are int8 already pass through
+    assert not any(x.is_deleted() for x in jax.tree.leaves(fresh))
+    programs = dict(eng._leaf_fns)
+    again = eng.recast(eng.recast(fresh))
+    assert eng._leaf_fns == programs
+    for a, b in zip(jax.tree.leaves(again), jax.tree.leaves(re)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_quantize_resident_is_quantize_tree_a_leaf_at_a_time(model_and_params):
+    """What the engine does to parameters that are on the device already
+    (its own init, a loaded checkpoint): the payloads and scales of
+    ``quantize_tree``, stacked leaves a layer slice at a time, and every
+    leaf it quantized is consumed, so the tree is never held twice."""
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.quantization import quantize_tree
+    model, params = model_and_params
+    want = quantize_tree(params)
+    fresh = jax.tree.map(jnp.array, params)         # the copy to consume
+    got = make_engine(model, params)._quantize_resident(fresh)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        # jitted against eager: a scale may differ in its last bit, and a
+        # value on a rounding boundary with it
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), rtol=1e-6,
+                                   atol=1 if a.dtype == jnp.int8 else 0)
+    kept = jax.tree.leaves(jax.tree.map(
+        lambda x, q: x.is_deleted() == isinstance(q, QuantizedWeight),
+        fresh, got, is_leaf=lambda x: isinstance(x, QuantizedWeight)))
+    assert all(kept) and any(isinstance(x, QuantizedWeight)
+                             for x in jax.tree.leaves(
+                                 got, is_leaf=lambda x: isinstance(
+                                     x, QuantizedWeight)))

@@ -322,6 +322,37 @@ def test_stacked_leaves_at_a_layer_equal_that_layers_slice(experts, case):
     assert float(jnp.abs(want).max()) > 0
 
 
+@pytest.mark.parametrize("rows", [1, 4, 5, 8, 12])
+@pytest.mark.parametrize("experts", [ExpertFFN, GatedExpertFFN])
+def test_a_row_count_that_is_no_multiple_of_eight(experts, rows):
+    """``apply_grouped`` appends zero rows up to a multiple of 8 and cuts
+    them off again (``_whole_tiles``: the chip's kernel is wrong for a
+    float32 count that is none; four picks of a one-slot pool): the rows
+    that were there equal every expert's dense ``apply`` at the row's own
+    expert, stacked leaves or one layer's."""
+    e, m = 8, 16
+    ffn = experts(m, 32, e)
+    stacked = jax.vmap(ffn.init)(jax.random.split(jax.random.PRNGKey(7), 2))
+    for i, k in enumerate(("bi", "bo")):
+        if k in stacked:
+            stacked[k] = jax.random.normal(jax.random.PRNGKey(8 + i),
+                                           stacked[k].shape) * 0.1
+    ids = jnp.sort(jax.random.randint(jax.random.PRNGKey(rows), (rows,), 0, e))
+    sizes = jnp.bincount(ids, length=e).astype(jnp.int32)
+    x = jax.random.normal(jax.random.PRNGKey(9), (rows, m))
+    one = jax.tree.map(lambda a: a[1], stacked)
+    want = ffn.apply(one, jnp.broadcast_to(x[None], (e, rows, m)),
+                     train=False)[ids, jnp.arange(rows)]
+    whole = {k: stacked[k] if k in ffn.matmul_leaves else v
+             for k, v in one.items()}
+    for params, layer in ((one, None), (whole, 1)):
+        got = jax.jit(lambda p: ffn.apply_grouped(p, x, sizes, ids,
+                                                  layer=layer))(params)
+        assert got.shape == (rows, m)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-6)
+
+
 @pytest.fixture
 def grouped_calls(monkeypatch):
     """Every ``apply_grouped`` call made (traced) while the test runs:
