@@ -62,12 +62,30 @@ def _compile(fn, one_chip, shape, dtype, grad=True):
     return compiled
 
 
-@pytest.mark.parametrize("b,h,d", [(8, 12, 64), (8, 16, 64), (4, 32, 64),
-                                   (4, 16, 128)])
-def test_packed_fwd_bwd(one_chip, b, h, d):
-    """The GPT-2 125M / 350M / 1.3B train-step attention shapes."""
+@pytest.mark.parametrize("b,t,h,d", [
+    (8, 1024, 12, 64), (8, 1024, 16, 64), (4, 1024, 32, 64),
+    (4, 1024, 16, 128),
+    (1, 1024, 16, 64),      # eval_batch's single row
+    (2, 256, 32, 64),       # the OPT cells' engine.forward check
+    (1, 2048, 16, 64),      # the longest a grid step holds in 16 MiB
+    (1, 4096, 16, 64),      # supported()'s cap: the raised VMEM limit
+], ids=lambda v: str(v))
+def test_packed_fwd_bwd(one_chip, b, t, h, d):
+    """The GPT-2 125M / 350M / 1.3B train-step attention shapes and the
+    two shapes the cells' checks run beside them, then the longest
+    sequences ``supported()`` admits (no cell; the parent's kernel did not
+    compile at 4096): Mosaic accepts every tile the resolver picks for
+    them, forward and backward."""
     _compile(lambda q, k, v: packed_flash_attention(q, k, v, h),
-             one_chip, (b, 1024, h * d), jnp.bfloat16)
+             one_chip, (b, t, h * d), jnp.bfloat16)
+
+
+def test_packed_window_fits(one_chip):
+    """A window's two edges give the loop three bodies; at 512 tiles they
+    overran the scoped VMEM at T = 2048 by 24 KB, so the resolver gives a
+    window no tile over 256."""
+    _compile(lambda q, k, v: packed_flash_attention(q, k, v, 16, window=512),
+             one_chip, (1, 2048, 16 * 64), jnp.bfloat16)
 
 
 @pytest.mark.parametrize("shape,dtype", [
