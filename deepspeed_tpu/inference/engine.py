@@ -155,9 +155,9 @@ class InferenceEngine:
         # slot_decode_step read its routing stats back with the tokens
         self._routed = bool(getattr(model, "routed_experts", False))
         self._routing = None
-        # a model whose pool holds a recurrent state beside K and V: the
-        # prefills tell it each prompt's real length
-        self._recurrent = bool(getattr(model, "recurrent_state", ()))
+        # a model whose pool holds a recurrent state or a window ring
+        # beside K and V: the prefills tell it each prompt's real length
+        self._recurrent = bool(getattr(model, "lane_end_state", ()))
         n_params = sum(int(np.prod(s.shape))
                        for s in jax.tree.leaves(param_shapes))
         log_dist(f"InferenceEngine initialized: params={n_params/1e6:.1f}M "

@@ -183,6 +183,19 @@ class GPT2Model(ModelSpec):
     #: column" of a KV lane (a shared prefix, a rolled-back draft) is not
     #: valid of it: ``ServingEngine`` fences those mechanisms
     recurrent_state = ()
+    #: the two leaves of ``init_kv_cache`` (keys, values) that are RINGS of
+    #: a window layer's last W columns, ``[L', S, W, G, w]``, position p in
+    #: column ``p mod W`` (``_window_attend``); such a layer hands its
+    #: ``attn_fn`` ``ring=`` them. Like a recurrent state a ring is valid
+    #: at the lane's end alone (``lane_end_state``)
+    window_rings = ()
+
+    @property
+    def lane_end_state(self):
+        """The pool leaves that hold a lane as it stands after its LAST
+        token, not a row per token: whoever pads a row must say its real
+        length, and nothing may go on from an earlier column of the lane."""
+        return tuple(self.recurrent_state) + tuple(self.window_rings)
 
     def _compute_dtype(self, params):
         return _params_compute_dtype(params, self.config.dtype)
@@ -718,6 +731,15 @@ class GPT2Model(ModelSpec):
         vs = lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
         s, h, t, hd = q.shape
         max_len, g, w = ks.shape[1:]
+        if t > 1 and w > hd and hd % _LANES == 0:
+            # a prefill over rows that hold several whole-lane heads (a
+            # family's own choice for its decode step: ``models/lfm2.py``):
+            # the zero-padded query below would multiply w / hd times the
+            # scores' FLOPs, which a block of T queries pays T times; the
+            # rows seen as heads cost one re-laying of the keys it reads
+            ks = ks.reshape(s, max_len, g * w // hd, hd)
+            vs = vs.reshape(s, max_len, g * w // hd, hd)
+            g, w = g * w // hd, hd
         pack = w // hd                   # KV heads to a stored row
         rep = h // (g * pack)            # query heads to a KV head
         # own[j, j']: head j of a row owns lane block j'
@@ -736,6 +758,142 @@ class GPT2Model(ModelSpec):
                          vs.astype(q.dtype))
         out = out.reshape(s, g, pack, rep, t, pack, hd)
         return (out * own).sum(axis=5).reshape(s, h, t, hd)
+
+    #: bytes of float32 scores one call of ``_kv_attend`` may hold: a prefill
+    #: of more queries than fit goes in blocks of queries (``_query_block``)
+    _attend_scores_bytes = 1 << 30
+
+    def _query_block(self, t: int, heads: int, keys: int) -> int:
+        """How many of a prefill's ``t`` queries attend ``keys`` columns in
+        one piece: the largest power of two (8 at least) whose float32
+        scores ``[heads, block, keys]`` stay inside
+        ``_attend_scores_bytes``. ``t`` itself where that is no fewer: one
+        piece, the program it always was (a decode step; every bucket of a
+        pool of 32 heads up to 2048 columns)."""
+        rows = max(self._attend_scores_bytes // (heads * keys * 4), 8)
+        return min(1 << (rows.bit_length() - 1), t)
+
+    @staticmethod
+    def _in_row_blocks(fn, block, axis, *rows):
+        """``fn(at, *cut) -> (out, extra)`` over arrays that hold a row of
+        their own a token along ``axis``, cut alike into blocks of ``block``
+        rows and taken one after another (``lax.map``), so that what ``fn``
+        holds at a time is a block's; ``at`` is the block's first row. The
+        whole blocks go through the map, and what is left over (fewer than
+        ``block`` rows, where ``block`` does not divide them) through one
+        call more of its own: no length falls back to one piece. ``out``
+        has its rows on ``axis`` and is laid end to end; ``extra`` (a
+        pytree, or None) is added up over the blocks. Rows that fit in one
+        block are the plain call. For work in which a row needs no other
+        row's: a query's row of a softmax, a token's feed-forward."""
+        t = rows[0].shape[axis]
+        n, rest = divmod(t, block)
+        if n == 0 or (n == 1 and not rest):
+            return fn(0, *rows)
+
+        def cut(a, lo, size):
+            return lax.slice_in_dim(a, lo, lo + size, axis=axis)
+
+        def blocks(a):
+            a = cut(a, 0, n * block)
+            return jnp.moveaxis(a.reshape(
+                a.shape[:axis] + (n, block) + a.shape[axis + 1:]), axis, 0)
+
+        out, extra = lax.map(lambda xs: fn(xs[0], *xs[1:]),
+                             (jnp.arange(n) * block,) + tuple(
+                                 blocks(a) for a in rows))
+        out = jnp.moveaxis(out, 0, axis)
+        out = out.reshape(out.shape[:axis] + (n * block,) +
+                          out.shape[axis + 2:])
+        extra = jax.tree.map(lambda e: e.sum(axis=0), extra)
+        if rest:
+            last, more = fn(n * block, *(cut(a, n * block, rest)
+                                         for a in rows))
+            out = jnp.concatenate([out, last], axis=axis)
+            extra = jax.tree.map(jnp.add, extra, more)
+        return out, extra
+
+    #: queries of a window layer's prefill that go against their band of
+    #: keys in one piece: scores [heads, block, W + block]
+    _window_query_block = 256
+
+    def _window_attend(self, q, k, v, ring_k, ring_v, layer, start,
+                       lengths=None):
+        """A WINDOW layer over its ring: pool leaves ``ring_k`` / ``ring_v``
+        ``[Lw, S, W, G, w]`` that keep a slot's last W positions, position
+        p in column ``p mod W``, whatever the lane's length. q, k, v
+        [S, H|Hk, T, hd] are the new tokens', row s's token j at position
+        ``start + j``; a query at p sees the keys at ``p - W + 1 ... p``.
+        Returns ``(attention [S, H, T, hd], ring_k, ring_v)``.
+
+        - ``T == 1`` (a decode step; ``start`` a scalar or [S]): the row is
+          written at ``start mod W`` (``_kv_write``: S rows and nothing
+          else) and the ring attended where it lies (``_kv_attend``).
+          Every column then holds one of the last W positions, or nothing
+          yet: column c is kept where ``c <= start``.
+        - ``T > 1`` (a prefill, a chunk; ``start`` a scalar): the W
+          positions before ``start`` (the ring's rows in order; under 0:
+          masked) and the block's T are one strip of ``W + T`` keys, and
+          the queries go in blocks of ``_window_query_block``
+          (``_in_row_blocks``) against the band of ``W + block`` keys
+          beside them: scores
+          ``[H, block, W + block]``, whatever T. Then the last W rows up
+          to row ``lengths[s] - 1`` (``None``: T; a right-padded bucket's
+          padding never enters the ring) are written at their positions'
+          columns."""
+        s, t = q.shape[0], q.shape[2]
+        window = ring_k.shape[2]
+        row = ring_k.shape[3:]
+        k = k.transpose(0, 2, 1, 3)
+        v = v.transpose(0, 2, 1, 3)                         # [S, T, Hk, hd]
+        if t == 1:
+            pos = jnp.broadcast_to(start, (s,))
+            ring_k = self._kv_write(ring_k, layer, k, pos % window)
+            ring_v = self._kv_write(ring_v, layer, v, pos % window)
+            keep = (jnp.arange(window)[None, :] <= pos[:, None])
+            return self._kv_attend(q, ring_k, ring_v, layer,
+                                   keep[:, None, None, :], None), \
+                ring_k, ring_v
+        if jnp.ndim(start) != 0:
+            raise NotImplementedError(
+                "a block of several tokens at a position of its own a row "
+                "(verify_with_slots) cannot go over a window ring: rows a "
+                "rejected draft wrote have replaced columns still in the "
+                "window")
+        order = (start + jnp.arange(window)) % window   # row r: start - W + r
+        strips = []
+        for ring, new in ((ring_k, k), (ring_v, v)):
+            hist = lax.dynamic_index_in_dim(ring, layer, 0, keepdims=False)
+            strips.append(jnp.concatenate(
+                [hist[:, order], new.reshape((s, t) + row).astype(ring.dtype)],
+                axis=1))                                    # [S, W + T, G, w]
+
+        def attend(at, qb):
+            # a block of queries against the band of W + block keys beside
+            # it: query i and key j of the band lie i - j + W positions
+            # apart; key j lies at position start - W + at + j
+            block = qb.shape[2]
+            band = window + block
+            apart = window + jnp.arange(block)[:, None] - \
+                jnp.arange(band)[None, :]
+            real = start - window + at + jnp.arange(band) >= 0
+            keys = [lax.dynamic_slice_in_dim(strip, at, band, axis=1)
+                    for strip in strips]
+            return self._kv_attend(
+                qb, keys[0][None], keys[1][None], 0,
+                (apart >= 0) & (apart < window) & real[None, :], None), None
+
+        out, _ = self._in_row_blocks(attend, self._window_query_block, 2, q)
+        # the ring keeps positions end - W ... end - 1, end = start + lengths
+        ends = jnp.full((s,), t) if lengths is None else lengths
+        cols = (start + ends[:, None] + jnp.arange(window)[None, :]) % window
+        rings = []
+        for ring, strip in zip((ring_k, ring_v), strips):
+            last = jax.vmap(lambda rows, at: lax.dynamic_slice_in_dim(
+                rows, at, window, axis=0))(strip, ends)
+            rings.append(ring.at[layer, jnp.arange(s)[:, None], cols].set(
+                last, unique_indices=True))
+        return out, rings[0], rings[1]
 
     def _decode_attn_mask(self, q_pos, k_pos):
         """Boolean keep-mask over the cache columns: ``q_pos`` [B|1, 1, T, 1]
@@ -807,6 +965,7 @@ class GPT2Model(ModelSpec):
                 jnp.maximum(cols - pad_counts[:, None], 0)
         x = self._embed(params, input_ids, start_pos=start_pos,
                         positions=positions)
+        block = self._query_block(t, self.config.n_head, max_len)
         k_pos = jnp.arange(max_len)[None, None, None, :]
         pad_valid = None
         if pad_counts is not None:     # left-pad columns are never valid keys
@@ -815,33 +974,51 @@ class GPT2Model(ModelSpec):
         extras = self._layer_extras()
         blocks, whole = self._scan_split(params["blocks"], cached=True)
 
-        def keep_mask(extra):
+        def keep_mask(extra, q_pos=q_pos):
             mask = self._decode_attn_mask_ex(q_pos, k_pos, extra)
             return mask if pad_valid is None else mask & pad_valid
 
-        base_mask = keep_mask(None) if extras is None else None
-        bias = self._decode_attn_bias(q_pos, k_pos)
+        # one piece: mask and bias are made once, outside the layers
+        whole_mask = block == t and extras is None
+        base_mask = keep_mask(None) if whole_mask else None
+        base_bias = self._decode_attn_bias(q_pos, k_pos) if block == t \
+            else None
 
         def body(carry, xs):
             x, pool = carry
             layer_params, layer, extra = xs
-            mask = base_mask if extras is None else keep_mask(extra)
             routed = {} if whole is None else {"stacked": (whole, layer)}
             pool = dict(pool)
 
-            def cached_attn(q, k, v):
+            def attend(at, q, q_pos):
+                if block == t:
+                    mask = base_mask if whole_mask else keep_mask(extra)
+                    bias = base_bias
+                else:
+                    mask = keep_mask(extra, q_pos)
+                    bias = self._decode_attn_bias(q_pos, k_pos)
+                return self._kv_attend(q, pool["k"], pool["v"], layer,
+                                       mask, bias), None
+
+            def cached_attn(q, k, v, ring=None):
                 # q, k, v arrive [S, H, T, hd]. kv_write / kv_read scopes
                 # nest inside "attn" and take precedence in the perf
                 # plane's bucket classifier, so cache traffic is
-                # attributed as bytes, not attention math
+                # attributed as bytes, not attention math. ``ring``: a
+                # window layer names its two ring leaves of the pool
+                # (``_window_attend``) in place of ``k`` and ``v``
+                if ring is not None:
+                    out, pool[ring[0]], pool[ring[1]] = self._window_attend(
+                        q, k, v, pool[ring[0]], pool[ring[1]], layer, start,
+                        lengths)
+                    return out
                 with jax.named_scope("kv_write"):
                     pool["k"] = self._kv_write(pool["k"], layer,
                                                k.transpose(0, 2, 1, 3), start)
                     pool["v"] = self._kv_write(pool["v"], layer,
                                                v.transpose(0, 2, 1, 3), start)
                 with jax.named_scope("kv_read"):
-                    return self._kv_attend(q, pool["k"], pool["v"], layer,
-                                           mask, bias)
+                    return self._in_row_blocks(attend, block, 2, q, q_pos)[0]
 
             if self.recurrent_state:
                 def cached_state(name, rows):

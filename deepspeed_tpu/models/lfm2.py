@@ -118,102 +118,60 @@ class _Layer:
         return cls(*kinds, *children)
 
 
-class LFM2MoEModel(LlamaModel):
-    routed_experts = True       # the cache forwards hand routing stats on
-    recurrent_state = ("conv",)
+class KindStacks:
+    """A stack whose layers are of several kinds, each kind's parameters in
+    a stacked tree of its own: ``blocks[op_stacks[kind]]`` for the
+    operators, ``blocks["dense"]`` and ``blocks["moe"]`` for the two
+    feed-forwards (``self.moe``: the routed layer). What a family with such
+    a stack shares (``models/lfm2.py``, ``models/kexaone.py``): which layer
+    is which (``_index_layers``) and the walk (``_scan_layers``)."""
 
-    def __init__(self, config: LFM2MoEConfig = LFM2_24B_A2B):
-        super().__init__(config)
-        cfg = config
-        types = tuple(cfg.layer_types)
-        if len(types) != cfg.n_layer or set(types) - {CONV, ATTN}:
+    #: {operator kind, as ``layer_types`` names it: its key in ``blocks``}
+    op_stacks = {}
+
+    def _index_layers(self, types, num_dense):
+        """``self.layers``: layer l as (operator kind, index in its stack,
+        FFN kind, index); ``self.counts``: layers of each kind; and the
+        pattern's split for the walk."""
+        types = tuple(types)
+        n = self.config.n_layer
+        if len(types) != n or set(types) - set(self.op_stacks):
             raise ValueError(
-                f"layer_types must name n_layer={cfg.n_layer} layers, each "
-                f"{CONV!r} or {ATTN!r}; got {len(types)}: {types}")
-        if ATTN not in types:
-            raise ValueError("the pool is sized by its attention layers: "
-                             "layer_types names none")
-        if not 0 <= cfg.num_dense_layers <= cfg.n_layer:
-            raise ValueError(f"num_dense_layers {cfg.num_dense_layers} not "
-                             f"in [0, {cfg.n_layer}]")
-        # layer l = (operator kind, index in its stack, FFN kind, index)
-        seen = {CONV: 0, ATTN: 0, "dense": 0, "moe": 0}
+                f"layer_types must name n_layer={n} layers, each one of "
+                f"{sorted(self.op_stacks)}; got {len(types)}: {types}")
+        if not 0 <= num_dense <= n:
+            raise ValueError(f"{num_dense} leading dense layers not in "
+                             f"[0, {n}]")
+        seen = dict.fromkeys((*self.op_stacks, "dense", "moe"), 0)
         self.layers = []
         for l, op in enumerate(types):
-            ffn = "dense" if l < cfg.num_dense_layers else "moe"
+            ffn = "dense" if l < num_dense else "moe"
             self.layers.append((op, seen[op], ffn, seen[ffn]))
             seen[op] += 1
             seen[ffn] += 1
         self.counts = seen
         self.lead, self.period, self.repeats = self._split_pattern(
-            types, cfg.num_dense_layers)
-        self.gate = TopKGate(cfg.n_embd, cfg.num_experts, cfg.top_k,
-                             score="sigmoid",
-                             select_bias=cfg.use_expert_bias,
-                             renorm_eps=cfg.renorm_eps,
-                             scale=cfg.routed_scaling_factor)
-        self.experts = GatedExpertFFN(
-            cfg.n_embd, cfg.moe_intermediate_size, cfg.num_experts,
-            initializer_range=cfg.initializer_range)
-        self.moe = MOELayer(self.gate, self.experts)
+            types, num_dense)
 
     @staticmethod
     def _split_pattern(types, lead):
         """``(lead, period, repeats)``: after the ``lead`` leading layers
-        (the dense ones, whose FFN is of another kind) the shortest run of
-        kinds that repeats, and how many whole times; what is left after
-        ``lead + period * repeats`` layers is the tail. ``repeats`` under 2
-        means there is nothing to scan."""
+        (the dense ones, whose FFN is of another kind) the run of kinds
+        that repeats at least twice and covers the most layers (the
+        shortest of those that cover as many), and how many whole times;
+        what is left after ``lead + period * repeats`` layers is the tail.
+        ``repeats`` under 2 means there is nothing to scan."""
         rest = types[lead:]
+        best = (lead, 0, 0)
         for period in range(1, len(rest) // 2 + 1):
             repeats = 1
             while rest[repeats * period:(repeats + 1) * period] == \
                     rest[:period]:
                 repeats += 1
-            if repeats >= 2:
-                return lead, period, repeats
-        return lead, 0, 0
+            if repeats >= 2 and period * repeats > best[1] * best[2]:
+                best = (lead, period, repeats)
+        return best
 
-    # ------------------------------------------------------------------ init
-    def init(self, rng):
-        cfg = self.config
-        d, v = cfg.n_embd, cfg.padded_vocab
-        hd, hk, m = cfg.head_dim, cfg.kv_head_count, cfg.intermediate
-        n = self.counts
-        std = cfg.initializer_range
-        proj_std = std / math.sqrt(2 * cfg.n_layer)
-        keys = iter(jax.random.split(rng, 12))
-
-        def norm(shape, s):
-            return jax.random.normal(next(keys), shape, jnp.float32) * s
-
-        lc, la, ld, lm = n[CONV], n[ATTN], n["dense"], n["moe"]
-        blocks = {
-            "conv": {"ln1_scale": jnp.ones((lc, d)),
-                     "in_w": norm((lc, d, 3 * d), std),
-                     "conv_w": norm((lc, d, cfg.conv_L_cache),
-                                    1.0 / math.sqrt(cfg.conv_L_cache)),
-                     "out_w": norm((lc, d, d), proj_std)},
-            "attn": {"ln1_scale": jnp.ones((la, d)),
-                     "qkv_w": norm((la, d, (cfg.n_head + 2 * hk) * hd), std),
-                     "q_norm_scale": jnp.ones((la, hd)),
-                     "k_norm_scale": jnp.ones((la, hd)),
-                     "attn_proj_w": norm((la, d, d), proj_std)},
-            "dense": {"ln2_scale": jnp.ones((ld, d)),
-                      "gate_w": norm((ld, d, m), std),
-                      "up_w": norm((ld, d, m), std),
-                      "down_w": norm((ld, m, d), proj_std)},
-            "moe": {"ln2_scale": jnp.ones((lm, d)),
-                    "moe": jax.vmap(self.moe.init)(
-                        jax.random.split(next(keys), lm))},
-        }
-        params = {"wte": norm((v, d), std), "blocks": blocks,
-                  "ln_f_scale": jnp.ones((d,))}
-        if not cfg.tie_word_embeddings:
-            params["lm_head"] = norm((v, d), std)
-        return params
-
-    # --------------------------------------------------- the walk of the stack
     def _scan_layers(self, body, carry, blocks, indexed=False, unroll=1):
         """Leading layers, a ``lax.scan`` over the whole periods with one
         period's layers unrolled in its body, the tail. Every stack is
@@ -223,9 +181,9 @@ class LFM2MoEModel(LlamaModel):
         routed layers' outputs stacked in layer order, or ``None`` where
         the body gives none."""
         moe, whole = self.moe.take_whole(blocks["moe"]["moe"])
-        stacks = {CONV: blocks["conv"], ATTN: blocks["attn"],
-                  "dense": blocks["dense"],
-                  "moe": {**blocks["moe"], "moe": moe}}
+        stacks = {kind: blocks[key] for kind, key in self.op_stacks.items()}
+        stacks["dense"] = blocks["dense"]
+        stacks["moe"] = {**blocks["moe"], "moe": moe}
 
         lead, period, repeats = self.lead, self.period, self.repeats
         first = self.layers[lead:lead + period]
@@ -268,6 +226,68 @@ class LFM2MoEModel(LlamaModel):
                 outs.append(out.reshape((-1,) + out.shape[2:]))
         carry = straight(carry, self.layers[lead + period * repeats:])
         return carry, jnp.concatenate(outs) if outs else None
+
+
+class LFM2MoEModel(KindStacks, LlamaModel):
+    routed_experts = True       # the cache forwards hand routing stats on
+    recurrent_state = ("conv",)
+    op_stacks = {CONV: "conv", ATTN: "attn"}
+
+    def __init__(self, config: LFM2MoEConfig = LFM2_24B_A2B):
+        super().__init__(config)
+        cfg = config
+        if ATTN not in cfg.layer_types:
+            raise ValueError("the pool is sized by its attention layers: "
+                             "layer_types names none")
+        self._index_layers(cfg.layer_types, cfg.num_dense_layers)
+        self.gate = TopKGate(cfg.n_embd, cfg.num_experts, cfg.top_k,
+                             score="sigmoid",
+                             select_bias=cfg.use_expert_bias,
+                             renorm_eps=cfg.renorm_eps,
+                             scale=cfg.routed_scaling_factor)
+        self.experts = GatedExpertFFN(
+            cfg.n_embd, cfg.moe_intermediate_size, cfg.num_experts,
+            initializer_range=cfg.initializer_range)
+        self.moe = MOELayer(self.gate, self.experts)
+
+    # ------------------------------------------------------------------ init
+    def init(self, rng):
+        cfg = self.config
+        d, v = cfg.n_embd, cfg.padded_vocab
+        hd, hk, m = cfg.head_dim, cfg.kv_head_count, cfg.intermediate
+        n = self.counts
+        std = cfg.initializer_range
+        proj_std = std / math.sqrt(2 * cfg.n_layer)
+        keys = iter(jax.random.split(rng, 12))
+
+        def norm(shape, s):
+            return jax.random.normal(next(keys), shape, jnp.float32) * s
+
+        lc, la, ld, lm = n[CONV], n[ATTN], n["dense"], n["moe"]
+        blocks = {
+            "conv": {"ln1_scale": jnp.ones((lc, d)),
+                     "in_w": norm((lc, d, 3 * d), std),
+                     "conv_w": norm((lc, d, cfg.conv_L_cache),
+                                    1.0 / math.sqrt(cfg.conv_L_cache)),
+                     "out_w": norm((lc, d, d), proj_std)},
+            "attn": {"ln1_scale": jnp.ones((la, d)),
+                     "qkv_w": norm((la, d, (cfg.n_head + 2 * hk) * hd), std),
+                     "q_norm_scale": jnp.ones((la, hd)),
+                     "k_norm_scale": jnp.ones((la, hd)),
+                     "attn_proj_w": norm((la, d, d), proj_std)},
+            "dense": {"ln2_scale": jnp.ones((ld, d)),
+                      "gate_w": norm((ld, d, m), std),
+                      "up_w": norm((ld, d, m), std),
+                      "down_w": norm((ld, m, d), proj_std)},
+            "moe": {"ln2_scale": jnp.ones((lm, d)),
+                    "moe": jax.vmap(self.moe.init)(
+                        jax.random.split(next(keys), lm))},
+        }
+        params = {"wte": norm((v, d), std), "blocks": blocks,
+                  "ln_f_scale": jnp.ones((d,))}
+        if not cfg.tie_word_embeddings:
+            params["lm_head"] = norm((v, d), std)
+        return params
 
     # ----------------------------------------------------------------- block
     def _qk_norm(self, q, k, p):

@@ -363,18 +363,59 @@ class MOELayer:
     [E, C, ...] and GSPMD emits the all-to-all the reference performs
     explicitly (``_AllToAll.apply``, sharded_moe.py:90)."""
 
-    def __init__(self, gate: TopKGate, experts, use_sharding_constraints=True):
+    def __init__(self, gate: TopKGate, experts, use_sharding_constraints=True,
+                 held=None, shared=None):
+        """``held = (offset, count)``: this layer holds experts ``offset ...
+        offset + count - 1`` of the ``gate.num_experts`` the router scores
+        (one chip's share under expert parallelism; ``experts`` is built for
+        ``count``). ``apply_routed`` then routes over all of them and
+        computes what its own give; what the absent ones would have added
+        is left out, and nothing stands in for their chips. ``None``: all.
+        ``shared``: an expert module of ONE expert that every token goes
+        through with weight 1, beside the routed ones (``"shared"`` in the
+        parameters; under expert parallelism every chip holds it, and its
+        output is counted once). Both are ``apply_routed``'s alone: the
+        capacity dispatch ``apply`` raises."""
         self.gate = gate
         self.experts = experts
         self.use_sharding_constraints = use_sharding_constraints
+        if held is not None:
+            offset, count = held
+            if not (0 <= offset and count >= 1 and
+                    offset + count <= gate.num_experts and
+                    count == experts.num_experts):
+                raise ValueError(
+                    f"held={held}: experts offset ... offset + count - 1 "
+                    f"must lie within the router's {gate.num_experts} and "
+                    f"the expert module hold count={experts.num_experts}")
+            if (offset, count) == (0, gate.num_experts):
+                held = None
+        self.held = held
+        self.shared = shared
 
     def init(self, rng):
         gate_rng, exp_rng = jax.random.split(rng)
-        return {"gate": self.gate.init(gate_rng),
-                "experts": self.experts.init(exp_rng)}
+        params = {"gate": self.gate.init(gate_rng),
+                  "experts": self.experts.init(exp_rng)}
+        if self.shared is not None:
+            params["shared"] = jax.tree.map(
+                lambda a: a[0], self.shared.init(jax.random.fold_in(rng, 2)))
+        return params
+
+    def _apply_shared(self, params, xs):
+        """The shared expert's output for rows ``xs`` [S, M]: its leaves
+        are one expert's, without the [E] axis."""
+        with jax.named_scope("shared_expert"):
+            one = jax.tree.map(lambda a: a[None], params["shared"])
+            return self.shared.apply(one, xs[None])[0]
 
     def apply(self, params, x, rng=None, train=True):
         """x: [..., M] (any leading dims) → (y [..., M], l_aux, exp_counts)."""
+        if self.held is not None or self.shared is not None:
+            raise NotImplementedError(
+                "MOELayer.apply (the capacity dispatch) knows neither a "
+                "share of the experts (held) nor a shared expert: both are "
+                "apply_routed's")
         lead = x.shape[:-1]
         m = x.shape[-1]
         xs = x.reshape(-1, m)                                      # [S, M]
@@ -429,10 +470,16 @@ class MOELayer:
         (``experts.apply_grouped``), un-sorted and combined in the
         activations' type. Costs the routed FLOPs and holds no [S, E, C]
         tensor. Same return shape as apply(): l_aux is 0 (no load-balance
-        objective when serving); exp_counts [E] are the rows each expert
-        got. ``stacked``: ``(whole, layer)`` from ``take_whole``; the
-        experts' matmul leaves are then read from ``whole`` at ``layer``
-        and ``params`` holds the rest."""
+        objective when serving); exp_counts are the rows each expert this
+        layer holds got ([E]; ``held``: [count]). ``stacked``: ``(whole,
+        layer)`` from ``take_whole``; the experts' matmul leaves are then
+        read from ``whole`` at ``layer`` and ``params`` holds the rest.
+
+        With ``held``, the router still scores and picks among all E; a
+        pair whose expert is not held sorts after every held one, belongs
+        to no group of the grouped matmuls (which visit the rows of their
+        groups and no others) and is combined as zero. The shared expert,
+        where there is one, is added once a token after the combine."""
         lead = x.shape[:-1]
         m = x.shape[-1]
         xs = x.reshape(-1, m)                                      # [S, M]
@@ -446,7 +493,12 @@ class MOELayer:
         if gate.scale != 1.0:
             w = w * gate.scale
         flat = idx.reshape(-1)                                     # [S*k]
+        if self.held is not None:
+            offset, e = self.held
+            flat = flat - offset        # its index among the held, or
+            flat = jnp.where((flat >= 0) & (flat < e), flat, e)    # absent
         order = jnp.argsort(flat, stable=True)   # pair ids, by expert
+        # an absent pair's index lies past the end and is dropped
         exp_counts = jnp.zeros((e,), jnp.int32).at[flat].add(1)
         experts, layer = params["experts"], None
         if stacked is not None:
@@ -456,9 +508,14 @@ class MOELayer:
             expert_out = self.experts.apply_grouped(
                 experts, xs[order // k], exp_counts, flat[order],
                 layer=layer)                                       # [S*k, M]
+        if self.held is not None:       # rows of no group: whatever lay there
+            rows = jnp.arange(order.shape[0])[:, None] < exp_counts.sum()
+            expert_out = jnp.where(rows, expert_out, 0)
         # un-sort: pair (s, j) sits at row inverse[s*k + j]
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
         picked = expert_out[inverse].reshape(-1, k, m)
         y = jnp.einsum("skm,sk->sm", picked, w.astype(x.dtype))
+        if self.shared is not None:
+            y = y + self._apply_shared(params, xs)
         return y.reshape(*lead, m), jnp.float32(0.0), exp_counts

@@ -35,40 +35,58 @@ __all__ = ["ServingEngine", "SamplingParams", "QueueFull", "RequestState"]
 class ServingEngine:
     """Slot-based continuous-batching serving on top of InferenceEngine."""
 
-    @staticmethod
-    def _fence_recurrent_state(engine, config):
-        """A model whose pool holds a recurrent state (``recurrent_state``:
-        what a layer keeps of a lane exists at the lane's END alone, not a
-        row per token) cannot be served with the mechanisms that lean on a
-        KV lane being valid up to ANY column, nor with the one that leaves
-        a lane half filled across decode ticks. Refused here, by name,
-        before a pool is allocated; snapshots of state, and a decode step
-        that leaves the state of a lane it does not serve alone, are
-        ROADMAP B8's."""
+    #: what cannot be served over each kind of state a model's pool may
+    #: declare beside full-length K and V (``GPT2Model.recurrent_state``,
+    #: ``window_rings``): {kind: (what it is, {config block: why not})}
+    _LANE_END_FENCES = {
+        "recurrent_state": (
+            "keeps a recurrent state {names} beside K and V, which exists "
+            "only at a lane's last token",
+            {"prefix_cache": "a lane copied from another request holds the "
+             "donor's state at the donor's end, not at the shared prefix",
+             "speculative": "rejected draft rows cannot be rolled back out "
+             "of it column by column",
+             "kv_quant": "it is rewritten every token, and an int8 round "
+             "trip a token would compound where a KV column is quantized "
+             "once",
+             "chunked_prefill": "the fused decode tick runs over every slot "
+             "between two chunks, and the dummy row of a lane that is still "
+             "prefilling is pushed into its state, where a dummy KV column "
+             "is overwritten by the next chunk"}),
+        "window_rings": (
+            "keeps its window layers' keys and values in rings {names} of "
+            "the last positions, which hold a lane as it stands at its last "
+            "token",
+            {"prefix_cache": "a lane copied from another request holds the "
+             "donor's last positions, not those before the shared prefix",
+             "speculative": "the rows a rejected draft wrote have replaced "
+             "columns still in the window and cannot be rolled back",
+             "chunked_prefill": "a prompt's last chunk may start below the "
+             "column the chunks before it reached (reuse_plan), and the "
+             "ring no longer holds the positions before that"}),
+    }
+
+    @classmethod
+    def _fence_lane_end_state(cls, engine, config):
+        """A model whose pool holds state that exists at a lane's END alone
+        (a recurrent state; a window layer's ring: not a row per token)
+        cannot be served with the mechanisms that lean on a KV lane being
+        valid up to ANY column, nor with those that leave a lane half
+        filled across decode ticks. Refused here, by the name of the block
+        and of the state, before a pool is allocated; snapshots of such
+        state are ROADMAP B8's. An int8 pool holds a ring as it holds a
+        lane (a column is quantized once, when it is written)."""
         module = getattr(engine, "module", None)
-        if not getattr(module, "recurrent_state", ()):
-            return
         from ..runtime.config_utils import ConfigError
-        on = lambda block: getattr(getattr(config, block, None),
-                                   "enabled", False)
-        why = (f"{type(module).__name__} keeps a recurrent state "
-               f"{tuple(module.recurrent_state)} beside K and V, which "
-               f"exists only at a lane's last token")
-        for block, reason in (
-                ("prefix_cache", "a lane copied from another request holds "
-                 "the donor's state at the donor's end, not at the shared "
-                 "prefix"),
-                ("speculative", "rejected draft rows cannot be rolled back "
-                 "out of it column by column"),
-                ("kv_quant", "it is rewritten every token, and an int8 round "
-                 "trip a token would compound where a KV column is quantized "
-                 "once"),
-                ("chunked_prefill", "the fused decode tick runs over every "
-                 "slot between two chunks, and the dummy row of a lane that "
-                 "is still prefilling is pushed into its state, where a "
-                 "dummy KV column is overwritten by the next chunk")):
-            if on(block):
-                raise ConfigError(f"{block}: {why}; {reason}")
+        for kind, (what, fences) in cls._LANE_END_FENCES.items():
+            names = tuple(getattr(module, kind, ()))
+            if not names:
+                continue
+            for block, reason in fences.items():
+                if getattr(getattr(config, block, None), "enabled", False):
+                    raise ConfigError(
+                        f"{block}: {type(module).__name__} "
+                        f"{what.format(names=names)}; {reason}")
 
     def __init__(self, engine, config: Union[ServingConfig, dict, None] = None,
                  clock: Callable[[], float] = time.monotonic, seed: int = 0,
@@ -83,7 +101,7 @@ class ServingEngine:
             config.validate()
         self.config = config
         self.engine = engine
-        self._fence_recurrent_state(engine, config)
+        self._fence_lane_end_state(engine, config)
         # fleet lane identity: the name build_fleet gave this replica (or
         # "serving" standalone) — stamped on every span so the fleet
         # aggregator can split the shared span ring into per-replica lanes

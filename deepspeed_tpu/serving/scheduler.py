@@ -319,6 +319,12 @@ class ContinuousBatchingScheduler:
                                 "enabled", False))
         self.pool = SlotPool(engine, config.num_slots, config.max_model_len,
                              quantize=quantize)
+        # a model with window layers: the columns a ring keeps of a lane,
+        # read off the pool's own leaf (``serve/kv_live``); 0 where every
+        # attending layer keeps them all
+        rings = getattr(getattr(engine, "module", None), "window_rings", ())
+        leaves = getattr(self.pool.cache, "q", self.pool.cache)
+        self._ring_window = int(leaves[rings[0]].shape[2]) if rings else 0
         #: admission queue: per-tenant FIFOs + deficit round-robin when
         #: the tenants block is on, a plain FIFO otherwise (deque API)
         self.queue = TenantQueues(getattr(config, "tenants", None))
@@ -982,6 +988,13 @@ class ContinuousBatchingScheduler:
                 self.pool.cache, toks, positions, temps,
                 top_ks=top_ks, top_ps=top_ps, seeds=seeds)
         self._record_routing("serve/moe_decode")
+        if self._ring_window:
+            # columns the step just read that hold a token of an active
+            # slot: over the full-length lanes, over the rings
+            live = positions[active] + 1
+            now = time.perf_counter_ns()
+            tr.record_phase("serve/kv_live", now, now, int(live.sum()),
+                            int(np.minimum(live, self._ring_window).sum()))
         dt = self.clock() - t0
         self.metrics.record_decode_step(dt, len(active))
         if self.cost is not None:

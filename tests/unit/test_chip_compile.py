@@ -384,3 +384,88 @@ def test_hybrid_stack_reads_every_leaf_in_place(one_chip, program):
         text, re.M)
     assert copied == [], copied
     assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
+
+
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_window_rings_are_written_and_read_in_place(one_chip, program):
+    """The slot decode step and a slot-prefill bucket (2048: eight blocks of
+    256 queries against their bands) of a K-EXAONE of
+    ``k-exaone-236b-a23b.serve-longdoc-16k``'s widths (hidden 6144, 64 heads
+    of 128 over 8 KV heads, 8 of 128 experts of 2048 held, 8 a token, a
+    shared expert; a dense window layer, a routed full layer and a routed
+    window layer; a narrow dense FFN and a small vocabulary), compiled for
+    the chip at the cell's pool, 48 slots x 16,384. The pool has a cache
+    shape per kind of layer: the full layer's lanes (1.6 GB a leaf) and the
+    window layers' rings of 128 columns (25 MB a leaf). The constraint is
+    the compiled program: every pool leaf is aliased to the output, no
+    instruction copies a lane slab or a ring leaf, no instruction produces
+    a layer's expert leaf, and the decode step's temporaries are its
+    scores over the one full layer (48 x 64 x 16,384 in bfloat16, 101 MB)
+    and little else. (The cell's own five layers read 107 MB for
+    ``jit_dec`` and 2.1 GB for its bucket-16,384 prefill, compiled the same
+    way: PERF.md, PR 38.)"""
+    import re
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.models.kexaone import (FULL, SLIDING, KExaoneConfig,
+                                              KExaoneModel)
+    from deepspeed_tpu.parallel import initialize_mesh
+
+    slots, max_len, bucket = 48, 16384, 2048
+    model = KExaoneModel(KExaoneConfig(
+        vocab_size=512, n_positions=max_len, n_layer=3, mlp_hidden=2048,
+        layer_types=(SLIDING, FULL, SLIDING), experts_held=(0, 8),
+        dtype="bfloat16"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len}),
+        mesh_manager=initialize_mesh(dp=1, devices=jax.devices()[:1]))
+    leaf = engine.params["blocks"]["moe"]["moe"]["experts"]["w_gate"]
+    assert leaf.shape == (2, 8, 6144, 2048) and leaf.dtype == jnp.bfloat16
+    tiny = engine.init_slot_pool(1, max_len)
+    assert {k: v.shape for k, v in tiny.items()} == {
+        "k": (1, 1, max_len, 1, 1024), "v": (1, 1, max_len, 1, 1024),
+        "wk": (2, 1, 128, 1, 1024), "wv": (2, 1, 128, 1, 1024)}
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    if program == "decode":
+        zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+        engine.slot_decode_step(tiny, zi, zi, zf)
+        fn = engine._slot_fns[("slot_decode", 1, max_len)]
+        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        first = len(jax.tree.leaves(params))
+        limit = 160 * 2 ** 20
+    else:
+        engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
+        fn = engine._slot_fns[("slot_prefill", 1, max_len)]
+        args = (params, on_chip((1, bucket), jnp.int32), pool, i32, i32,
+                f32, i32, f32, i32)
+        first = len(jax.tree.leaves(params)) + 1
+        # scores [64, 2048, 2048] in float32 and as probabilities, the mini
+        # cache of one lane (67 MB) and the rows of 2048 tokens
+        limit = 2 * 2 ** 30
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        *args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert donated_params_from_hlo(text) == set(range(first, first + 4))
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = bf16\[(?:48,16384,1024|1,48,16384,1,1024|"
+        r"2,48,128,1024|2,48,128,1,1024|48,128,1024|8,6144,2048|8,2048,6144)"
+        r"\]\S* (copy|transpose)\(", text, re.M)
+    assert copied == [], copied
+    assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
