@@ -1067,6 +1067,18 @@ class InferenceEngine:
 
         return self._pool_call(ins, lambda: (pool, lane, jnp.int32(slot)))
 
+    def decode_kernel_block(self, num_slots: int, max_len: int):
+        """The columns one block of the decode-attention kernel holds where
+        ``slot_decode_step`` over a pool of this shape takes it, else
+        ``None`` (``GPT2Model.decode_kernel_block``, asked under this
+        engine's mesh and of the pool as the step sees it: an int8 pool is
+        attended as its float copy)."""
+        model = self.module
+        with self.mesh:
+            return model.decode_kernel_block(jax.eval_shape(
+                lambda: model.init_kv_cache(num_slots, max_len,
+                                            dtype=self.dtype)))
+
     def slot_decode_step(self, pool, toks, positions, temps, top_ks=None,
                          top_ps=None, seeds=None):
         """One fused decode step over ALL slots: feed token ``toks[s]`` at

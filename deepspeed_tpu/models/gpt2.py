@@ -19,6 +19,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .api import ModelSpec
@@ -912,6 +913,44 @@ class GPT2Model(ModelSpec):
         None). ALiBi families override."""
         return None
 
+    def decode_kernel_block(self, cache):
+        """Whether a ``decode_with_slots`` step traced now over ``cache``
+        (``init_kv_cache``'s leaves, or their shapes) takes the Pallas
+        decode-attention kernel (``ops/pallas/decode_attention.py``), which
+        fetches a slot's live column blocks and no others: the columns one
+        such block holds, or ``None`` where the step contracts over the
+        whole slab (``_kv_attend``). Decided by what can be observed, here
+        and nowhere else (the serving scheduler's ``serve/kv_read`` rests on
+        the same answer):
+
+        - the family's mask over a lane is the plain causal one: no layer
+          extras (GPT-Neo's local layers), no additive bias (ALiBi), and the
+          query at the lane's last column keeps every column (a sliding
+          window does not);
+        - the program will run on a TPU, on one device: GSPMD cannot
+          partition a Mosaic kernel, and the pool may be sharded over
+          ``model``;
+        - the kernel takes the pool's stored rows
+          (``decode_attention.block_columns``)."""
+        from ..ops.pallas import decode_attention
+        from ..parallel.constraints import active_mesh
+        from ..parallel.topology import on_tpu
+        leaf = cache["k"]
+        max_len = leaf.shape[2]
+        mesh = active_mesh()
+        if not on_tpu() or (mesh is not None and mesh.devices.size > 1):
+            return None
+        if self._layer_extras() is not None:
+            return None
+        last = np.full((1, 1, 1, 1), max_len - 1)
+        cols = np.arange(max_len)[None, None, None, :]
+        with jax.ensure_compile_time_eval():
+            if self._decode_attn_bias(last, cols) is not None or \
+                    not np.all(self._decode_attn_mask(last, cols)):
+                return None
+        return decode_attention.block_columns(leaf.shape[3:], max_len,
+                                              leaf.dtype)
+
     @staticmethod
     def _state_shift(state, layer, rows, lengths=None):
         """Push ``rows`` [S, T, d] through layer ``layer`` of a recurrent
@@ -978,6 +1017,12 @@ class GPT2Model(ModelSpec):
             mask = self._decode_attn_mask_ex(q_pos, k_pos, extra)
             return mask if pad_valid is None else mask & pad_valid
 
+        # a decode step, one token a slot at a position of its own: the
+        # kernel that is told the lengths, where it takes this pool
+        kernel = t == 1 and jnp.ndim(start) != 0 and pad_counts is None \
+            and self.decode_kernel_block(cache) is not None
+        if kernel:
+            from ..ops.pallas.decode_attention import decode_attend
         # one piece: mask and bias are made once, outside the layers
         whole_mask = block == t and extras is None
         base_mask = keep_mask(None) if whole_mask else None
@@ -1018,6 +1063,10 @@ class GPT2Model(ModelSpec):
                     pool["v"] = self._kv_write(pool["v"], layer,
                                                v.transpose(0, 2, 1, 3), start)
                 with jax.named_scope("kv_read"):
+                    if kernel:
+                        return decode_attend(
+                            q[:, :, 0], pool["k"], pool["v"], layer,
+                            start + 1)[:, :, None]
                     return self._in_row_blocks(attend, block, 2, q, q_pos)[0]
 
             if self.recurrent_state:

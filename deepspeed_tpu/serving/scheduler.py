@@ -325,6 +325,12 @@ class ContinuousBatchingScheduler:
         rings = getattr(getattr(engine, "module", None), "window_rings", ())
         leaves = getattr(self.pool.cache, "q", self.pool.cache)
         self._ring_window = int(leaves[rings[0]].shape[2]) if rings else 0
+        # the columns a block of the decode-attention kernel fetches, where
+        # the decode program takes it; None where the step reads the whole
+        # pool (``serve/kv_read``)
+        ask = getattr(engine, "decode_kernel_block", None)
+        self._kv_read_block = ask(config.num_slots, config.max_model_len) \
+            if ask is not None else None
         #: admission queue: per-tenant FIFOs + deficit round-robin when
         #: the tenants block is on, a plain FIFO otherwise (deque API)
         self.queue = TenantQueues(getattr(config, "tenants", None))
@@ -995,6 +1001,15 @@ class ContinuousBatchingScheduler:
             now = time.perf_counter_ns()
             tr.record_phase("serve/kv_live", now, now, int(live.sum()),
                             int(np.minimum(live, self._ring_window).sum()))
+        # columns of one layer the step's attention just read, of the
+        # pool's: with the kernel every slot's live length in whole blocks
+        # (a free slot's one), with the XLA attend all of them
+        bk, max_len = self._kv_read_block, self.config.max_model_len
+        pool_cols = positions.size * max_len
+        read = pool_cols if not bk else bk * int(
+            ((np.minimum(positions + 1, max_len) + bk - 1) // bk).sum())
+        now = time.perf_counter_ns()
+        tr.record_phase("serve/kv_read", now, now, read, pool_cols)
         dt = self.clock() - t0
         self.metrics.record_decode_step(dt, len(active))
         if self.cost is not None:

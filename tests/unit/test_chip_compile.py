@@ -469,3 +469,131 @@ def test_window_rings_are_written_and_read_in_place(one_chip, program):
         r"\]\S* (copy|transpose)\(", text, re.M)
     assert copied == [], copied
     assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
+
+
+def _opt(max_len):
+    from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+    return OPTModel(OPTConfig(vocab_size=512, n_positions=max_len,
+                              n_embd=2048, n_layer=2, n_head=32,
+                              dtype="bfloat16"))
+
+
+def _olmoe(max_len):
+    from deepspeed_tpu.models.olmoe import OLMoEConfig, OLMoEModel
+    return OLMoEModel(OLMoEConfig(
+        vocab_size=512, n_positions=max_len, n_embd=2048, n_layer=2,
+        n_head=16, mlp_hidden=1024, num_experts=64, top_k=8,
+        dtype="bfloat16"))
+
+
+def _decode_program(model, max_len, tp=1):
+    """The engine's slot decode program of ``model`` (zero weights, built by
+    one call on a one-slot pool on the CPU, where it takes the XLA attend)
+    and the shapes of its parameters and of that pool."""
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.parallel import initialize_mesh
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len,
+             "tensor_parallel": {"tp_size": tp}}),
+        mesh_manager=initialize_mesh(tp=tp, devices=jax.devices()[:tp]))
+    tiny = engine.init_slot_pool(1, max_len)
+    zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+    tiny, _ = engine.slot_decode_step(tiny, zi, zi, zf)
+    return engine, engine._slot_fns[("slot_decode", 1, max_len)], tiny
+
+
+@pytest.mark.parametrize("cell,build,slots,max_len", [
+    ("opt-1.3b.serve-chat", _opt, 28, 1024),
+    ("opt-1.3b.serve-longprompt", _opt, 24, 2048),
+    ("olmoe-1b-7b.serve-chat-2k", _olmoe, 24, 2048),
+], ids=lambda v: v if isinstance(v, str) else "")
+def test_decode_step_takes_the_length_aware_kernel_in_place(
+        one_chip, monkeypatch, cell, build, slots, max_len):
+    """``jit_dec`` at the pools of the serving cells whose stored rows the
+    decode-attention kernel takes (two layers of the cell's widths, small
+    vocabulary), traced as on one TPU and compiled for the chip: a layer
+    body holds ONE Mosaic call (``decode_attend``; a routed model's grouped
+    matmuls are ``ragged-dot`` calls beside it), whose K and V operands are
+    the pool leaves as ``kv_write`` left them, so every leaf stays aliased
+    to the output and no slab is staged, re-laid or copied: the temporaries
+    stay under HALF a lane's bytes (0.7 MB at serve-chat's pool where the
+    XLA attend keeps 6.8 MB; a layout the custom call did not accept would
+    show as a copy of a layer's slab, 112 to 400 MB, or of the pool)."""
+    import re
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.parallel import topology
+    engine, fn, tiny = _decode_program(build(max_len), max_len)
+    assert tiny["k"].shape[3:] == (16, 128)
+    monkeypatch.setattr(topology, "on_tpu", lambda: True)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        params, pool, vi, vi, vf, vi, vf, vi).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    calls = re.findall(
+        r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", text, re.M)
+    ours = [c for c in calls if c.startswith("decode_attend")]
+    assert len(ours) == 1 and \
+        [c for c in calls if "ragged-dot" not in c] == ours, calls
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    lane = pool["k"].size * 2 // (pool["k"].shape[0] * slots)
+    assert mem.temp_size_in_bytes < lane // 2, mem.temp_size_in_bytes
+    first = len(jax.tree.leaves(params))
+    assert donated_params_from_hlo(text) == set(
+        range(first, first + len(pool)))
+    copied = re.findall(
+        rf"^\s*(?:ROOT )?%?\S+ = bf16\[(?:\d,)?{slots},{max_len},"
+        r"(?:16,128|2048)\]\S* (copy|transpose)\(", text, re.M)
+    assert copied == [], copied
+
+
+def test_decode_step_over_a_mesh_compiles_on_the_xla_attend(
+        topo, one_chip, monkeypatch):
+    """The same program with the pool's heads sharded four ways over
+    ``model`` (serve-chat's pool, tensor parallel 4), compiled for the
+    described 2x2 mesh: GSPMD cannot partition a Mosaic kernel, so under a
+    mesh of more than one device the cached forward keeps the XLA attend; no
+    ``tpu_custom_call`` is in the program, and it compiles."""
+    from jax.sharding import Mesh, NamedSharding
+    from deepspeed_tpu.parallel import topology
+    slots, max_len = 28, 1024
+    engine, fn, tiny = _decode_program(_opt(max_len), max_len, tp=4)
+    monkeypatch.setattr(topology, "on_tpu", lambda: True)
+    mesh = Mesh(np.array(topo.devices).reshape(engine.mesh.devices.shape),
+                engine.mesh.axis_names)
+
+    def moved(sh):
+        return None if sh is None else NamedSharding(mesh, sh.spec)
+
+    def like(x, sh, lead=None):
+        shape = x.shape if lead is None else (x.shape[0], lead) + x.shape[2:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=moved(sh))
+
+    pool_sh = engine._pool_shardings(slots, max_len)
+    assert pool_sh["k"].spec[3] == "model"
+    params = jax.tree.map(like, engine.params, engine.param_shardings)
+    pool = jax.tree.map(lambda x, sh: like(x, sh, slots), tiny, pool_sh)
+    rep = NamedSharding(mesh, jax.sharding.PartitionSpec())
+    vi = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=rep)
+    vf = jax.ShapeDtypeStruct((slots,), jnp.float32, sharding=rep)
+    with mesh:
+        compiled = jax.jit(
+            fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums,
+            out_shardings=(jax.tree.map(moved, pool_sh), None)).lower(
+            params, pool, vi, vi, vf, vi, vf, vi).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text         # the row-parallel matmuls' sum

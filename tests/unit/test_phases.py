@@ -155,10 +155,10 @@ def test_tick_with_one_prefill_holds_every_phase(engine, monkeypatch):
     want = {"serve/admit", "serve/queue_wait", "serve/prefill_prep",
             "serve/prefill_dispatch", "serve/prefill_wait",
             "serve/first_token", "serve/decode_prep",
-            "serve/decode_dispatch", "serve/decode_wait", "serve/deliver",
-            "serve/bookkeeping", "serve/tick"}
+            "serve/decode_dispatch", "serve/decode_wait", "serve/kv_read",
+            "serve/deliver", "serve/bookkeeping", "serve/tick"}
     assert {r[0] for r in recs} == want
-    assert 12 <= len(recs) <= 14
+    assert 13 <= len(recs) <= 15
     by = {}
     for r in recs:
         by.setdefault(r[0], []).append(r)
@@ -316,3 +316,27 @@ def test_serving_program_names_are_what_the_cell_files_read(engine):
     assert module(dec_text) == "jit_dec"
     assert re.search(patterns["prefill"], module(pf_text))
     assert re.search(patterns["decode"], module(dec_text))
+
+
+def test_kv_read_says_what_the_decode_attention_read(engine):
+    """``serve/kv_read``, an instant a decode tick: the columns of one layer
+    the step's attention read, of the pool's. A tick on the CPU takes the
+    XLA attend over the whole pool: all of them. Where the engine says its
+    decode program takes the decode-attention kernel (here: told so, with
+    blocks of 16 columns): every slot's live length in whole blocks and a
+    free slot's one block."""
+    assert engine.decode_kernel_block(2, 64) is None
+    srv = ServingEngine(engine, {"num_slots": 2, "max_model_len": 64})
+    mark = time.perf_counter_ns()
+    srv.submit(_prompt(17), SamplingParams(max_new_tokens=3))
+    srv.step()
+    read, = [r for r in _since(mark) if r[0] == "serve/kv_read"]
+    assert read[3:] == (2 * 64, 2 * 64) and read[1] == read[2]
+    srv.scheduler._kv_read_block = 16
+    mark = time.perf_counter_ns()
+    srv.step()
+    read, = [r for r in _since(mark) if r[0] == "serve/kv_read"]
+    # the request stands at position 18 (17 prompt tokens and one decoded):
+    # 19 live columns are two blocks; the free slot's position 0 is one
+    assert read[3:] == (32 + 16, 2 * 64)
+    srv.shutdown()
