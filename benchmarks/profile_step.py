@@ -143,15 +143,6 @@ def main():
     print(f"wall per global step (gas={gas}, bs={bs}, seq={seq}): "
           f"{wall*1e3:.1f} ms = {wall*1e3/gas:.2f} ms/micro")
     parse(trace_dir, gas)
-    # measured per-phase wall tree (named_scope attribution) — the same
-    # phases the flops profiler reports analytically
-    from deepspeed_tpu.profiling.flops_profiler import \
-        wall_fractions_from_trace
-    wf = wall_fractions_from_trace(trace_dir)
-    if wf:
-        print("\n== measured phase wall fractions ==")
-        for ph, frac in sorted(wf.items(), key=lambda kv: -kv[1]):
-            print(f"  {ph:10s} {100 * frac:5.1f}%")
     print("trace dir:", trace_dir)
 
 

@@ -32,7 +32,7 @@ from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..models.api import ModelSpec
-from ..telemetry.trace import get_tracer
+from ..telemetry.trace import avals_of, get_tracer
 from ..parallel.topology import (DeviceMeshManager, default_devices,
                                  initialize_mesh, get_mesh_manager)
 from ..runtime.zero.partition import ZeroShardingPlanner
@@ -737,6 +737,7 @@ class InferenceEngine:
                 donate_argnums=donated)
             fn.label = _POOL_LABELS.get(kind, kind)
             fn.arg_names = names
+            fn.key, fn.noted = key, False
             return fn
 
         return build
@@ -749,11 +750,25 @@ class InferenceEngine:
         prep_phase, dispatch_phase = phases
         with prep_phase:
             args = prep()
+            if not fn.noted:        # the program's first call
+                fn.noted = True
+                self._tracer.note_program("jit_" + fn.__name__, fn.key, fn,
+                                          avals_of(args), self.mesh)
             # observed BEFORE the call: the program donates its pool, so
             # its arguments can only be read while they are still live
             self._observe_compile(fn.label, fn, args, names=fn.arg_names)
         with dispatch_phase, self.mesh:
             return fn(*args)
+
+    def scope_tables(self):
+        """``{module name: {program key: {instruction name: scope}}}``: what
+        each instruction of the process's compiled programs is for, by the
+        ``named_scope`` words of ``telemetry.hlo_cost.SCOPES`` — the join of
+        a ``jax.profiler`` device trace to the program's own names
+        (docs/observability.md, "Device time by scope"). ``Tracer
+        .scope_tables`` by hand: builds the tables that are not built yet
+        (one cached compile each) and returns every one the tracer holds."""
+        return self._tracer.scope_tables()
 
     def slot_executables(self, kind: str, *dims,
                          quantized: Optional[bool] = None) -> int:
@@ -832,15 +847,18 @@ class InferenceEngine:
         @self._pool_program("slot_prefill", (bucket, max_len), shape, outs=1)
         def pf(params, ids, pool, slot, last_idx, temperature, top_k,
                top_p, seed):
-            mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
+            with jax.named_scope("kv_write"):   # the lane, empty
+                mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
             logits, mini, *stats = model.apply_with_cache(
                 params, ids, mini, jnp.int32(0), routing=self._routed,
                 **self._real_length(last_idx))
-            pool = write_lane(pool, mini, slot)
-            last = jnp.take(logits[0], last_idx, axis=0)
+            with jax.named_scope("kv_write"):
+                pool = write_lane(pool, mini, slot)
             # the first token is FED at column last_idx + 1
-            tok = _sample_one(last, temperature, top_k, top_p, seed,
-                              last_idx + 1, vocab)
+            with jax.named_scope("sample"):
+                last = jnp.take(logits[0], last_idx, axis=0)
+                tok = _sample_one(last, temperature, top_k, top_p, seed,
+                                  last_idx + 1, vocab)
             # routed experts: [token, touched, largest], one read-back
             return pool, \
                 jnp.concatenate([tok[None], *stats]) if stats else tok
@@ -950,13 +968,16 @@ class InferenceEngine:
         @self._pool_program("slot_suffix", (bucket, max_len), shape, outs=1)
         def spf(params, ids, pool, slot, start_pos, last_idx, temperature,
                 top_k, top_p, seed):
-            mini = self._lane_from(pool, slot, start_pos)
+            with jax.named_scope("kv_read"):
+                mini = self._lane_from(pool, slot, start_pos)
             logits, mini = model.apply_with_cache(
                 params, ids, mini, start_pos, **self._real_length(last_idx))
-            pool = write_lane(pool, mini, slot)
-            last = jnp.take(logits[0], last_idx, axis=0)
-            tok = _sample_one(last, temperature, top_k, top_p, seed,
-                              start_pos + last_idx + 1, vocab)
+            with jax.named_scope("kv_write"):
+                pool = write_lane(pool, mini, slot)
+            with jax.named_scope("sample"):
+                last = jnp.take(logits[0], last_idx, axis=0)
+                tok = _sample_one(last, temperature, top_k, top_p, seed,
+                                  start_pos + last_idx + 1, vocab)
             return pool, tok
 
         pool, tok = self._slot_prefill_call(
@@ -1008,10 +1029,12 @@ class InferenceEngine:
         @self._pool_program("slot_chunk", (num_slots, bucket, max_len),
                             shape)
         def cpf(params, ids, pool, slot, start_pos):
-            mini = self._lane_from(pool, slot, start_pos)
+            with jax.named_scope("kv_read"):
+                mini = self._lane_from(pool, slot, start_pos)
             mini = model.chunk_prefill_with_cache(params, ids, mini,
                                                   start_pos)
-            return write_lane(pool, mini, slot)
+            with jax.named_scope("kv_write"):
+                return write_lane(pool, mini, slot)
 
         return self._slot_prefill_call(cpf, pool, slot, tokens, bucket,
                                        (np.int32(start_pos),))

@@ -243,8 +243,11 @@ class GPT2Model(ModelSpec):
         cfg = self.config
         b, t, d = x.shape
         h, hd = cfg.n_head, cfg.head_dim
-        ln1 = _layer_norm(x, p["ln1_scale"], p["ln1_bias"], cfg.layer_norm_epsilon)
-        qkv = ln1 @ p["qkv_w"].astype(ln1.dtype) + p["qkv_b"].astype(ln1.dtype)
+        with jax.named_scope("qkv"):
+            ln1 = _layer_norm(x, p["ln1_scale"], p["ln1_bias"],
+                              cfg.layer_norm_epsilon)
+            qkv = ln1 @ p["qkv_w"].astype(ln1.dtype) + \
+                p["qkv_b"].astype(ln1.dtype)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         bias = None if attn_fn is not None else self._train_attn_bias_ex(
             t, extra)
@@ -264,9 +267,10 @@ class GPT2Model(ModelSpec):
                 lambda q, k, v, n: packed_flash_attention(
                     q, k, v, n, interpret=interpret),
                 q, k, v, h, packed=True)
-            attn = attn @ p["attn_proj_w"].astype(attn.dtype) + \
-                p["attn_proj_b"].astype(attn.dtype)
-            return x + self._dropout(attn, rng, train, 0)
+            with jax.named_scope("out_proj"):
+                attn = attn @ p["attn_proj_w"].astype(attn.dtype) + \
+                    p["attn_proj_b"].astype(attn.dtype)
+                return x + self._dropout(attn, rng, train, 0)
         q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         k = k.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
         v = v.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
@@ -281,9 +285,11 @@ class GPT2Model(ModelSpec):
                                 dropout_rng=drop_rng, impl=cfg.sp_attention,
                                 backend=cfg.attn_backend,
                                 bias=bias)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-        attn = attn @ p["attn_proj_w"].astype(attn.dtype) + p["attn_proj_b"].astype(attn.dtype)
-        return x + self._dropout(attn, rng, train, 0)
+        with jax.named_scope("out_proj"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+            attn = attn @ p["attn_proj_w"].astype(attn.dtype) + \
+                p["attn_proj_b"].astype(attn.dtype)
+            return x + self._dropout(attn, rng, train, 0)
 
     def _packed_attn_ok(self, t: int, hd: int, h: int) -> bool:
         """Packed-layout Pallas attention eligibility: TPU pallas backend,
@@ -322,9 +328,11 @@ class GPT2Model(ModelSpec):
         ``routed``: ``stacked=(whole, layer)`` from a serving scan whose
         family left leaves whole (``_scan_split``), for its MLP sublayer.
 
-        named_scope phases feed the flops profiler's per-phase attribution
-        (and label the XLA fusions in device traces) — they cost nothing at
-        runtime."""
+        The ``named_scope`` words (``telemetry.hlo_cost.SCOPES``) feed the
+        flops profiler's per-phase attribution and survive into the
+        compiled program's ``op_name`` metadata, where
+        ``hlo_cost.scope_table`` reads them to name a device trace's
+        operations — they cost nothing at runtime."""
         with jax.named_scope("attn"):
             x = self._attn_sublayer(x, layer_params, rng, train, extra=extra)
         with jax.named_scope("mlp"):
@@ -492,10 +500,11 @@ class GPT2Model(ModelSpec):
             from ..runtime.activation_checkpointing.checkpointing import \
                 get_policy
             body_fn = jax.checkpoint(body, policy=get_policy(cfg.remat_policy))
-        (x, _, aux_total), _ = self._scan_layers(
-            body_fn, (x, 0, jnp.float32(0.0)), blocks,
-            unroll=min(max(1, int(getattr(cfg, "scan_unroll", 1))),
-                       cfg.n_layer))
+        with jax.named_scope("layers"):
+            (x, _, aux_total), _ = self._scan_layers(
+                body_fn, (x, 0, jnp.float32(0.0)), blocks,
+                unroll=min(max(1, int(getattr(cfg, "scan_unroll", 1))),
+                           cfg.n_layer))
 
         x = self._final_norm(params, x)
         return x, aux_total / cfg.n_layer, \
@@ -615,7 +624,8 @@ class GPT2Model(ModelSpec):
                        (cfg.loss_chunking == "auto" and
                         n_logits > self._DENSE_LOSS_MAX_ELEMS))
         if use_chunked:
-            return self._chunked_lm_loss(x, wte, batch, head_b=head_b)
+            with jax.named_scope("loss"):
+                return self._chunked_lm_loss(x, wte, batch, head_b=head_b)
         logits = x @ wte.T
         if head_b is not None:
             logits = logits + head_b
@@ -1002,8 +1012,9 @@ class GPT2Model(ModelSpec):
             start_pos = start
             positions = None if pad_counts is None else \
                 jnp.maximum(cols - pad_counts[:, None], 0)
-        x = self._embed(params, input_ids, start_pos=start_pos,
-                        positions=positions)
+        with jax.named_scope("embed"):
+            x = self._embed(params, input_ids, start_pos=start_pos,
+                            positions=positions)
         block = self._query_block(t, self.config.n_head, max_len)
         k_pos = jnp.arange(max_len)[None, None, None, :]
         pad_valid = None
@@ -1046,12 +1057,13 @@ class GPT2Model(ModelSpec):
                                        mask, bias), None
 
             def cached_attn(q, k, v, ring=None):
-                # q, k, v arrive [S, H, T, hd]. kv_write / kv_read scopes
-                # nest inside "attn" and take precedence in the perf
-                # plane's bucket classifier, so cache traffic is
-                # attributed as bytes, not attention math. ``ring``: a
-                # window layer names its two ring leaves of the pool
-                # (``_window_attend``) in place of ``k`` and ``v``
+                # q, k, v arrive [S, H, T, hd]. The kv_write / kv_read
+                # scopes nest inside "attn": a device trace's reader
+                # (``hlo_cost.scope_table``) sees the cache's writes and
+                # the attend over it apart from the projections around
+                # them. ``ring``: a window layer names its two ring
+                # leaves of the pool (``_window_attend``) in place of
+                # ``k`` and ``v``
                 if ring is not None:
                     out, pool[ring[0]], pool[ring[1]] = self._window_attend(
                         q, k, v, pool[ring[0]], pool[ring[1]], layer, start,
@@ -1081,13 +1093,15 @@ class GPT2Model(ModelSpec):
                 positions=positions, extra=extra, **routed))
             return (x, pool), stats
 
-        (x, pool), stats = self._scan_layers(body, (x, dict(cache)), blocks,
-                                             indexed=True)
-        x = self._final_norm(params, x)
-        logits = x @ self._unembed_weight(params, compute_dtype).T
-        head_b = self._head_bias(params, logits.dtype)
-        if head_b is not None:
-            logits = logits + head_b
+        with jax.named_scope("layers"):
+            (x, pool), stats = self._scan_layers(body, (x, dict(cache)),
+                                                 blocks, indexed=True)
+        with jax.named_scope("head"):
+            x = self._final_norm(params, x)
+            logits = x @ self._unembed_weight(params, compute_dtype).T
+            head_b = self._head_bias(params, logits.dtype)
+            if head_b is not None:
+                logits = logits + head_b
         return self._cache_return(logits, pool, stats, routing)
 
     def apply_with_cache(self, params, input_ids, cache, start_pos,

@@ -178,7 +178,8 @@ class KExaoneModel(KindStacks, LlamaModel):
         b, t, _ = x.shape
         h, hk, hd = cfg.n_head, cfg.kv_head_count, cfg.head_dim
         eps = cfg.layer_norm_epsilon
-        qkv = x @ p["qkv_w"].astype(x.dtype)
+        with jax.named_scope("qkv"):
+            qkv = x @ p["qkv_w"].astype(x.dtype)
         q, k, v = jnp.split(qkv, [h * hd, (h + hk) * hd], axis=-1)
         q = _rms_norm(q.reshape(b, t, h, hd), p["q_norm_scale"], eps)
         k = _rms_norm(k.reshape(b, t, hk, hd), p["k_norm_scale"], eps)
@@ -200,9 +201,10 @@ class KExaoneModel(KindStacks, LlamaModel):
                     q, k, v, causal=True, impl=cfg.sp_attention,
                     backend=cfg.attn_backend,
                     window=cfg.sliding_window if window else None)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
-        attn = attn @ p["attn_proj_w"].astype(attn.dtype)
-        return x + _rms_norm(attn, p["post_attn_scale"], eps)
+        with jax.named_scope("out_proj"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
+            attn = attn @ p["attn_proj_w"].astype(attn.dtype)
+            return x + _rms_norm(attn, p["post_attn_scale"], eps)
 
     def _dense_ffn(self, x, p):
         g = x @ p["gate_w"].astype(x.dtype)

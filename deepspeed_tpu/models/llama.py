@@ -153,8 +153,9 @@ class LlamaModel(GPT2Model):
         cfg = self.config
         b, t, d = x.shape
         h, hk, hd = cfg.n_head, cfg.kv_head_count, cfg.head_dim
-        ln1 = _rms_norm(x, p["ln1_scale"], cfg.layer_norm_epsilon)
-        qkv = ln1 @ p["qkv_w"].astype(ln1.dtype)
+        with jax.named_scope("qkv"):
+            ln1 = _rms_norm(x, p["ln1_scale"], cfg.layer_norm_epsilon)
+            qkv = ln1 @ p["qkv_w"].astype(ln1.dtype)
         q, k, v = jnp.split(qkv, [h * hd, (h + hk) * hd], axis=-1)
         q, k = self._qk_norm(q, k, p)
         q = q.reshape(b, t, h, hd).transpose(0, 2, 1, 3)
@@ -178,9 +179,10 @@ class LlamaModel(GPT2Model):
                                 impl=cfg.sp_attention,
                                 backend=cfg.attn_backend,
                                 window=cfg.sliding_window)
-        attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
-        attn = attn @ p["attn_proj_w"].astype(attn.dtype)
-        return x + self._dropout(attn, rng, train, 0)
+        with jax.named_scope("out_proj"):
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+            attn = attn @ p["attn_proj_w"].astype(attn.dtype)
+            return x + self._dropout(attn, rng, train, 0)
 
     def _mlp_sublayer(self, x, p, rng, train):
         cfg = self.config

@@ -271,25 +271,24 @@ def topk_route(logits: jnp.ndarray, k: int,
     DeepSeek-V3's router, LFM2's ``use_expert_bias``); ``renorm_eps`` is
     added to the sum the picks are divided by (``None``: the sum is held
     over float32's epsilon instead)."""
-    logits = logits.astype(jnp.float32)
-    if score == "softmax":
-        gates = jax.nn.softmax(logits, axis=-1)
-    elif score == "sigmoid":
-        gates = jax.nn.sigmoid(logits)
-    else:
+    if score not in ("softmax", "sigmoid"):
         raise ValueError(f"unknown router score {score!r}")
-    if select_bias is None:
-        w, idx = lax.top_k(gates, k)
-    else:
-        _, idx = lax.top_k(gates + select_bias.astype(jnp.float32), k)
-        w = jnp.take_along_axis(gates, idx, axis=-1)
-    if renormalize is None:
-        renormalize = k > 1
-    if renormalize:
-        total = jnp.sum(w, axis=-1, keepdims=True)
-        w = w / (jnp.maximum(total, jnp.finfo(jnp.float32).eps)
-                 if renorm_eps is None else total + renorm_eps)
-    return w, idx.astype(jnp.int32)
+    with jax.named_scope("router"):
+        logits = logits.astype(jnp.float32)
+        gates = jax.nn.softmax(logits, axis=-1) if score == "softmax" \
+            else jax.nn.sigmoid(logits)
+        if select_bias is None:
+            w, idx = lax.top_k(gates, k)
+        else:
+            _, idx = lax.top_k(gates + select_bias.astype(jnp.float32), k)
+            w = jnp.take_along_axis(gates, idx, axis=-1)
+        if renormalize is None:
+            renormalize = k > 1
+        if renormalize:
+            total = jnp.sum(w, axis=-1, keepdims=True)
+            w = w / (jnp.maximum(total, jnp.finfo(jnp.float32).eps)
+                     if renorm_eps is None else total + renorm_eps)
+        return w, idx.astype(jnp.int32)
 
 
 class TopKGate:

@@ -16,13 +16,12 @@ compiled train step and prints/writes the report (reference
 engine.py:1646-1664 start/stop wiring).
 """
 
-import math
-import re
 from typing import Any, Dict, Optional
 
 import jax
 import numpy as np
-from jax import core
+
+from ..telemetry.hlo_cost import SCOPES, scope_words
 
 
 def _prod(xs):
@@ -66,27 +65,24 @@ _REDUCE = {"reduce_sum", "reduce_max", "reduce_min", "reduce_prod",
            "cumlogsumexp", "cummax"}
 
 
-#: model phases recognised in named_scope stacks (models/gpt2.py _block
-#: et al. annotate these; reference profiler.py:239 prints the torch
+#: the named_scope words recognised in name stacks: the one vocabulary the
+#: models and the engines set (reference profiler.py:239 prints the torch
 #: module tree — the phase tree is the jax equivalent, since there is no
 #: module hierarchy at trace time, only the name stack)
-PHASES = ("embed", "attn", "mlp", "moe", "head")
-
-
-#: token-boundary match: under autodiff the stack segments are wrapped
-#: ('jvp(attn)', 'transpose(jvp(mlp))'), and raw substring search would
-#: misfire on identifiers like 'num_heads'/'embedding'
-_PHASE_RE = re.compile(
-    r"(?<![A-Za-z0-9_])(" + "|".join(PHASES) + r")(?![A-Za-z0-9_])")
+PHASES = SCOPES
 
 
 def _phase_of(eqn) -> str:
+    """The equation's outermost scope word, looking through ``layers`` (the
+    layer scan, which holds ``attn`` and ``mlp``) to the word below it."""
     try:
         stack = str(eqn.source_info.name_stack)
     except Exception:
         return "other"
-    m = _PHASE_RE.search(stack)
-    return m.group(1) if m else "other"
+    words = scope_words(stack)
+    if words[:1] == ["layers"] and len(words) > 1:
+        words = words[1:]
+    return words[0] if words else "other"
 
 
 def jaxpr_flops(jaxpr, breakdown: Optional[Dict[str, int]] = None,
@@ -194,10 +190,11 @@ class FlopsProfiler:
                latency_s: Optional[float] = None, top: int = 10,
                wall_fractions: Optional[Dict[str, float]] = None) -> str:
         """Reference-style tree report (profiler.py:239 prints the torch
-        module tree; the phase tree is the jax equivalent). When a device
-        trace is available, pass ``wall_fractions`` from
-        :func:`wall_fractions_from_trace` for MEASURED per-phase wall —
-        otherwise the wall column is flops-proportional and labelled so."""
+        module tree; the phase tree is the jax equivalent). Pass
+        ``wall_fractions`` ({phase: share}, e.g. summed from a device trace
+        joined to ``engine.scope_tables()``: docs/observability.md) for
+        MEASURED per-phase wall — otherwise the wall column is
+        flops-proportional and labelled so."""
         if not wall_fractions:
             wall_fractions = None   # {} = no trace found: honest fallback
         lines = ["-" * 60, "deepspeed_tpu flops profiler", "-" * 60]
@@ -243,53 +240,6 @@ class FlopsProfiler:
             lines.append(f"  {name:<28} {_num_to_string(fl):>12}  {pct:5.1f}%")
         lines.append("-" * 60)
         return "\n".join(lines)
-
-
-def wall_fractions_from_trace(trace_dir: str) -> Dict[str, float]:
-    """Measured per-phase wall fractions from a ``jax.profiler`` trace.
-
-    XLA op/fusion names carry the named_scope stack of their constituent
-    HLOs, so device self-time can be attributed to the same phases the
-    analytic tree uses. Returns {} when no trace is found (callers fall
-    back to flops-proportional wall)."""
-    import glob
-    import gzip
-    import json
-    import os
-
-    files = glob.glob(os.path.join(trace_dir, "**", "*.trace.json.gz"),
-                      recursive=True)
-    if not files:
-        return {}
-    with gzip.open(sorted(files)[-1], "rt") as f:
-        data = json.load(f)
-    events = data.get("traceEvents", [])
-    tid_names = {(e["pid"], e["tid"]): e["args"]["name"] for e in events
-                 if e.get("ph") == "M" and e.get("name") == "thread_name"}
-    per_phase: Dict[str, float] = {}
-    total = 0.0
-    for e in events:
-        if e.get("ph") != "X" or \
-                tid_names.get((e["pid"], e["tid"])) != "XLA Ops":
-            continue
-        dur = float(e.get("dur", 0.0))
-        # fusion names don't always carry the scope; the event metadata
-        # (args: long_name / tf_op / hlo metadata) usually does. Token-
-        # boundary match so 'num_heads'/'embedding' don't misattribute to
-        # 'head'/'embed'; XLA fuses across scope boundaries, so a fusion
-        # matching several phases splits its time evenly between them
-        # rather than crediting whichever token appears first.
-        hay = e.get("name", "") + " " + " ".join(
-            str(v) for v in (e.get("args") or {}).values())
-        found = sorted(set(_PHASE_RE.findall(hay)))
-        if not found:
-            found = ["other"]
-        for ph in found:
-            per_phase[ph] = per_phase.get(ph, 0.0) + dur / len(found)
-        total += dur
-    if total <= 0:
-        return {}
-    return {ph: d / total for ph, d in per_phase.items()}
 
 
 def get_model_profile(model, batch, rng=None) -> Dict[str, Any]:

@@ -38,7 +38,8 @@ from ..accelerator import get_accelerator
 from ..comm.logging import configure_comms_logger
 from ..models.api import ModelSpec
 from ..parallel.topology import initialize_mesh, default_devices
-from ..telemetry.trace import RecompileWatchdog, configure_tracer
+from ..telemetry.trace import (RecompileWatchdog, avals_of,
+                               configure_tracer)
 from ..utils.logging import logger, log_dist
 from ..utils.timer import (SynchronizedWallClockTimer, ThroughputTimer,
                            FORWARD_GLOBAL_TIMER, BACKWARD_GLOBAL_TIMER,
@@ -826,9 +827,11 @@ class DeepSpeedEngine:
                 lsum, gsum, gas = accum_grads(params, scaler_state, batch,
                                               rng, pld_theta, ltd_keep,
                                               loss_mul)
-                new_params, new_opt, new_scaler, finite, grad_norm, applied \
-                    = self._apply_update(params, opt_state, scaler_state,
-                                         gsum, lr, denom=jnp.float32(gas))
+                with jax.named_scope("optimizer"):
+                    new_params, new_opt, new_scaler, finite, grad_norm, \
+                        applied = self._apply_update(
+                            params, opt_state, scaler_state, gsum, lr,
+                            denom=jnp.float32(gas))
                 metrics = {
                     "loss": lsum / (gas * scaler_state.scale),
                     "grad_norm": grad_norm,
@@ -1270,6 +1273,13 @@ class DeepSpeedEngine:
                     fn, (self.params, self.opt_state, self.scaler_state,
                          batch, lr, rng, theta, loss_mul))
                 with tr.phase("train/dispatch"):
+                    if not getattr(fn, "noted", False):     # its first call
+                        fn.noted = True
+                        tr.note_program(
+                            "jit_train_step", ("train", keep), fn,
+                            avals_of((self.params, self.opt_state,
+                                      self.scaler_state, batch, lr, rng,
+                                      theta, loss_mul)), self.mesh)
                     cp_ev = self._observe_compile(
                         "train_batch", fn,
                         (self.params, self.opt_state, self.scaler_state,
@@ -1905,6 +1915,20 @@ class DeepSpeedEngine:
         if release_ledger:
             from ..telemetry.goodput import configure_ledger
             configure_ledger(enabled=False)
+        # last: where a jax.profiler trace was taken, what each instruction
+        # of the step is for outlives the engine, with the tracer
+        self.tracer.keep_tables()
+
+    def scope_tables(self):
+        """``{module name: {program key: {instruction name: scope}}}``: what
+        each instruction of the compiled train step is for, by pass
+        (``forward``, ``remat``, ``backward``) and by the ``named_scope``
+        words of ``telemetry.hlo_cost.SCOPES`` — the join of a
+        ``jax.profiler`` device trace to the program's own names
+        (docs/observability.md, "Device time by scope"). ``Tracer
+        .scope_tables`` by hand: builds the tables that are not built yet
+        (one cached compile each) and returns every one the tracer holds."""
+        return self.tracer.scope_tables()
 
     def _health_check(self):
         """Training liveness: unhealthy once a preemption signal latched

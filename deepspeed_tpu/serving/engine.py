@@ -565,6 +565,9 @@ class ServingEngine:
                                                # InferenceEngine
         self.tracer.release_counters(self)
         self.tracer.unwatch_gc(self)
+        # last: where a jax.profiler trace was taken, what each instruction
+        # of the pool programs is for outlives the engine, with the tracer
+        self.tracer.keep_tables()
 
     def _traces_in_flight(self):
         """Trace ids of every request still moving through THIS replica

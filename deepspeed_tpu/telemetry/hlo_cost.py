@@ -42,6 +42,11 @@ Contents:
   of floats with python-identifier keys.
 - ``memory_summary(stats)`` — normalize a ``memory_analysis()``
   ``CompiledMemoryStats`` to a plain dict of the ``*_in_bytes`` fields.
+- ``SCOPES`` — the one vocabulary of ``jax.named_scope`` words the models
+  and the engines set, and ``scope_table(hlo_text)`` — {instruction name:
+  scope} of a compiled program, read off the ``op_name`` metadata its
+  optimized HLO keeps: what a device trace's "XLA Ops" events are joined
+  to (``Tracer.scope_tables``, docs/observability.md).
 
 This module is deliberately standalone — stdlib-only, no package
 imports — so ``benchmarks/hlo_audit.py`` can load it by file path before
@@ -53,7 +58,9 @@ import math
 import re
 from typing import Any, Dict, Optional
 
-__all__ = ["DTYPE_BYTES", "COLLECTIVES", "collect_collectives",
+__all__ = ["DTYPE_BYTES", "COLLECTIVES", "SCOPES", "PASSES", "scope_words",
+           "scope_table",
+           "collect_collectives",
            "collect_async", "collect_schedule_overlap",
            "collect_replica_groups", "module_num_partitions",
            "hlo_overlap_summary", "cost_summary", "memory_summary"]
@@ -66,6 +73,20 @@ DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4,
 #: the collective-op vocabulary the audit and the overlap analyzer track
 COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
                "collective-permute")
+
+#: every word ``jax.named_scope`` is given under models/, moe/, the inference
+#: engine and the train engine, outermost first where they nest
+#: (tests/unit/test_scope_table.py walks the sources). ``layers`` is the
+#: layer scan: what reads ``layers`` and no deeper word is the scan's own
+#: slicing of the stacked leaves.
+SCOPES = ("embed", "layers", "attn", "qkv", "out_proj", "kv_write",
+          "kv_read", "attend_window", "attend_full", "mlp", "dense_mlp",
+          "moe", "router", "moe_experts", "shared_expert", "conv", "head",
+          "loss", "sample", "verify", "optimizer")
+
+#: the pass of a differentiated program an instruction belongs to, by how
+#: autodiff wraps the name stack; put in front of the scope
+PASSES = ("forward", "remat", "backward")
 
 _PAT_SINGLE = re.compile(
     r"=\s*(\w+)\[([\d,]*)\]\S*\s+(" + "|".join(COLLECTIVES) + r")\(")
@@ -460,3 +481,197 @@ def memory_summary(stats: Any) -> Optional[Dict[str, int]]:
             continue                     # host fields are usually all-zero
         out[attr[:-len("_size_in_bytes")]] = val
     return out or None
+
+
+#: token-bounded, as the flops profiler matches its phases: under autodiff
+#: the stack's segments are wrapped ('jvp(attn)', 'transpose(jvp(mlp))'), and
+#: a plain substring would take 'num_heads' for 'head'
+_SCOPE_RE = re.compile(
+    r"(?<![A-Za-z0-9_])(" + "|".join(SCOPES) + r")(?![A-Za-z0-9_])")
+_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_FUSION_RE = re.compile(r"\sfusion\(")
+_APPLIED_RE = re.compile(
+    r"(?:to_apply=|called_computations=\{)(%?[\w.\-]+)")
+#: instructions the compiler emits no code for: never an event in a trace
+_FREE_RE = re.compile(
+    r"\s(parameter|constant|get-tuple-element|tuple|bitcast)\(")
+
+
+def scope_words(name_stack: str) -> list:
+    """The ``SCOPES`` words of a name stack or an ``op_name``, in order."""
+    return _SCOPE_RE.findall(name_stack)
+
+
+def _name_of(line: str) -> str:
+    """``ROOT %fusion.3 = bf16[...] fusion(...)`` -> ``fusion.3``."""
+    head = line.split(" = ", 1)[0]
+    return (head[5:] if head.startswith("ROOT ") else head).lstrip("%")
+
+
+def _scope_of(op_name: str) -> Optional[str]:
+    """``jit(f)/transpose(jvp(layers))/while/body/attn/qkv/dot_general`` ->
+    ``backward/layers/attn/qkv``: the pass the name stack shows, then its
+    ``SCOPES`` words in order; ``None`` where it shows neither."""
+    if "rematted_computation" in op_name:
+        parts = ["remat"]
+    elif "transpose(jvp(" in op_name:
+        parts = ["backward"]
+    elif "jvp(" in op_name:
+        parts = ["forward"]
+    else:
+        parts = []
+    parts += scope_words(op_name)
+    return "/".join(parts) or None
+
+
+def scope_table(hlo_text: str) -> Dict[str, Optional[str]]:
+    """{instruction name: scope} of a compiled module's optimized HLO
+    (``compiled.as_text()``), for every instruction of a computation that is
+    neither a fusion's body nor applied an element by a reduce or a sort:
+    the entry, ``while`` bodies and conditions, called computations. Those
+    are what a device trace's "XLA Ops" line has events for, under these
+    names; parameters, constants, tuples and bitcasts, for which the
+    compiler emits no code, are left out. A fusion inside a fusion's body
+    is listed too (the trace nests its event in the outer one's).
+
+    The scope is the ``SCOPES`` words of the instruction's ``op_name``
+    metadata in order, joined by ``/``, behind the pass where the name stack
+    shows one (``PASSES``). An instruction whose own ``op_name`` holds no
+    word (a fusion whose root carries no metadata) takes the commonest scope
+    with a word among the instructions of the computation it ``calls=``,
+    nested fusions included; a nested fusion with neither, that of the
+    fusion around it. Those are the program's own names.
+
+    What is left carries no name anywhere: what the compiler put in (a
+    prefetch's ``copy-start``/``copy-done``, a re-laying ``copy``, the
+    pieces a cumulative sum is expanded into) or renamed (the Mosaic kernel
+    a ``ragged_dot`` becomes reads ``op_name="ragged-dot-none"``). Such an
+    instruction is given what the instructions that use its result share
+    (``_shared``), else what its operands share, and the scope is marked
+    INFERRED by a leading ``?`` (``?layers/moe``): a reader adds its time
+    under the scope and counts it apart. With neither it reads its pass
+    alone, or ``None``."""
+    comps = {name.lstrip("%"): block
+             for name, block in _parse_computations(hlo_text).items()}
+
+    def called(line):
+        m = _CALLS_RE.search(line)
+        return m.group(1).lstrip("%") if m else None
+
+    # no events of their own: a fusion's body, and what a reduce, a sort, a
+    # scatter or a custom call's top-k applies an element (``to_apply=``,
+    # ``called_computations=``; a ``call`` runs its own)
+    inner = {called(line) for block in comps.values() for line in block
+             if _FUSION_RE.search(line)}
+    inner |= {name.lstrip("%") for block in comps.values()
+              for line in block if " call(" not in line
+              for name in _APPLIED_RE.findall(line)}
+
+    def worded(scope):
+        return scope is not None and scope.lstrip("?") not in PASSES
+
+    inside: Dict[str, Optional[str]] = {}
+
+    def commonest(cname, seen=()):
+        """The commonest worded scope among a called computation's
+        instructions; the first met wins a tie."""
+        if cname in inside:
+            return inside[cname]
+        votes: Dict[str, int] = {}
+        for line in comps.get(cname, ()):
+            scope = named(line, seen + (cname,))
+            if worded(scope):
+                votes[scope] = votes.get(scope, 0) + 1
+        inside[cname] = max(votes, key=votes.get) if votes else None
+        return inside[cname]
+
+    def named(line, seen=()):
+        """The scope the program's own names give an instruction: its
+        ``op_name``'s (a merged instruction lists its sources' names: the
+        first is whole), else its called computation's commonest."""
+        m = _OP_NAME_RE.search(line)
+        scope = _scope_of(m.group(1).split(";")[0]) if m else None
+        if not worded(scope) and called(line) not in seen + (None,):
+            scope = commonest(called(line), seen) or scope
+        return scope
+
+    table: Dict[str, Optional[str]] = {}
+    bodies = []                 # (a listed fusion's body, the fusion's name)
+    for cname, block in comps.items():
+        if cname in inner:
+            continue
+        mine: Dict[str, Optional[str]] = {}
+        free, operands = {}, {}
+        for line in block:
+            name = _name_of(line)
+            mine[name] = named(line)
+            kind = _FREE_RE.search(line)
+            if kind:
+                free[name] = kind.group(1)
+            elif _FUSION_RE.search(line):
+                bodies.append((called(line), name))
+            operands[name] = [t.lstrip("%") for t in _NAME_TOKEN_RE.findall(
+                line.split(" = ", 1)[1])]
+        users: Dict[str, list] = {}
+        for name, ops in operands.items():
+            ops[:] = [o for o in ops if o in mine and o != name]
+            for o in ops:
+                users.setdefault(o, []).append(name)
+        # the unnamed from their neighbours, until nothing moves: first from
+        # what uses them alone, so that a chain of them is named from its
+        # far end, and only what that leaves from their operands too. A
+        # get-tuple-element or a bitcast hands a scope on either way; a
+        # tuple only back to what it packs (a loop names what is packed for
+        # it, its result names nothing); a parameter or a constant stands
+        # for no scope
+        for sides in ((users,), (users, operands)):
+            moved = True
+            while moved:
+                moved = False
+                for name, scope in mine.items():
+                    if worded(scope) or free.get(name) in ("parameter",
+                                                           "constant"):
+                        continue
+                    for side in sides:
+                        if side is operands and free.get(name) == "tuple":
+                            continue
+                        near = _shared([mine[n].lstrip("?")
+                                        for n in side.get(name, ())
+                                        if worded(mine[n])])
+                        # a forward instruction is not renamed by the
+                        # backward ones that use it
+                        if near and (not scope
+                                     or near.split("/")[0] == scope):
+                            mine[name], moved = "?" + near, True
+                            break
+        table.update((n, s) for n, s in mine.items() if n not in free)
+    while bodies:
+        cname, around = bodies.pop()
+        for line in comps.get(cname, ()):
+            if _FUSION_RE.search(line):
+                scope = named(line)
+                table[_name_of(line)] = scope if worded(scope) \
+                    else table[around]
+                bodies.append((called(line), _name_of(line)))
+    return table
+
+
+def _shared(scopes) -> Optional[str]:
+    """What every one of ``scopes`` begins with, in whole words: the leading
+    ``SCOPES`` words they have in common, behind the pass where each has
+    the same one (``backward`` where some have that: a backward loop's body
+    holds remat's forward beside it). ``None`` where they share no word."""
+    split = [s.split("/") for s in scopes]
+    passes = [p[0] for p in split if p[0] in PASSES]
+    words = [p[1:] if p[0] in PASSES else p for p in split]
+    lead = []
+    for column in zip(*words):
+        if len(set(column)) > 1:
+            break
+        lead.append(column[0])
+    if not lead:
+        return None
+    if len(passes) == len(split) and (len(set(passes)) == 1
+                                      or "backward" in passes):
+        lead.insert(0, "backward" if "backward" in passes else passes[0])
+    return "/".join(lead)

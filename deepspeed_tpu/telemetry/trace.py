@@ -30,6 +30,12 @@ Four record kinds:
   trace is being taken each is also a ``dstpu/<name>`` annotation on the
   profiler's clock.
 
+Beside the rings the tracer keeps a registry of the process's compiled
+programs (``note_program``, at each program's first call) and, built when
+asked, each one's scope table (``scope_tables``; telemetry/hlo_cost.py): what
+joins a ``jax.profiler`` device trace's operations to the program's own
+``named_scope`` words.
+
 Disabled is the default and costs nothing: ``span()`` returns a shared
 no-op singleton — no ``Span`` object is ever allocated (asserted by
 tests/unit/test_telemetry.py). Counters and phase records stay live
@@ -46,10 +52,12 @@ import itertools
 import os
 import threading
 import time
+import weakref
+from contextlib import nullcontext
 from typing import Any, Dict, List, Optional, Tuple
 
 __all__ = ["Span", "Tracer", "RecompileWatchdog", "get_tracer",
-           "configure_tracer"]
+           "configure_tracer", "avals_of"]
 
 _NOSYNC = object()
 
@@ -62,6 +70,22 @@ def _default_sync():
         jax.effects_barrier()
     except Exception:
         pass
+
+
+def avals_of(args):
+    """A call's arguments as ``Tracer.note_program`` keeps them: every array
+    as a ``ShapeDtypeStruct`` (its sharding where it is committed to one:
+    an uncommitted array is lowered as the call lowers it, unspecified),
+    anything else as it is. Holds no buffer."""
+    import jax
+
+    def one(x):
+        if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+            return x
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype, weak_type=getattr(x, "weak_type", False),
+            sharding=x.sharding if getattr(x, "committed", False) else None)
+    return jax.tree.map(one, args)
 
 
 def _block_on(value):
@@ -216,8 +240,15 @@ class Tracer:
         self._phase_seq = itertools.count()
         self._phase_total = 0
         self._annotation_cls = None     # jax.profiler.TraceAnnotation, lazily
+        self._profiled_ns = 0       # when a phase last saw a profiler trace
         self._gc_owners: set = set()
         self._gc_t0 = 0
+        # compiled programs by (module name, key): a weak reference to the
+        # jitted function, the avals and mesh of its first call, when that
+        # was, and its scope table once built. Nothing here keeps an engine
+        # alive: a jitted body closes over its engine, hence the weak
+        # reference; a table is a dict of strings.
+        self._programs: Dict[Tuple[str, Any], list] = {}
 
     # ------------------------------------------------------------ configure
     def configure(self, config=None, **overrides):
@@ -366,7 +397,92 @@ class Tracer:
             except ImportError:
                 cls = False
             self._annotation_cls = cls
-        return cls if cls and cls.is_enabled() else None
+        if cls and cls.is_enabled():
+            self._profiled_ns = time.perf_counter_ns()
+            return cls
+        return None
+
+    # -------------------------------------------------------------- programs
+    def note_program(self, module: str, key: Any, fn, avals, mesh=None):
+        """Register a compiled program at its first call: ``module`` is its
+        name in a device trace (``jit_pf``, ``jit_dec``,
+        ``jit_train_step``), ``key`` what tells two programs of one module
+        name apart (the pool programs' key in ``_slot_fns``:
+        ``("slot_prefill", bucket, max_len)``), ``fn`` the jitted function,
+        ``avals`` its arguments as ``ShapeDtypeStruct``s with shardings and
+        ``mesh`` the mesh it is called under. Lowers nothing: the caller
+        marks ``fn`` noted and pays one attribute test a call after that.
+        The registry holds ``fn`` weakly (its body closes over its engine);
+        a program noted again under the same name and key (a second engine
+        of the same shape) takes the entry over."""
+        self._programs[(module, key)] = [weakref.ref(fn), avals, mesh,
+                                         time.perf_counter_ns(), None]
+
+    def scope_tables(self) -> Dict[str, Dict[Any, dict]]:
+        """``{module name: {program key: {instruction name: scope}}}`` of
+        every noted program (``hlo_cost.scope_table`` of its optimized HLO).
+        A table not built yet is built now, from
+        ``fn.lower(*avals).compile().as_text()``: the program the process
+        already runs, so the persistent compile cache makes the compile a
+        fetch, and its instructions carry the names a device trace of the
+        process shows. A program whose function is gone and whose table was
+        never built is forgotten. Never called on a call path: an operator
+        or a trace's reader asks by hand (an engine that closes after a
+        profiler trace leaves its own through ``keep_tables``)."""
+        out: Dict[str, Dict[Any, dict]] = {}
+        for (module, key), entry in list(self._programs.items()):
+            if entry[4] is None and entry[0]() is None:
+                del self._programs[(module, key)]
+                continue
+            if entry[4] is None:
+                self._build_table(module, key, entry)
+            out.setdefault(module, {})[key] = entry[4]
+        return out
+
+    def keep_tables(self):
+        """An engine's last act as it closes: build now, while they can
+        still be lowered, the tables of the programs noted before a phase
+        of this tracer last saw a ``jax.profiler`` trace, so that they
+        outlive the engine exactly where a device trace exists to join them
+        to. Nothing in a process no profiler traced: that one never lowers
+        a program twice. A failure is logged and not raised: it must not
+        keep an engine from closing."""
+        if not self._profiled_ns:
+            return
+        try:
+            for (module, key), entry in list(self._programs.items()):
+                if entry[4] is None and entry[0]() is not None \
+                        and entry[3] <= self._profiled_ns:
+                    self._build_table(module, key, entry)
+        except Exception as e:      # noqa: BLE001
+            from ..utils.logging import logger
+            logger.warning(f"scope tables not kept: {type(e).__name__}: {e}")
+
+    def _build_table(self, module, key, entry):
+        from .hlo_cost import scope_table
+        from ..utils.logging import logger
+        fn, avals, mesh = entry[0](), entry[1], entry[2]
+        t0 = time.perf_counter()
+        with mesh if mesh is not None else nullcontext():
+            text = fn.lower(*avals).compile().as_text()
+        t1 = time.perf_counter()
+        table = entry[4] = scope_table(text)
+        unnamed = sum(v is None for v in table.values())
+        inferred = sum(1 for v in table.values() if v and v[0] == "?")
+        logger.info(
+            f"scope table {module} {key}: {len(table)} instructions "
+            f"({inferred} inferred, {unnamed} unnamed), compile "
+            f"{t1 - t0:.2f} s, parse {time.perf_counter() - t1:.2f} s")
+        # every program of an engine scans its layers under ``layers``: a
+        # table without the word is another program's, handed over by a
+        # compile cache whose key leaves the metadata out
+        if module in ("jit_pf", "jit_dec", "jit_train_step") and not any(
+                v and "layers" in v.split("/") for v in table.values()):
+            logger.warning(
+                f"scope table {module} {key} holds no 'layers': the "
+                f"executable carries another program's names (a compile "
+                f"cache keyed without metadata?); its scopes are not to "
+                f"be trusted")
 
     def watch_gc(self, owner: Any):
         """Record garbage collections as ``gc`` phases (generation, objects

@@ -17,6 +17,14 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 def enable_compile_cache() -> str:
     """Turn the persistent cache on and return its directory."""
     import jax
+    # JAX leaves metadata out of the cache's key by default, so a program
+    # that differs from a cached one in its names alone (a ``named_scope``
+    # added, a line moved) is handed the OTHER program's executable, whose
+    # HLO text and whose profile carry the other's names: the scope tables
+    # (``Tracer.scope_tables``) and a device trace would then describe code
+    # that is not running. With the metadata in the key an executable's
+    # names are those of the program that asked for it.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(_CHECKOUT, ".jax_cache")

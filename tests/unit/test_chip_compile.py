@@ -597,3 +597,94 @@ def test_decode_step_over_a_mesh_compiles_on_the_xla_attend(
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text         # the row-parallel matmuls' sum
+
+
+@pytest.fixture(scope="module")
+def longprompt_programs():
+    """``opt-1.3b.serve-longprompt``'s widths and vocabulary at two layers:
+    the engine with its decode program and bucket 2,048's prefill, each
+    called once on a one-slot pool on the CPU."""
+    from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+    max_len, bucket = 2048, 2048
+    model = OPTModel(OPTConfig(vocab_size=50272, n_positions=max_len,
+                               n_embd=2048, n_layer=2, n_head=32,
+                               dtype="bfloat16"))
+    engine, dec, tiny = _decode_program(model, max_len)
+    tiny, _ = engine.slot_prefill(tiny, 0, np.zeros(bucket - 3, np.int32))
+    return engine, tiny, {
+        "jit_dec": dec,
+        "jit_pf": engine._slot_fns[("slot_prefill", bucket, max_len)]}
+
+
+@pytest.mark.parametrize("program", ["jit_dec", "jit_pf"])
+def test_scope_table_names_every_large_instruction(
+        one_chip, monkeypatch, longprompt_programs, program):
+    """``jit_dec`` and bucket 2,048's ``jit_pf`` at
+    ``opt-1.3b.serve-longprompt``'s pool (24 slots x 2,048, the cell's
+    vocabulary, two layers), compiled for the chip: in the scope table of
+    the optimized HLO (``telemetry.hlo_cost.scope_table``) no instruction
+    with 1 MB of output or more reads ``None``, the ``[bucket, vocabulary]``
+    matmul reads ``head``, the float32 scores ``kv_read``, the decode
+    kernel ``kv_read``, and the sampler's sort ``sample``. (Two layers: the
+    compiler unrolls the scan, and the names hold all the same.)"""
+    import re
+    from deepspeed_tpu.parallel import topology
+    from deepspeed_tpu.telemetry.hlo_cost import DTYPE_BYTES, scope_table
+    slots, bucket = 24, 2048
+    engine, tiny, fns = longprompt_programs
+    fn = fns[program]
+    monkeypatch.setattr(topology, "on_tpu", lambda: True)
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    si, sf = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    if program == "jit_dec":
+        args = (params, pool, vi, vi, vf, vi, vf, vi)
+    else:
+        args = (params, on_chip((1, bucket), jnp.int32), pool, si, si, sf,
+                si, sf, si)
+    assert fn.__name__ == program[4:]
+    text = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        *args).compile().as_text()
+    table = scope_table(text)
+
+    def out_bytes(name):
+        m = re.search(rf"^\s*(?:ROOT )?%?{re.escape(name)} = (\w+)\[([\d,]*)\]",
+                      text, re.M)
+        if m is None:                   # a tuple-shaped result: not one array
+            return 0
+        return DTYPE_BYTES.get(m.group(1), 4) * int(np.prod(
+            [int(d) for d in m.group(2).split(",") if d] or [1]))
+
+    # (what nothing uses has no neighbour to be named by: at this depth the
+    # compiler prefetches the position table a second time and drops it)
+    large = {n: s for n, s in table.items() if out_bytes(n) >= 1 << 20
+             and re.search(rf"\(.*%{re.escape(n)}[,)]", text)}
+    assert len(large) > 8 and \
+        [n for n, s in large.items() if s is None] == []
+    # the program's own names alone (an ``op_name``, the called
+    # computation's) leave some of them unnamed: prefetched slices and
+    # copies, the lane's zero fill. Those read a scope inferred from what
+    # uses them, and say so
+    assert [n for n, s in large.items() if s.startswith("?")]
+    unnamed = [n for n, s in table.items() if s is None]
+    assert len(unnamed) < 0.1 * len(table), unnamed
+
+    def shaped(pattern):
+        return {table[n] for n in re.findall(
+            rf"^\s*(?:ROOT )?%?(\S+) = {pattern}", text, re.M) if n in table}
+
+    assert shaped(r"\(f32\[\d+,50272\].* sort\(") == {"sample"}
+    if program == "jit_pf":
+        assert shaped(r"bf16\[2048,50304\]\S* fusion\(") == {"head"}
+        assert shaped(r"f32\[16,2,2048,2048\]\S* fusion\(") == \
+            {"layers/attn/kv_read"}
+    else:
+        assert shaped(r"bf16\[24,32,128\]\S* custom-call\(") == \
+            {"layers/attn/kv_read"}

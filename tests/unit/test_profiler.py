@@ -178,44 +178,3 @@ def test_per_phase_attribution_survives_autodiff():
     for ph in ("attn", "mlp", "head"):
         assert phases.get(ph, 0) > 0, (ph, phases)
     assert phases.get("other", 0) < prof["flops"] * 0.5, phases
-
-
-def test_wall_fractions_from_synthetic_trace(tmp_path):
-    """Trace parsing: XLA-op self-time attributed by named-scope tokens,
-    cross-phase fusions split evenly, 'heads'/'embedding' identifiers do
-    NOT misattribute, and non-XLA threads are ignored."""
-    import gzip
-    import json
-    from deepspeed_tpu.profiling.flops_profiler import \
-        wall_fractions_from_trace
-
-    events = [
-        {"ph": "M", "pid": 1, "tid": 1, "name": "thread_name",
-         "args": {"name": "XLA Ops"}},
-        {"ph": "M", "pid": 1, "tid": 2, "name": "thread_name",
-         "args": {"name": "Steps"}},
-        # plain attn op: 60us
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 0, "dur": 60,
-         "name": "fusion.1", "args": {"long_name": "jit(step)/attn/dot"}},
-        # cross-phase fusion: 40us split between attn and mlp
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 100, "dur": 40,
-         "name": "fusion.2",
-         "args": {"long_name": "jit(step)/mlp/add fused jit(step)/attn/ln"}},
-        # 'num_heads'/'embedding' must not count as head/embed
-        {"ph": "X", "pid": 1, "tid": 1, "ts": 200, "dur": 100,
-         "name": "fusion.3", "args": {"long_name": "num_heads=12 embedding"}},
-        # non-XLA thread ignored entirely
-        {"ph": "X", "pid": 1, "tid": 2, "ts": 0, "dur": 1000,
-         "name": "attn something"},
-    ]
-    path = tmp_path / "sub" / "x.trace.json.gz"
-    path.parent.mkdir()
-    with gzip.open(path, "wt") as f:
-        json.dump({"traceEvents": events}, f)
-
-    wf = wall_fractions_from_trace(str(tmp_path))
-    total = 60 + 40 + 100
-    assert abs(wf["attn"] - (60 + 20) / total) < 1e-9, wf
-    assert abs(wf["mlp"] - 20 / total) < 1e-9, wf
-    assert abs(wf["other"] - 100 / total) < 1e-9, wf
-    assert "head" not in wf and "embed" not in wf, wf
