@@ -45,6 +45,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 __all__ = ["DraftRuntime", "build_draft", "draft_key", "row_keys",
            "sample_rows"]
@@ -167,27 +168,46 @@ def sample_rows(logits, temps, top_ks, top_ps, keys, vocab):
     i32 [S] (0 = off); keys [S]. Greedy rows (temps <= 0) are fp32
     argmax over the real vocab — bitwise the ``generate()`` contract.
     Sampled rows follow HF's warper order: temperature, then top-k,
-    then top-p on the top-k-renormalized distribution."""
+    then top-p on the top-k-renormalized distribution.
+
+    The call computes only what some row of it asks for, decided on the
+    device from ``temps`` / ``top_ks`` / ``top_ps``: nothing but the
+    argmax where every row is greedy, and no sort of the vocabulary
+    where no row truncates. Either way each row gets bitwise the token
+    the long way gives it."""
     last = logits[:, :vocab].astype(jnp.float32)
     greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
     v = last.shape[-1]
-    scaled = last / jnp.maximum(temps, 1e-6)[:, None]
-    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    kth = jnp.take_along_axis(desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None],
-                              axis=-1)
-    k_on = (top_ks > 0)[:, None]
-    masked = jnp.where(k_on & (scaled < kth), -jnp.inf, scaled)
-    # top-p on the top-k survivors (exactly the first k sorted entries)
-    eff_k = jnp.where(top_ks > 0, top_ks, v)
-    desc = jnp.where(jnp.arange(v)[None, :] < eff_k[:, None], desc, -jnp.inf)
-    probs = jax.nn.softmax(desc, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]
-    thresh = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
-    p_on = (top_ps < 1.0)[:, None]
-    masked = jnp.where(p_on & (masked < thresh), -jnp.inf, masked)
-    sampled = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
-    return jnp.where(temps <= 0.0, greedy, sampled)
+
+    def draw(masked):
+        return jax.vmap(jax.random.categorical)(keys, masked)
+
+    def truncated(scaled):
+        desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        kth = jnp.take_along_axis(
+            desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None], axis=-1)
+        k_on = (top_ks > 0)[:, None]
+        masked = jnp.where(k_on & (scaled < kth), -jnp.inf, scaled)
+        # top-p on the top-k survivors (exactly the first k sorted entries)
+        eff_k = jnp.where(top_ks > 0, top_ks, v)
+        desc = jnp.where(jnp.arange(v)[None, :] < eff_k[:, None], desc,
+                         -jnp.inf)
+        probs = jax.nn.softmax(desc, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        keep = (cum - probs) < top_ps[:, None]
+        thresh = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1,
+                         keepdims=True)
+        p_on = (top_ps < 1.0)[:, None]
+        return draw(jnp.where(p_on & (masked < thresh), -jnp.inf, masked))
+
+    def sampled():
+        scaled = last / jnp.maximum(temps, 1e-6)[:, None]
+        # no row truncates: both masks are off, the draw is over `scaled`
+        tok = lax.cond(jnp.any((top_ks > 0) | (top_ps < 1.0)),
+                       truncated, draw, scaled).astype(jnp.int32)
+        return jnp.where(temps <= 0.0, greedy, tok)
+
+    return lax.cond(jnp.any(temps > 0.0), sampled, lambda: greedy)
 
 
 def sampling_arrays(n: int):

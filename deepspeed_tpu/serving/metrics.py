@@ -101,6 +101,8 @@ class ServingMetrics:
         self.timeouts = 0
         self.tokens_out = 0
         self.ticks = 0
+        self.decode_ticks = 0     # ticks that ran a decode step
+        self.sampled_ticks = 0    # ... with a slot at temperature > 0
         self.handoffs_in = 0      # KV lanes received into this pool
         self.handoffs_out = 0     # KV lanes extracted and handed off
         self.handoffs_refused = 0  # lanes rejected at a weights_version
@@ -216,6 +218,16 @@ class ServingMetrics:
         self.token_ms.append(seconds * 1e3)
         self._token_t.append(self._now())
         self.tokens_out += n_active
+
+    def record_decode_tick(self, sampled: bool):
+        """One decode tick, plain or speculative. ``sampled``: some slot's
+        temperature is on, so the tick's sampler runs its sort-and-draw
+        branch (``inference/speculative.py:sample_rows``); the share of
+        ``serve/sampled_ticks`` in ``serve/decode_ticks`` is how often."""
+        self.decode_ticks += 1
+        self.sampled_ticks += bool(sampled)
+        self._gauge("serve/decode_ticks", self.decode_ticks)
+        self._gauge("serve/sampled_ticks", self.sampled_ticks)
 
     def record_tenant_tokens(self, tenant, n: int = 1):
         """Attribute ``n`` decode tokens to ``tenant`` (the aggregate

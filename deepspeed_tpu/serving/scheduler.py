@@ -978,6 +978,8 @@ class ContinuousBatchingScheduler:
         active = self.pool.active_slots
         if not active:
             return
+        # a free slot's temperature is 0: what the step's sampler will see
+        self.metrics.record_decode_tick((self.pool.temps > 0).any())
         if self.spec is not None:
             return self._decode_speculative(active)
         tr = self.tracer

@@ -603,7 +603,8 @@ def test_decode_step_over_a_mesh_compiles_on_the_xla_attend(
 def longprompt_programs():
     """``opt-1.3b.serve-longprompt``'s widths and vocabulary at two layers:
     the engine with its decode program and bucket 2,048's prefill, each
-    called once on a one-slot pool on the CPU."""
+    called once on a one-slot pool on the CPU, and the optimized HLO of
+    those compiled for the chip so far (``_longprompt_text``)."""
     from deepspeed_tpu.models.opt import OPTConfig, OPTModel
     max_len, bucket = 2048, 2048
     model = OPTModel(OPTConfig(vocab_size=50272, n_positions=max_len,
@@ -613,7 +614,40 @@ def longprompt_programs():
     tiny, _ = engine.slot_prefill(tiny, 0, np.zeros(bucket - 3, np.int32))
     return engine, tiny, {
         "jit_dec": dec,
-        "jit_pf": engine._slot_fns[("slot_prefill", bucket, max_len)]}
+        "jit_pf": engine._slot_fns[("slot_prefill", bucket, max_len)]}, {}
+
+
+def _longprompt_text(one_chip, monkeypatch, programs, program):
+    """The optimized HLO of ``program`` (``jit_dec``, or bucket 2,048's
+    ``jit_pf``) at ``opt-1.3b.serve-longprompt``'s pool (24 slots x 2,048),
+    traced as on one TPU and compiled for the chip; once a module."""
+    from deepspeed_tpu.parallel import topology
+    slots, bucket = 24, 2048
+    engine, tiny, fns, texts = programs
+    fn = fns[program]
+    if program not in texts:
+        monkeypatch.setattr(topology, "on_tpu", lambda: True)
+
+        def on_chip(shape, dtype):
+            return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+        params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype),
+                              engine.params)
+        pool = jax.tree.map(
+            lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype),
+            tiny)
+        vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+        si, sf = on_chip((), jnp.int32), on_chip((), jnp.float32)
+        if program == "jit_dec":
+            args = (params, pool, vi, vi, vf, vi, vf, vi)
+        else:
+            args = (params, on_chip((1, bucket), jnp.int32), pool, si, si,
+                    sf, si, sf, si)
+        assert fn.__name__ == program[4:]
+        texts[program] = jax.jit(
+            fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+            *args).compile().as_text()
+    return texts[program]
 
 
 @pytest.mark.parametrize("program", ["jit_dec", "jit_pf"])
@@ -628,30 +662,9 @@ def test_scope_table_names_every_large_instruction(
     kernel ``kv_read``, and the sampler's sort ``sample``. (Two layers: the
     compiler unrolls the scan, and the names hold all the same.)"""
     import re
-    from deepspeed_tpu.parallel import topology
     from deepspeed_tpu.telemetry.hlo_cost import DTYPE_BYTES, scope_table
-    slots, bucket = 24, 2048
-    engine, tiny, fns = longprompt_programs
-    fn = fns[program]
-    monkeypatch.setattr(topology, "on_tpu", lambda: True)
-
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
-    pool = jax.tree.map(
-        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
-    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
-    si, sf = on_chip((), jnp.int32), on_chip((), jnp.float32)
-    if program == "jit_dec":
-        args = (params, pool, vi, vi, vf, vi, vf, vi)
-    else:
-        args = (params, on_chip((1, bucket), jnp.int32), pool, si, si, sf,
-                si, sf, si)
-    assert fn.__name__ == program[4:]
-    text = jax.jit(
-        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        *args).compile().as_text()
+    text = _longprompt_text(one_chip, monkeypatch, longprompt_programs,
+                            program)
     table = scope_table(text)
 
     def out_bytes(name):
@@ -688,3 +701,54 @@ def test_scope_table_names_every_large_instruction(
     else:
         assert shaped(r"bf16\[24,32,128\]\S* custom-call\(") == \
             {"layers/attn/kv_read"}
+
+
+@pytest.mark.parametrize("program", ["jit_dec", "jit_pf"])
+def test_sampler_sorts_inside_a_conditional_only(
+        one_chip, monkeypatch, longprompt_programs, program):
+    """The same two programs, compiled for the chip: the compiler keeps
+    the sampler's two ``cond``s as ``conditional`` instructions (it turns
+    neither into a select that would run both sides), every ``sort`` of
+    the program lies in a computation that only a conditional's branch
+    reaches, so a call whose rows are all greedy sorts nothing, and the
+    scope table still names the sort, the draw and the conditionals
+    ``sample``."""
+    import re
+    from deepspeed_tpu.telemetry.hlo_cost import (_parse_computations,
+                                                  scope_table)
+    text = _longprompt_text(one_chip, monkeypatch, longprompt_programs,
+                            program)
+    comps = {name.lstrip("%"): block
+             for name, block in _parse_computations(text).items()}
+    refs = re.compile(r"(?:calls|to_apply|body|condition|true_computation|"
+                      r"false_computation)=%?([\w.\-]+)")
+    groups = re.compile(r"(?:branch_computations|called_computations)="
+                        r"\{([^}]*)\}")
+
+    def callees(line):
+        return refs.findall(line) + [
+            n.strip().lstrip("%") for g in groups.findall(line)
+            for n in g.split(",")]
+
+    conds = [line for block in comps.values() for line in block
+             if " conditional(" in line]
+    assert len(conds) == 2, conds       # some row samples; some truncates
+    under, todo = set(), [c for line in conds for c in callees(line)]
+    while todo:
+        name = todo.pop()
+        if name not in under:
+            under.add(name)
+            todo += [c for line in comps[name] for c in callees(line)]
+    sorting = {name for name, block in comps.items()
+               if any(re.search(r"\ssort\(", line) for line in block)}
+    assert sorting and sorting <= under, sorting - under
+    # reached from a branch ONLY: nothing outside the branches calls them
+    outside = {c for name, block in comps.items() if name not in under
+               for line in block if " conditional(" not in line
+               for c in callees(line)}
+    assert not (sorting & outside)
+    table = scope_table(text)
+    named = {n: s for n, s in table.items()
+             if re.match(r"(sort|conditional)[.\d]*$", n)}
+    assert len(named) >= 3 and \
+        {s.lstrip("?") for s in named.values()} == {"sample"}, named

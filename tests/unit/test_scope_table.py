@@ -276,7 +276,10 @@ def test_every_program_of_a_family_gets_a_table(family, monkeypatch):
     word the family sets appears and few instructions read ``None``; until
     ``scope_tables()`` is asked nothing is lowered a second time."""
     build, words = FAMILIES[family]
-    get_tracer().scope_tables()     # whatever earlier engines left unbuilt
+    # an earlier module's engine that is only waiting for the collector is
+    # forgotten; a live one's tables are built now, out of `built`'s sight
+    gc.collect()
+    get_tracer().scope_tables()
     engine = deepspeed_tpu.init_inference(build(),
                                           config={"dtype": "float32"})
     built = _builds(monkeypatch)

@@ -300,6 +300,189 @@ def test_sampling_determinism_per_seed(engine):
     assert len(set(a)) > 1          # actually sampling, not degenerate
 
 
+def _straight_line_sample_rows(logits, temps, top_ks, top_ps, keys, vocab):
+    """``sample_rows`` as it stood before it branched (PR 42's body, kept
+    here to the letter): every call sorts the vocabulary, draws for every
+    row, and picks per row at the end. The reference each branch of
+    today's function has to equal bitwise."""
+    import jax
+    import jax.numpy as jnp
+    last = logits[:, :vocab].astype(jnp.float32)
+    greedy = jnp.argmax(last, axis=-1).astype(jnp.int32)
+    v = last.shape[-1]
+    scaled = last / jnp.maximum(temps, 1e-6)[:, None]
+    desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+    kth = jnp.take_along_axis(desc, jnp.clip(top_ks - 1, 0, v - 1)[:, None],
+                              axis=-1)
+    k_on = (top_ks > 0)[:, None]
+    masked = jnp.where(k_on & (scaled < kth), -jnp.inf, scaled)
+    # top-p on the top-k survivors (exactly the first k sorted entries)
+    eff_k = jnp.where(top_ks > 0, top_ks, v)
+    desc = jnp.where(jnp.arange(v)[None, :] < eff_k[:, None], desc, -jnp.inf)
+    probs = jax.nn.softmax(desc, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < top_ps[:, None]
+    thresh = jnp.min(jnp.where(keep, desc, jnp.inf), axis=-1, keepdims=True)
+    p_on = (top_ps < 1.0)[:, None]
+    masked = jnp.where(p_on & (masked < thresh), -jnp.inf, masked)
+    sampled = jax.vmap(jax.random.categorical)(keys, masked).astype(jnp.int32)
+    return jnp.where(temps <= 0.0, greedy, sampled)
+
+
+_ROWS = 12
+#: (temps, top_ks, top_ps) of a call's rows, by what the rows ask for
+_SAMPLER_CALLS = {
+    "all_greedy": lambda r, i: (np.zeros(_ROWS), np.zeros(_ROWS),
+                                np.ones(_ROWS)),
+    # a greedy row may carry stale truncation registers: still greedy
+    "greedy_with_registers": lambda r, i: (np.zeros(_ROWS),
+                                           r.integers(0, 20, _ROWS),
+                                           r.uniform(0.5, 1.0, _ROWS)),
+    "sampled_no_truncation": lambda r, i: (r.uniform(0.3, 1.5, _ROWS),
+                                           np.zeros(_ROWS), np.ones(_ROWS)),
+    "sampled_top_k": lambda r, i: (r.uniform(0.3, 1.5, _ROWS),
+                                   r.integers(1, 30, _ROWS), np.ones(_ROWS)),
+    "sampled_top_p": lambda r, i: (r.uniform(0.3, 1.5, _ROWS),
+                                   np.zeros(_ROWS),
+                                   r.uniform(0.3, 0.99, _ROWS)),
+    "sampled_top_k_and_top_p": lambda r, i: (r.uniform(0.3, 1.5, _ROWS),
+                                             r.integers(1, 30, _ROWS),
+                                             r.uniform(0.3, 0.99, _ROWS)),
+    "mixed_greedy_and_plain": lambda r, i: (np.where(i % 3 == 0, 0.0, 0.8),
+                                            np.zeros(_ROWS), np.ones(_ROWS)),
+    "mixed_every_kind": lambda r, i: (np.where(i % 3 == 0, 0.0, 0.8),
+                                      np.where(i % 2, 20, 0),
+                                      np.where(i % 4 == 1, 0.9, 1.0)),
+    "one_sampled_row": lambda r, i: (np.where(i == 5, 1.1, 0.0),
+                                     np.zeros(_ROWS), np.ones(_ROWS)),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(_SAMPLER_CALLS))
+def test_sample_rows_bitwise_the_straight_line_function(rows):
+    """Whatever a call's rows ask for (all greedy, all sampled with and
+    without top-k / top-p, a mix), ``sample_rows`` returns bitwise what
+    the straight-line function returns: the branches skip work, they
+    change no token. bf16 logits over a padded vocabulary, as the engine
+    hands them over."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.speculative import row_keys, sample_rows
+    vocab, padded = 1000, 1024
+    rng = np.random.default_rng(sorted(_SAMPLER_CALLS).index(rows))
+    temps, top_ks, top_ps = (
+        jnp.asarray(x, dt) for x, dt in zip(
+            _SAMPLER_CALLS[rows](rng, np.arange(_ROWS)),
+            (jnp.float32, jnp.int32, jnp.float32)))
+    new = jax.jit(sample_rows, static_argnums=5)
+    old = jax.jit(_straight_line_sample_rows, static_argnums=5)
+    drawn = set()
+    for _ in range(6):
+        logits = jnp.asarray(rng.normal(0.0, 3.0, (_ROWS, padded)),
+                             jnp.bfloat16)
+        keys = row_keys(
+            jnp.asarray(rng.integers(0, 1 << 30, _ROWS), jnp.int32),
+            jnp.asarray(rng.integers(0, 2048, _ROWS), jnp.int32))
+        got = np.asarray(new(logits, temps, top_ks, top_ps, keys, vocab))
+        want = np.asarray(old(logits, temps, top_ks, top_ps, keys, vocab))
+        assert got.dtype == want.dtype == np.int32
+        np.testing.assert_array_equal(got, want)
+        greedy = np.argmax(np.asarray(logits[:, :vocab], np.float32), -1)
+        np.testing.assert_array_equal(got[np.asarray(temps) <= 0],
+                                      greedy[np.asarray(temps) <= 0])
+        drawn.update((got != greedy).nonzero()[0].tolist())
+    # sampled rows really sample (not the arg-max every time), others never
+    sampling = set(np.nonzero(np.asarray(temps) > 0)[0].tolist())
+    assert drawn <= sampling and bool(drawn) == bool(sampling)
+
+
+def _sorts(jaxpr, conds=0):
+    """(how many ``cond`` branches deep) of every ``sort`` in a jaxpr,
+    through whatever holds a jaxpr: a ``scan`` or a ``pjit`` body, a
+    ``cond``'s branches."""
+    from jax.extend import core as jcore
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort":
+            yield conds
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (tuple, list)) else (val,)):
+                if isinstance(sub, jcore.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jcore.Jaxpr):
+                    yield from _sorts(
+                        sub, conds + (eqn.primitive.name == "cond"))
+
+
+def _program_jaxprs(engine, monkeypatch, call):
+    """{program name: jaxpr} of the pool programs ``call`` runs, traced
+    from the arguments the engine really hands each. (The program's own
+    ``trace``: a second ``jit`` of its body would keep the engine alive in
+    jax's caches past this module, and its tables with it.)"""
+    seen = {}
+    real = engine._pool_call
+
+    def spy(fn, prep, *rest):
+        args = prep()
+        with engine.mesh:
+            seen[fn.__name__] = fn.trace(*args).jaxpr.jaxpr
+        return real(fn, lambda: args, *rest)
+
+    monkeypatch.setattr(engine, "_pool_call", spy)
+    call()
+    return seen
+
+
+def test_sample_rows_sorts_two_conds_deep():
+    """The three levels of the sampler, in its jaxpr: no sort outside a
+    ``cond``, and the sort two branches deep (some row samples AND some
+    row truncates)."""
+    import jax
+    import jax.numpy as jnp
+    from deepspeed_tpu.inference.speculative import row_keys, sample_rows
+    z = jnp.zeros((4,), jnp.int32)
+    jaxpr = jax.make_jaxpr(lambda lg, t, k, p, s: sample_rows(
+        lg, t, k, p, row_keys(s, s), 96))(
+        jnp.zeros((4, 96)), jnp.zeros((4,)), z, jnp.ones((4,)), z).jaxpr
+    assert list(_sorts(jaxpr)) == [2]
+    assert list(_sorts(jax.make_jaxpr(
+        lambda lg, t, k, p, s: _straight_line_sample_rows(
+            lg, t, k, p, row_keys(s, s), 96))(
+        jnp.zeros((4, 96)), jnp.zeros((4,)), z, jnp.ones((4,)),
+        z).jaxpr)) == [0]
+
+
+@pytest.mark.parametrize("program", ["pf", "dec", "prop", "ver"])
+def test_every_sampling_program_sorts_inside_a_cond_only(
+        engine, monkeypatch, program):
+    """The prefill, the decode step, the draft's proposal scan and the
+    verify step each hold their sampler's sort inside ``cond`` branches
+    and nowhere else: an all-greedy call runs no sort. In the verify step
+    the sampler runs under a ``vmap`` over the block's positions; its
+    predicates come from the un-batched ``temps`` / ``top_ks`` /
+    ``top_ps``, so the ``cond`` survives the batching (a batched
+    predicate would turn it into a ``select_n`` over both branches'
+    results, and the sort would stand outside every ``cond``)."""
+    k = 2
+    prompt = _prompts((6,), seed=77)[0]
+    draft = engine.init_draft(DraftConfig(mode="self", layers=1))
+
+    def call():
+        pool, _first, (toks, pos, temps, tk, tp, sd) = _seed_slot(
+            engine, 2, 32, prompt, k)
+        if program == "dec":
+            engine.slot_decode_step(pool, toks, pos, temps, tk, tp, sd)
+        elif program == "prop":
+            dpool = engine.init_draft_pool(draft, 2, 32)
+            engine.slot_draft_propose(draft, dpool, toks, pos, temps, tk,
+                                      tp, sd, k)
+        elif program == "ver":
+            engine.slot_verify_step(pool, toks, np.zeros((2, k), np.int32),
+                                    pos, temps, tk, tp, sd)
+
+    depths = list(_sorts(_program_jaxprs(engine, monkeypatch, call)[program]))
+    assert depths == [2], depths
+
+
 def test_sampling_params_validation():
     with pytest.raises(ValueError):
         SamplingParams(top_k=5).validate()          # needs temperature
@@ -457,6 +640,50 @@ def test_spec_gauges_dedicated_series_and_lifecycle(engine):
     finally:
         tr.clear()
         tr.configure(enabled=False)
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_sampled_ticks_counter(engine, monkeypatch, speculative):
+    """``serve/sampled_ticks`` of ``serve/decode_ticks``: how often a
+    decode tick's sampler ran its sort-and-draw branch. Greedy requests
+    leave it at 0 however many ticks run; one request at temperature 0.7
+    makes exactly the ticks it lives in count (its greedy neighbour's
+    further ticks do not); both gauges go with the engine that owns
+    them."""
+    from deepspeed_tpu.telemetry import get_tracer
+    tr = get_tracer()
+    tr.clear()
+    cfg = _spec_cfg(k=2, layers=2) if speculative else \
+        {"num_slots": 4, "max_model_len": 64}
+    srv = ServingEngine(engine, cfg)
+    try:
+        read = lambda tag: tr.counter_value(tag)            # noqa: E731
+        prompts = _prompts((5, 7), seed=91)
+        for p in prompts:
+            srv.submit(p, SamplingParams(max_new_tokens=6))
+        srv.run_until_idle()
+        greedy_ticks = read("serve/decode_ticks")
+        assert greedy_ticks >= 2 and read("serve/sampled_ticks") == 0
+        assert greedy_ticks == srv.metrics.decode_ticks
+        # a short sampled request beside a long greedy one
+        long_rid = srv.submit(prompts[0], SamplingParams(max_new_tokens=24))
+        hot_rid = srv.submit(prompts[1], SamplingParams(
+            max_new_tokens=4, temperature=0.7, seed=3))
+        lived = []              # per decode step: is the hot request bound?
+        pool, decode = srv.scheduler.pool, srv.scheduler._decode
+        monkeypatch.setattr(srv.scheduler, "_decode", lambda: (lived.append(
+            any(r is not None and r.request_id == hot_rid
+                for r in pool.requests)), decode())[1])
+        srv.run_until_idle()
+        assert srv.result(long_rid).state is RequestState.FINISHED
+        assert sum(lived) >= 1 and read("serve/sampled_ticks") == sum(lived)
+        assert read("serve/decode_ticks") == greedy_ticks + len(lived)
+        assert len(lived) > sum(lived)
+    finally:
+        srv.shutdown()
+    assert read("serve/decode_ticks") is None
+    assert read("serve/sampled_ticks") is None
 
 
 def test_spec_verify_stage_sums_into_critical_path(engine):
