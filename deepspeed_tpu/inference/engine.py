@@ -651,15 +651,18 @@ class InferenceEngine:
         return QuantizedSlotPool(
             q=fixed, scales=jax.tree.map(drop_hd, fixed, shapes))
 
-    @staticmethod
-    def _pool_dims(pool):
-        """(num_slots, max_len, quantized) from any pool flavor. Every
-        program that returns a pool consumes the one it was given
+    def _pool_dims(self, pool, model=None):
+        """(num_slots, max_len, quantized) from any pool flavor of
+        ``model`` (``None``: the engine's own; a draft's pool: the draft).
+        Every program that returns a pool consumes the one it was given
         (``_pool_program`` donates it): a pool that was handed over already
         is refused here, by name, before XLA refuses its buffers."""
-        # a KV leaf, by name: a pool may hold leaves of another shape beside
-        # K and V (a recurrent state has no max_len), and they sort first
-        leaf = (pool.q if is_quantized_pool(pool) else pool)["k"]
+        # a full-length leaf, by the name the model declares
+        # (``GPT2Model.lane_leaves``: ``k``, or a latent leaf): a pool may
+        # hold leaves of another shape beside it (a recurrent state and a
+        # ring have no max_len), and they may sort first
+        name = getattr(model or self.module, "lane_leaves", ("k",))[0]
+        leaf = (pool.q if is_quantized_pool(pool) else pool)[name]
         if leaf.is_deleted():
             raise RuntimeError(
                 "this KV pool was consumed by an earlier slot_* call (or by "
@@ -1177,7 +1180,7 @@ class InferenceEngine:
         donated. Returns the new draft pool."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         t = prompt.shape[0]
-        shape = self._pool_dims(dpool)
+        shape = self._pool_dims(dpool, draft.model)
         num_slots, max_len, _ = shape
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
@@ -1205,7 +1208,7 @@ class InferenceEngine:
         that maximizes exact-match acceptance. Draft pool donated.
         Returns (new_dpool, draft_tokens [S, k])."""
         vocab = draft.model.config.vocab_size
-        shape = self._pool_dims(dpool)
+        shape = self._pool_dims(dpool, draft.model)
 
         @self._pool_program("slot_draft", shape[:2] + (int(k), draft.key),
                             shape, outs=1, draft=draft)

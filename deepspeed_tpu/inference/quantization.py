@@ -110,7 +110,9 @@ def _default_predicate(path, leaf) -> bool:
     last = names[-1] if names else ""
     if last.endswith(("_b", "bias", "scale", "norm", "gamma", "beta")):
         return False
-    skip = ("wpe", "wte", "embed", "position", "lm_head")
+    # ``hc_``: a widened residual's maps (models/xing.py), a few columns
+    # that steer every stream of every sublayer, computed in float32
+    skip = ("wpe", "wte", "embed", "position", "lm_head", "hc_")
     return not any(s in n for n in names for s in skip)
 
 

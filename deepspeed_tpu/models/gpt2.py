@@ -129,8 +129,33 @@ def _kv_row_shape(kv_heads: int, head_dim: int):
     return kv_heads // pack, head_dim * pack
 
 
+def _latent_row_width(values: int) -> int:
+    """The stored width of a pool row of ``values`` values that is no
+    whole number of vector rows: the next multiple of 128 lanes, the rest
+    zeros (``_kv_row_shape``'s rule met from the other side). Compiled for
+    a v5e, a bfloat16 leaf ``[L, S, max_len, 1, 576]`` is laid out with
+    ``max_len`` in the lanes (576 is 72 sublane rows of 8: nothing to pad),
+    and the decode step, which wants a token's row in the lanes, then
+    copies the whole pool in and out of its layer loop (6.3 GB of
+    temporaries at 48 slots x 8192); the tiled row of 576 takes 640 lanes
+    either way."""
+    return -(-values // _LANES) * _LANES
+
+
 def _layer_norm(x, scale, bias, eps):
     return me.layer_norm(x, scale, bias, eps)
+
+
+def _einsum_f32(spec, a, b):
+    """``einsum`` of two arrays of one type, accumulated and handed back in
+    float32: what the MXU does with bfloat16 operands at no cost, where a
+    bfloat16 result would round the sums. The CPU's dot has no bfloat16
+    pair with a float32 result: there (tests, rehearsals) the operands are
+    widened first."""
+    from ..parallel.topology import on_tpu
+    if not on_tpu() and a.dtype != jnp.float32:
+        a, b = a.astype(jnp.float32), b.astype(jnp.float32)
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
 
 
 class GPT2Model(ModelSpec):
@@ -190,6 +215,21 @@ class GPT2Model(ModelSpec):
     #: ``attn_fn`` ``ring=`` them. Like a recurrent state a ring is valid
     #: at the lane's end alone (``lane_end_state``)
     window_rings = ()
+    #: the one leaf of ``init_kv_cache`` that holds a LATENT row a token,
+    #: ``[L, S, max_len, 1, w]`` (``_latent_row_width``), in place of ``k``
+    #: and ``v``: what a
+    #: layer's keys and values are expanded from, or attended through
+    #: (``_latent_attend``). Such a layer hands its ``attn_fn`` the row as
+    #: ``k``, ``v=None`` and ``latent=`` the up-projection. A row per token,
+    #: so the lane is valid up to any column, as K and V are
+    latent_cache = ()
+
+    @property
+    def lane_leaves(self):
+        """The pool leaves that keep a row per token over the lane's full
+        length, the first of which says ``max_len`` (``_pool_dims``): ``k``
+        and ``v``, or the family's latent leaf."""
+        return tuple(self.latent_cache) or ("k", "v")
 
     @property
     def lane_end_state(self):
@@ -219,6 +259,19 @@ class GPT2Model(ModelSpec):
     def _final_norm(self, params, x):
         return _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"],
                            self.config.layer_norm_epsilon)
+
+    def _open_streams(self, x):
+        """What the layers carry, from the embedding ``x`` [B, T, D]: ``x``.
+        A family that widens the residual path (``models/xing.py``: several
+        streams a token, mixed around every sublayer) opens them here, after
+        ``embed``, and closes them before ``head`` (``_close_streams``);
+        its ``_block`` / ``_decode_block`` take and give what this gives."""
+        return x
+
+    def _close_streams(self, x):
+        """The layers' carry as the one row a token [B, T, D] that the
+        final norm and the head take."""
+        return x
 
     def _unembed_weight(self, params, dtype):
         """[V, D] weight of the LM head (tied to wte for GPT-2/OPT)."""
@@ -450,6 +503,7 @@ class GPT2Model(ModelSpec):
         x = self._dropout(x, rng, train, 2)
         use_wrappers = train and rng is not None
         t = x.shape[1]
+        x = self._open_streams(x)
         extras = self._layer_extras()
         # serving runs the code the cache forwards run; training's scan
         # slices every leaf
@@ -506,7 +560,7 @@ class GPT2Model(ModelSpec):
                 unroll=min(max(1, int(getattr(cfg, "scan_unroll", 1))),
                            cfg.n_layer))
 
-        x = self._final_norm(params, x)
+        x = self._final_norm(params, self._close_streams(x))
         return x, aux_total / cfg.n_layer, \
             self._unembed_weight(params, compute_dtype)
 
@@ -906,6 +960,85 @@ class GPT2Model(ModelSpec):
                 last, unique_indices=True))
         return out, rings[0], rings[1]
 
+    def _latent_attend(self, q, q_pos, slab, latent, block, keep):
+        """Attention of ``q`` [S, H, T, n + r] over a layer's LATENT slab
+        ``[S, keys, w]``: a key column holds a token's compressed row
+        ``c_kv`` (c values), then the one rotated key ``k_r`` (r values)
+        that all heads share, then zeros up to whole vector rows
+        (``w >= c + r``). ``latent = (up, scale, r)``: ``up`` [c, H, n + v]
+        expands ``c_kv`` to a head's un-rotated key (n) and value (v); a
+        score is ``(q_n . k_n + q_r . k_r) * scale``. ``keep(q_pos)`` is
+        the keep-mask of the queries at ``q_pos``, broadcastable to
+        [S, H, T, keys]. Scores and softmax in float32. Two paths, by what
+        can be observed:
+
+        - ``T > 1`` (a prefill, a chunk): the slab is EXPANDED once to
+          per-head keys and values (``latent_up``) and the queries go in
+          blocks of ``block`` against them (``_in_row_blocks``), a head
+          at a time: compiled for a v5e, the heads' scores as ONE batched
+          product handed back in float32 and fused into the softmax ran
+          98 ms a block of 1,024 queries over 8,192 keys, a head after
+          another 2.7 (PERF.md, PR 46).
+        - ``T == 1`` (a decode step) is ABSORBED: the query goes through
+          the key half of ``up`` into the latent space, scores and the
+          weighted sum run over the slab itself where it lies, read once
+          for all heads, and the sum goes through the value half
+          (``absorb``): nothing of ``keys`` x H is made."""
+        up, scale, r = latent
+        s, h, t, _ = q.shape
+        c, n, w = up.shape[0], q.shape[-1] - r, slab.shape[-1]
+        up = up.astype(q.dtype)
+        slab = slab.astype(q.dtype)
+
+        def soft(scores, q_pos):
+            scores = jnp.where(keep(q_pos), scores * scale, -1e30)
+            return jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+
+        if t == 1:
+            with jax.named_scope("absorb"):
+                q_c = jnp.einsum("shn,chn->shc", q[:, :, 0, :n], up[..., :n])
+            q_l = jnp.concatenate(
+                [q_c, q[:, :, 0, n:], jnp.zeros((s, h, w - c - r), q.dtype)],
+                axis=-1)
+            probs = soft(_einsum_f32("shw,skw->shk", q_l, slab)[:, :, None],
+                         q_pos)
+            # over the whole row, and on through a value half that is zero
+            # past ``c``: a slice of the slab, or of the sum (which the
+            # compiler moves onto the slab), would be a copy of it
+            out = jnp.einsum("shk,skw->shw", probs[:, :, 0], slab)
+            with jax.named_scope("absorb"):
+                up_v = jnp.pad(up[..., n:], ((0, w - c), (0, 0), (0, 0)))
+                return jnp.einsum("shw,whv->shv", out, up_v)[:, :, None]
+        with jax.named_scope("latent_up"):      # heads first: [H, S, ...]
+            kv = jnp.einsum("skc,chd->hskd", slab[..., :c], up)
+            keys = jnp.concatenate(
+                [kv[..., :n], jnp.broadcast_to(
+                    slab[None, :, :, c:c + r], kv.shape[:3] + (r,))], axis=-1)
+            values = kv[..., n:]
+
+        def attend(at, qb, q_pos):
+            kept = keep(q_pos)              # [S | 1, H | 1, T, keys]
+            by_head = kept.shape[1] != 1
+
+            def head(xs):
+                qh, kh, vh = xs[:3]         # [S, T, .], [S, keys, .]
+                scores = _einsum_f32("sqd,skd->sqk", qh, kh)
+                scores = jnp.where(xs[3] if by_head else kept[:, 0],
+                                   scores * scale, -1e30)
+                # the softmax's division on the [T, v] sums, not on the
+                # [T, keys] weights: a pass over the scores less
+                weights = jnp.exp(scores - lax.stop_gradient(
+                    scores.max(axis=-1, keepdims=True)))
+                out = _einsum_f32("sqk,skv->sqv", weights.astype(q.dtype), vh)
+                return (out / weights.sum(axis=-1, keepdims=True)
+                        ).astype(q.dtype)
+
+            out = lax.map(head, (jnp.moveaxis(qb, 1, 0), keys, values) + (
+                (jnp.moveaxis(kept, 1, 0),) if by_head else ()))
+            return jnp.moveaxis(out, 0, 1), None
+
+        return self._in_row_blocks(attend, block, 2, q, q_pos)[0]
+
     def _decode_attn_mask(self, q_pos, k_pos):
         """Boolean keep-mask over the cache columns: ``q_pos`` [B|1, 1, T, 1]
         against ``k_pos`` [1, 1, 1, max_len]. Sliding-window families
@@ -940,11 +1073,14 @@ class GPT2Model(ModelSpec):
         - the program will run on a TPU, on one device: GSPMD cannot
           partition a Mosaic kernel, and the pool may be sharded over
           ``model``;
-        - the kernel takes the pool's stored rows
+        - the pool holds K and V (a latent leaf is attended by
+          ``_latent_attend``), in stored rows the kernel takes
           (``decode_attention.block_columns``)."""
         from ..ops.pallas import decode_attention
         from ..parallel.constraints import active_mesh
         from ..parallel.topology import on_tpu
+        if self.latent_cache:       # the kernel contracts K and V rows
+            return None
         leaf = cache["k"]
         max_len = leaf.shape[2]
         mesh = active_mesh()
@@ -1000,7 +1136,7 @@ class GPT2Model(ModelSpec):
         (``_state_shift`` on the layer's own index), and ``lengths`` [S]
         says how many of each row's T tokens are real (``None``: all)."""
         s, t = input_ids.shape
-        max_len = cache["k"].shape[2]
+        max_len = cache[self.lane_leaves[0]].shape[2]
         compute_dtype = self._compute_dtype(params)
         if jnp.ndim(start) != 0:
             positions = start[:, None] + jnp.arange(t)[None, :]      # [S, T]
@@ -1013,8 +1149,8 @@ class GPT2Model(ModelSpec):
             positions = None if pad_counts is None else \
                 jnp.maximum(cols - pad_counts[:, None], 0)
         with jax.named_scope("embed"):
-            x = self._embed(params, input_ids, start_pos=start_pos,
-                            positions=positions)
+            x = self._open_streams(self._embed(
+                params, input_ids, start_pos=start_pos, positions=positions))
         block = self._query_block(t, self.config.n_head, max_len)
         k_pos = jnp.arange(max_len)[None, None, None, :]
         pad_valid = None
@@ -1046,24 +1182,36 @@ class GPT2Model(ModelSpec):
             routed = {} if whole is None else {"stacked": (whole, layer)}
             pool = dict(pool)
 
-            def attend(at, q, q_pos):
+            def mask_and_bias(q_pos):
                 if block == t:
-                    mask = base_mask if whole_mask else keep_mask(extra)
-                    bias = base_bias
-                else:
-                    mask = keep_mask(extra, q_pos)
-                    bias = self._decode_attn_bias(q_pos, k_pos)
-                return self._kv_attend(q, pool["k"], pool["v"], layer,
-                                       mask, bias), None
+                    return (base_mask if whole_mask else keep_mask(extra),
+                            base_bias)
+                return keep_mask(extra, q_pos), \
+                    self._decode_attn_bias(q_pos, k_pos)
 
-            def cached_attn(q, k, v, ring=None):
+            def attend(at, q, q_pos):
+                return self._kv_attend(q, pool["k"], pool["v"], layer,
+                                       *mask_and_bias(q_pos)), None
+
+            def cached_attn(q, k, v, ring=None, latent=None):
                 # q, k, v arrive [S, H, T, hd]. The kv_write / kv_read
                 # scopes nest inside "attn": a device trace's reader
                 # (``hlo_cost.scope_table``) sees the cache's writes and
                 # the attend over it apart from the projections around
                 # them. ``ring``: a window layer names its two ring
                 # leaves of the pool (``_window_attend``) in place of
-                # ``k`` and ``v``
+                # ``k`` and ``v``. ``latent``: ``k`` is the token's latent
+                # row [S, T, w] and there is no ``v`` (``_latent_attend``)
+                if latent is not None:
+                    name, = self.latent_cache
+                    with jax.named_scope("kv_write"):
+                        pool[name] = self._kv_write(pool[name], layer,
+                                                    k[:, :, None], start)
+                    with jax.named_scope("kv_read"):
+                        return self._latent_attend(
+                            q, q_pos, lax.dynamic_index_in_dim(
+                                pool[name], layer, 0, keepdims=False)[:, :, 0],
+                            latent, block, lambda qp: mask_and_bias(qp)[0])
                 if ring is not None:
                     out, pool[ring[0]], pool[ring[1]] = self._window_attend(
                         q, k, v, pool[ring[0]], pool[ring[1]], layer, start,
@@ -1097,7 +1245,7 @@ class GPT2Model(ModelSpec):
             (x, pool), stats = self._scan_layers(body, (x, dict(cache)),
                                                  blocks, indexed=True)
         with jax.named_scope("head"):
-            x = self._final_norm(params, x)
+            x = self._final_norm(params, self._close_streams(x))
             logits = x @ self._unembed_weight(params, compute_dtype).T
             head_b = self._head_bias(params, logits.dtype)
             if head_b is not None:

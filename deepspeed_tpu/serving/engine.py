@@ -36,8 +36,13 @@ class ServingEngine:
     """Slot-based continuous-batching serving on top of InferenceEngine."""
 
     #: what cannot be served over each kind of state a model's pool may
-    #: declare beside full-length K and V (``GPT2Model.recurrent_state``,
-    #: ``window_rings``): {kind: (what it is, {config block: why not})}
+    #: declare beside, or in place of, full-length K and V
+    #: (``GPT2Model.recurrent_state``, ``window_rings``, ``latent_cache``):
+    #: {kind: (what it is, {config block: why not})}. A latent lane keeps a
+    #: row per token and IS valid up to any column: prefix cache, chunked
+    #: prefill and an int8 pool run over it as over K and V
+    #: (tests/unit/test_xing.py); what is refused is what it has no
+    #: program for
     _LANE_END_FENCES = {
         "recurrent_state": (
             "keeps a recurrent state {names} beside K and V, which exists "
@@ -64,6 +69,13 @@ class ServingEngine:
              "chunked_prefill": "a prompt's last chunk may start below the "
              "column the chunks before it reached (reuse_plan), and the "
              "ring no longer holds the positions before that"}),
+        "latent_cache": (
+            "keeps a latent row a token {names} in place of K and V",
+            {"speculative": "a block of draft tokens at a position of its "
+             "own a slot would expand EVERY slot's whole lane to per-head "
+             "keys and values a verify step (the block path of "
+             "_latent_attend), and the family's own drafter, its "
+             "multi-token-prediction block, is not built (ROADMAP B9)"}),
     }
 
     @classmethod
@@ -75,7 +87,9 @@ class ServingEngine:
         filled across decode ticks. Refused here, by the name of the block
         and of the state, before a pool is allocated; snapshots of such
         state are ROADMAP B8's. An int8 pool holds a ring as it holds a
-        lane (a column is quantized once, when it is written)."""
+        lane (a column is quantized once, when it is written). The kinds
+        are what the MODEL declares, none is inferred from a leaf's name:
+        a latent lane is fenced for speculation alone."""
         module = getattr(engine, "module", None)
         from ..runtime.config_utils import ConfigError
         for kind, (what, fences) in cls._LANE_END_FENCES.items():

@@ -322,9 +322,13 @@ class ContinuousBatchingScheduler:
         # a model with window layers: the columns a ring keeps of a lane,
         # read off the pool's own leaf (``serve/kv_live``); 0 where every
         # attending layer keeps them all
-        rings = getattr(getattr(engine, "module", None), "window_rings", ())
+        module = getattr(engine, "module", None)
+        rings = getattr(module, "window_rings", ())
         leaves = getattr(self.pool.cache, "q", self.pool.cache)
         self._ring_window = int(leaves[rings[0]].shape[2]) if rings else 0
+        # a pool whose lanes are not K and V of every layer (rings beside
+        # them; a latent row a token) says what of it is live a decode tick
+        self._kv_live = bool(rings or getattr(module, "latent_cache", ()))
         # the columns a block of the decode-attention kernel fetches, where
         # the decode program takes it; None where the step reads the whole
         # pool (``serve/kv_read``)
@@ -996,9 +1000,9 @@ class ContinuousBatchingScheduler:
                 self.pool.cache, toks, positions, temps,
                 top_ks=top_ks, top_ps=top_ps, seeds=seeds)
         self._record_routing("serve/moe_decode")
-        if self._ring_window:
+        if self._kv_live:
             # columns the step just read that hold a token of an active
-            # slot: over the full-length lanes, over the rings
+            # slot: over the full-length lanes, over the rings (0: none)
             live = positions[active] + 1
             now = time.perf_counter_ns()
             tr.record_phase("serve/kv_live", now, now, int(live.sum()),

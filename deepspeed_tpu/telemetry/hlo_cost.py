@@ -79,8 +79,9 @@ COLLECTIVES = ("all-reduce", "reduce-scatter", "all-gather", "all-to-all",
 #: (tests/unit/test_scope_table.py walks the sources). ``layers`` is the
 #: layer scan: what reads ``layers`` and no deeper word is the scan's own
 #: slicing of the stacked leaves.
-SCOPES = ("embed", "layers", "attn", "qkv", "out_proj", "kv_write",
-          "kv_read", "attend_window", "attend_full", "mlp", "dense_mlp",
+SCOPES = ("embed", "layers", "hc_maps", "hc_mix", "attn", "qkv", "out_proj",
+          "kv_write", "kv_read", "attend_window", "attend_full",
+          "attend_latent", "latent_up", "absorb", "mlp", "dense_mlp",
           "moe", "router", "moe_experts", "shared_expert", "conv", "head",
           "loss", "sample", "verify", "optimizer")
 

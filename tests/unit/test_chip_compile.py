@@ -471,6 +471,102 @@ def test_window_rings_are_written_and_read_in_place(one_chip, program):
     assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
 
 
+@pytest.mark.parametrize("program", ("decode", "prefill"))
+def test_latent_pool_is_written_and_attended_in_place(one_chip, program):
+    """The slot decode step (absorbed: one token a slot against the latent
+    slab where it lies) and a slot-prefill bucket (2048: the lane expanded
+    to per-head keys and values, queries in blocks) of a Xing4.0 of
+    ``xing4.0-29b-a4b.serve-docqa``'s widths (hidden 3584, 32 heads over a
+    latent of 512 + 64, 8 of 64 experts of 1024 held, 4 a token, a shared
+    expert, four residual streams; the dense layer and two routed ones; a
+    small vocabulary), compiled for the chip at ISSUE 46's first pool, 48
+    slots x 8,192 (the cell runs its second, 32 x 4,224: half the lane, the
+    same programs). The pool's only leaf is the latent one, a token's 576 values
+    stored as 640 (whole vector rows: 1,280 B a token a layer). The
+    constraint is the compiled program: the leaf is aliased to the output,
+    no instruction copies or transposes it or a layer's slab of it (with
+    576-wide rows the decode step copied the whole pool in and out of its
+    layer loop: 6.3 GB of temporaries at the cell's twelve layers), no
+    layer's expert leaf is produced, and the Sinkhorn steps are unrolled
+    into the maps' fusion: no loop of 20 trips is left. (Twelve layers,
+    compiled the same way: 0.62 GB of temporaries for ``jit_dec`` and 1.39
+    GB for the bucket-8,192 prefill at 48 x 8,192; the cell's 32 x 4,224:
+    its file's ``memory``, and PERF.md, PR 46.)"""
+    import re
+    from deepspeed_tpu.analysis.hlo_audit_rules import donated_params_from_hlo
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.models.xing import XingConfig, XingModel
+    from deepspeed_tpu.parallel import initialize_mesh
+
+    slots, max_len, bucket = 48, 8192, 2048
+    model = XingModel(XingConfig(
+        vocab_size=512, n_positions=max_len, n_layer=3,
+        first_k_dense_replace=1, experts_held=(0, 8), dtype="bfloat16"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len}),
+        mesh_manager=initialize_mesh(dp=1, devices=jax.devices()[:1]))
+    leaf = engine.params["blocks"]["moe"]["moe"]["experts"]["w_gate"]
+    assert leaf.shape == (2, 8, 3584, 1024) and leaf.dtype == jnp.bfloat16
+    assert engine.params["blocks"]["attn"]["hc_attn"]["phi"].shape == \
+        (3, 4 * 3584, 24)
+    tiny = engine.init_slot_pool(1, max_len)
+    assert {k: v.shape for k, v in tiny.items()} == {
+        "latent": (3, 1, max_len, 1, 640)}
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    i32, f32 = on_chip((), jnp.int32), on_chip((), jnp.float32)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    assert pool_nbytes(pool) == 3 * slots * max_len * 1280
+    if program == "decode":
+        zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
+        engine.slot_decode_step(tiny, zi, zi, zf)
+        fn = engine._slot_fns[("slot_decode", 1, max_len)]
+        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        first = len(jax.tree.leaves(params))
+        # a layer's scores and probabilities [48, 32, 8192] in float32
+        limit = 768 * 2 ** 20
+    else:
+        engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
+        fn = engine._slot_fns[("slot_prefill", 1, max_len)]
+        args = (params, on_chip((1, bucket), jnp.int32), pool, i32, i32,
+                f32, i32, f32, i32)
+        first = len(jax.tree.leaves(params)) + 1
+        # a block of 1,024 queries' scores [32, 1024, 8192] in float32
+        # (``_attend_scores_bytes``: 1 GB) and as probabilities; the lane
+        # of 8,192 expanded to 32 heads' keys (192) and values (128): 168
+        # MB; four streams of rows
+        limit = 3 * 2 ** 30
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        *args).compile()
+    text, mem = compiled.as_text(), compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert donated_params_from_hlo(text) == {first}
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = bf16\[(?:3,48,8192,1,640|48,8192,1,640|"
+        r"48,8192,640|1,48,8192,1,640|8,3584,1024|8,1024,3584)"
+        r"\]\S* (copy|transpose)\(", text, re.M)
+    assert copied == [], copied
+    assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
+    if program == "decode":
+        # the layer scan and no other loop: the Sinkhorn steps are unrolled
+        # (a prefill also loops over its query blocks, the heads of each
+        # and the feed-forward's chunks)
+        assert len(re.findall(r" while\(", text)) == 1
+
+
 def _opt(max_len):
     from deepspeed_tpu.models.opt import OPTConfig, OPTModel
     return OPTModel(OPTConfig(vocab_size=512, n_positions=max_len,
