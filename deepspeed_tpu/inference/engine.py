@@ -694,7 +694,9 @@ class InferenceEngine:
         - the key (``_pool_key``), under which ``_slot_fns`` keeps it;
         - the shardings, read off the body's signature: the parameters'
           at an argument named ``...params``, the pool's at one named
-          ``...pool`` and at the first output, none elsewhere;
+          ``...pool`` and at the first output, whole on every device at
+          the outputs behind it and at an argument named ``prev`` (such an
+          output fed back), none elsewhere;
         - donation: a program that returns a pool donates the pool it
           is given, always. Aliased to the output, a lane write
           (``dynamic_update_slice``) or a decode step's rows change the
@@ -726,13 +728,19 @@ class InferenceEngine:
                 model=draft.model if on_draft else None)
             param_sh = draft.param_shardings if on_draft \
                 else self.param_shardings
+            # what a program returns beside the pool is read by the host or
+            # fed back as the next call's ``prev``: whole on every device,
+            # said at both ends (left to the compiler, tokens [S] may come
+            # out split over the slots, and fed back they would be a
+            # second executable)
+            whole = NamedSharding(self.mesh, P())
             in_sh = tuple(param_sh if n.endswith("params") else
-                          pool_sh if n.endswith("pool") else None
-                          for n in names)
+                          pool_sh if n.endswith("pool") else
+                          whole if n == "prev" else None for n in names)
             if outs is None:        # returns no pool: nothing to alias
                 out_sh, donated = None, ()
             else:
-                out_sh = (pool_sh,) + (None,) * outs if outs else pool_sh
+                out_sh = (pool_sh,) + (whole,) * outs if outs else pool_sh
                 donated = tuple(i for i, n in enumerate(names)
                                 if n.endswith("pool"))
             fn = self._slot_fns[key] = jax.jit(
@@ -1112,7 +1120,26 @@ class InferenceEngine:
         (greedy where temps[s] <= 0; per-row top-k/top-p with keys
         derived from ``(seeds[s], position)`` otherwise — deterministic
         replay). Inactive slots pass dummy inputs and their outputs are
-        ignored by the scheduler. Returns (new_pool, next_tokens [S])."""
+        ignored by the scheduler. Returns (new_pool, next_tokens [S]):
+        ``slot_decode_dispatch`` and ``slot_decode_read`` in a row."""
+        pool, out = self.slot_decode_dispatch(
+            pool, toks, positions, temps, top_ks=top_ks, top_ps=top_ps,
+            seeds=seeds)
+        return pool, self.slot_decode_read(out)
+
+    def slot_decode_dispatch(self, pool, toks, positions, temps,
+                             top_ks=None, top_ps=None, seeds=None,
+                             prev=None, from_host=None):
+        """The first half of ``slot_decode_step``: send the step and return
+        (new_pool, out) with ``out`` still on the device, un-read (the
+        sampled tokens [S]; a model with routed experts appends its two
+        stats). A scheduler that keeps a step in flight hands the next
+        call ``prev``, the ``out`` of the call before, and ``from_host``
+        [S] bool: a row feeds ``toks[s]`` where it is set (a slot bound
+        since that call: its token came from a prefill's read-back) and
+        ``prev[s]`` where it lies otherwise, so step k + 1 can be sent
+        before step k is read. Left out, every row feeds ``toks``. ONE
+        program either way."""
         model = self.module
         vocab = model.config.vocab_size
         shape = self._pool_dims(pool)
@@ -1120,7 +1147,8 @@ class InferenceEngine:
 
         @self._pool_program("slot_decode", shape[:2], shape, outs=1)
         def dec(params, pool, toks, positions, temps, top_ks, top_ps,
-                seeds):
+                seeds, prev, from_host):
+            toks = jnp.where(from_host, toks, prev[:toks.shape[0]])
             # an int8 pool is seen whole as fp by the step and stored back
             # on the way out: per-column scales make the round-trip of
             # every column this step did not write exact
@@ -1136,15 +1164,30 @@ class InferenceEngine:
             return pool_from_fp(fp, pool), \
                 jnp.concatenate([nxt, *stats]) if stats else nxt
 
+        def prep():
+            # zeros where nothing was in flight, placed as the program's
+            # output is: the same input type, so the same executable
+            fed = jax.device_put(
+                np.zeros(num_slots + 2 * self._routed, np.int32),
+                NamedSharding(self.mesh, P())) if prev is None else prev
+            mask = np.ones(num_slots, bool) if from_host is None \
+                else from_host
+            return (self.params, pool, *self._slot_arrays(
+                toks, positions, temps, top_ks, top_ps, seeds), fed,
+                jnp.asarray(mask, bool))
+
         tr = self._tracer
-        pool, nxt = self._pool_call(
-            dec, lambda: (self.params, pool, *self._slot_arrays(
-                toks, positions, temps, top_ks, top_ps, seeds)),
-            (tr.phase("serve/decode_prep"),
-             tr.phase("serve/decode_dispatch")))
-        with tr.phase("serve/decode_wait"):
-            nxt = self._read_back(np.asarray(nxt), num_slots)
-        return pool, nxt
+        return self._pool_call(
+            dec, prep, (tr.phase("serve/decode_prep"),
+                        tr.phase("serve/decode_dispatch")))
+
+    def slot_decode_read(self, out):
+        """The second half of ``slot_decode_step``: wait for a dispatched
+        step and read its tokens back as a host array [S] (a routed
+        model's stats are kept for ``take_routing``)."""
+        with self._tracer.phase("serve/decode_wait"):
+            out = np.asarray(out)
+            return self._read_back(out, out.shape[0] - 2 * self._routed)
 
     # -------------------------------------------- speculative decode protocol
     # Draft-model speculation over the slot pool (inference/speculative.py):

@@ -384,7 +384,11 @@ class ServingEngine:
     # ------------------------------------------------------------------ step
     def step(self) -> int:
         """One scheduler tick: expire deadlines, admit into free slots
-        (prefill), one fused decode step over all active slots. Returns
+        (prefill), one token for every active slot from one fused decode
+        step. The step read is the one the tick before dispatched; this
+        tick's own is dispatched first and left in flight for the next
+        (``Scheduler._decode``), and nothing is left in flight once no
+        request is (``run_until_idle``, ``drain``, ``shutdown``). Returns
         requests still in flight. On a preemption signal (SIGTERM/SIGINT
         or the ``preempt_signal`` fault) the tick becomes a clean drain:
         admissions stop, running slots complete, queued requests cancel."""
@@ -551,6 +555,11 @@ class ServingEngine:
         statusz server, and retract this engine's gauges from the shared
         telemetry counter space."""
         self.drain(serve_queued=serve_queued)
+        m = self.metrics
+        log_dist(f"serving: shut down after {m.ticks} ticks: "
+                 f"{m.decode_ticks} decode steps read, {m.pipelined_ticks} of "
+                 f"them dispatched behind the one before, {m.sampled_ticks} "
+                 f"sampled, {m.dropped_rows} rows dropped", ranks=[0])
         if self.monitor is not None:
             self.monitor.close()
         tcfg = self.config.telemetry

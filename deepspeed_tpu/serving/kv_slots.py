@@ -13,10 +13,14 @@ exactly once.
 
 ``SlotPool`` owns the device arrays plus the host-side per-slot registers
 (length counter, pending token, temperature) that the scheduler feeds to
-``InferenceEngine.slot_decode_step`` each tick.
+``InferenceEngine.slot_decode_dispatch`` each tick. The scheduler keeps one
+decode step in flight, so a slot has two lengths while its step is out:
+``lengths`` counts the columns whose token was DELIVERED (what a donated or
+handed-off lane holds), ``dispatched`` the columns a step was sent for (where
+the next step writes).
 """
 
-from typing import List, Optional
+from typing import Collection, List, Optional
 
 import numpy as np
 
@@ -34,6 +38,8 @@ class SlotPool:
                                            quantize=self.quantized)
         # host-side slot registers, mirrored into device arrays each tick
         self.lengths = np.zeros((num_slots,), np.int32)   # tokens in cache
+        # ... counting the step in flight: ``lengths``, or one more
+        self.dispatched = np.zeros((num_slots,), np.int32)
         self.pending = np.zeros((num_slots,), np.int32)   # next token to feed
         self.temps = np.zeros((num_slots,), np.float32)
         # per-request sampling registers: top-k / top-p truncation and the
@@ -66,7 +72,7 @@ class SlotPool:
         if self.requests[slot] is None and slot in self._free:
             return
         self.requests[slot] = None
-        self.lengths[slot] = 0
+        self.set_length(slot, 0)
         self.pending[slot] = 0
         self.temps[slot] = 0.0
         self.top_ks[slot] = 0
@@ -82,8 +88,11 @@ class SlotPool:
         the valid-column count; the per-tick dummy decode write for a
         parked slot lands at column ``lengths[slot]`` — one column past
         the cached content, exactly where a reusing request prefills or
-        decodes first, so the cached prefix itself is never clobbered."""
+        decodes first, so the cached prefix itself is never clobbered. So
+        does the row of a step that was in flight when the request ended
+        (by EOS: learnt of one step late), which is computed and dropped."""
         self.requests[slot] = None
+        self.dispatched[slot] = self.lengths[slot]
         self.pending[slot] = 0
         self.temps[slot] = 0.0
         self.top_ks[slot] = 0
@@ -98,12 +107,18 @@ class SlotPool:
         greedy defaults) — its temperature/top-k/top-p/seed become this
         slot's per-tick registers."""
         self.requests[slot] = request
-        self.lengths[slot] = length
+        self.set_length(slot, length)
         self.pending[slot] = first_token
         self.temps[slot] = getattr(sampling, "temperature", 0.0)
         self.top_ks[slot] = getattr(sampling, "top_k", 0)
         self.top_ps[slot] = getattr(sampling, "top_p", 1.0)
         self.seeds[slot] = getattr(sampling, "seed", 0)
+
+    def set_length(self, slot: int, length: int):
+        """The valid columns of a slot no step is in flight for (free,
+        parked, mid-prefill, just bound): a dummy row lands one past
+        them."""
+        self.lengths[slot] = self.dispatched[slot] = length
 
     # ------------------------------------------------------------ queries
     def slot_nbytes(self) -> int:
@@ -138,3 +153,26 @@ class SlotPool:
         are dropped by the scheduler."""
         return (self.pending.copy(), self.lengths.copy(), self.temps.copy(),
                 self.top_ks.copy(), self.top_ps.copy(), self.seeds.copy())
+
+    def dispatch_arrays(self, slots: List[int], fed: Collection[int]):
+        """``decode_arrays`` for the step the scheduler sends next, which
+        advances ``slots`` alone, and ``from_host`` [S] behind them: the
+        rows whose token is ``toks[s]``, all but the slots in ``fed``, whose
+        token the step in flight holds on the device (none after an idle
+        pool; a slot bound since that step was sent is not among them).
+        Every slot that takes no part carries a dummy row (token 0, greedy)
+        at the column one past what it holds, clamped into the lane: a
+        bound slot left out because its request ends with the step in
+        flight keeps its lane whole for ``_release_slot``. Counts the step
+        as dispatched."""
+        live = np.zeros((self.num_slots,), bool)
+        live[slots] = True
+        from_host = np.ones_like(live)
+        from_host[[s for s in slots if s in fed]] = False
+        positions = np.minimum(self.dispatched, self.max_model_len - 1)
+        self.dispatched[slots] += 1
+        return (np.where(live, self.pending, 0), positions,
+                np.where(live, self.temps, np.float32(0)),
+                np.where(live, self.top_ks, 0),
+                np.where(live, self.top_ps, np.float32(1)),
+                np.where(live, self.seeds, 0), from_host)

@@ -103,6 +103,8 @@ class ServingMetrics:
         self.ticks = 0
         self.decode_ticks = 0     # ticks that ran a decode step
         self.sampled_ticks = 0    # ... with a slot at temperature > 0
+        self.pipelined_ticks = 0  # ... sent while another step was in flight
+        self.dropped_rows = 0     # rows computed for a request that had ended
         self.handoffs_in = 0      # KV lanes received into this pool
         self.handoffs_out = 0     # KV lanes extracted and handed off
         self.handoffs_refused = 0  # lanes rejected at a weights_version
@@ -219,15 +221,29 @@ class ServingMetrics:
         self._token_t.append(self._now())
         self.tokens_out += n_active
 
-    def record_decode_tick(self, sampled: bool):
+    def record_decode_tick(self, sampled: bool, pipelined: bool = False):
         """One decode tick, plain or speculative. ``sampled``: some slot's
         temperature is on, so the tick's sampler runs its sort-and-draw
         branch (``inference/speculative.py:sample_rows``); the share of
-        ``serve/sampled_ticks`` in ``serve/decode_ticks`` is how often."""
+        ``serve/sampled_ticks`` in ``serve/decode_ticks`` is how often.
+        ``pipelined``: the tick's step was dispatched while the step before
+        it was still in flight, so the device went from one to the next
+        with no host in between; ``serve/decode_ticks`` less
+        ``serve/pipelined_ticks`` is the steps that followed an idle pool."""
         self.decode_ticks += 1
         self.sampled_ticks += bool(sampled)
+        self.pipelined_ticks += bool(pipelined)
         self._gauge("serve/decode_ticks", self.decode_ticks)
         self._gauge("serve/sampled_ticks", self.sampled_ticks)
+        self._gauge("serve/pipelined_ticks", self.pipelined_ticks)
+
+    def record_dropped_rows(self, n: int):
+        """``n`` rows of a decode step were computed for a request that had
+        ended before the step was read (by EOS or its deadline, learnt of
+        one step late) and dropped."""
+        if n:
+            self.dropped_rows += n
+            self._gauge("serve/dropped_rows", self.dropped_rows)
 
     def record_tenant_tokens(self, tenant, n: int = 1):
         """Attribute ``n`` decode tokens to ``tenant`` (the aggregate

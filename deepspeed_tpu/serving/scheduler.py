@@ -4,10 +4,13 @@ Orca-style iteration-level scheduling on static XLA shapes: each ``tick()``
 (1) expires requests past their deadline, (2) admits queued requests into
 free slots — prefill writes the prompt's K/V into the slot's cache lane and
 samples the request's FIRST token (so TTFT is one prefill away from
-admission), and (3) runs ONE fused decode step over all active slots,
-advancing every in-flight request by one token. Requests retire on EOS or
-max-tokens and their slot returns to the free list for the next admission —
-no compiled shape ever changes.
+admission), and (3) advances every in-flight request by one token of ONE
+fused decode step over all slots — the step dispatched a tick earlier,
+read only after the next one has been dispatched behind it, fed on the
+device (``_decode``: a pipeline one step deep, so the device does not wait
+for the host between steps). Requests retire on EOS or max-tokens and their
+slot returns to the free list for the next admission — no compiled shape
+ever changes.
 """
 
 import dataclasses
@@ -153,6 +156,16 @@ class Request:
         """prompt + generated tokens."""
         return np.concatenate(
             [self.prompt, np.asarray(self.tokens, np.int32)])
+
+
+@dataclasses.dataclass
+class _Flight:
+    """A decode step that was dispatched and is not read yet."""
+    out: object                 # its output, on the device
+    rows: list                  # (slot, request) it advances
+    positions: np.ndarray       # [S] the column each row was fed at
+    sampled: bool               # some row's temperature is on
+    pipelined: bool             # sent while another step was in flight
 
 
 class TenantQueues:
@@ -387,6 +400,8 @@ class ContinuousBatchingScheduler:
                     // max(1, config.num_slots)
             self.cost.slot_bytes = slot_bytes
         self._tick_no = 0
+        #: the decode step dispatched and not read yet (``_decode``)
+        self._flight: Optional[_Flight] = None
         # per-request async spans (queue → prefill → decode → complete)
         # land in the same trace as train/comm spans
         self.tracer = get_tracer()
@@ -758,7 +773,7 @@ class ContinuousBatchingScheduler:
         # dummy decode writes for an unbound slot land at column
         # lengths[slot] — keep it one past the valid prefix so the next
         # chunk (which starts exactly there) overwrites the garbage
-        self.pool.lengths[slot] = start
+        self.pool.set_length(slot, start)
         self.prefilling[slot] = req
         return self._chunk_step(slot, req)
 
@@ -791,7 +806,7 @@ class ContinuousBatchingScheduler:
                 self.cost.charge_prefill(self.cost.record_for(req),
                                          self.clock() - t0, chunk)
             req.prefill_pos = p + chunk
-            self.pool.lengths[slot] = req.prefill_pos
+            self.pool.set_length(slot, req.prefill_pos)
             if ctx is not None:
                 ctx.mark("prefill_chunk")
             return chunk
@@ -968,7 +983,7 @@ class ContinuousBatchingScheduler:
                      args={"handed_off": True})
         # the lane was only written, never bound: park it in the prefix
         # cache (or free it) before the sink possibly re-enters us
-        self.pool.lengths[slot] = int(req.prompt.size)
+        self.pool.set_length(slot, int(req.prompt.size))
         self._release_slot(slot, req, donate_seq=req.prompt)
         self.metrics.record_handoff_out()
         if self.handoff_sink is None:
@@ -978,32 +993,53 @@ class ContinuousBatchingScheduler:
         self.handoff_sink(handoff, req)
 
     def _decode(self):
-        """One fused decode step over all slots; retire on EOS/max."""
+        """One decode tick of a pipeline one step deep: the step that
+        advances every slot that goes on is DISPATCHED, fed on the device
+        by the step in flight, and only then is the step in flight waited
+        on, read and delivered — so the device runs the next step while the
+        host delivers this one, closes the tick, admits, and comes back.
+        A tick that finds nothing in flight (the pool was idle) sends two.
+
+        What a dispatch needs is known a step early: every slot in a step
+        advances one column, and a request that ends by ``max_new_tokens``
+        is left out of the step after its last. An ending learnt of from
+        the token itself (EOS) or between ticks (a deadline) comes one step
+        late: the request's row of the step already in flight is computed
+        and dropped, its write one column past what the lane holds
+        (``SlotPool.retire_to_cache``)."""
         active = self.pool.active_slots
         if not active:
+            self._settle()
             return
-        # a free slot's temperature is 0: what the step's sampler will see
-        self.metrics.record_decode_tick((self.pool.temps > 0).any())
         if self.spec is not None:
+            # a free slot's temperature is 0: what the sampler will see
+            self.metrics.record_decode_tick((self.pool.temps > 0).any())
             return self._decode_speculative(active)
         tr = self.tracer
-        with tr.phase("serve/decode_prep", len(active)):
-            toks, positions, temps, top_ks, top_ps, seeds = \
-                self.pool.decode_arrays()
+        pool = self.pool
         t0 = self.clock()
         with tr.span("decode_step", cat="serving",
                      args={"n_active": len(active), "tick": self._tick_no,
                            "replica": self.replica_name}
                      if tr.enabled else None):
-            # slot_decode_step returns host ndarrays (already synced)
-            self.pool.cache, nxt = self.engine.slot_decode_step(
-                self.pool.cache, toks, positions, temps,
-                top_ks=top_ks, top_ps=top_ps, seeds=seeds)
+            flight = self._flight or self._dispatch(active)
+            # the rows of the step in flight that are still their request's
+            # (not timed out, not ended by the token before)
+            rows = [(slot, req) for slot, req in flight.rows
+                    if pool.requests[slot] is req]
+            ahead = {slot for slot, _ in rows}
+            going = [s for s in active
+                     if len(pool.requests[s].tokens) + (s in ahead)
+                     < pool.requests[s].max_new_tokens]
+            self._flight = self._dispatch(going, flight, ahead) \
+                if going else None
+            nxt = self.engine.slot_decode_read(flight.out)
         self._record_routing("serve/moe_decode")
+        positions = flight.positions
         if self._kv_live:
-            # columns the step just read that hold a token of an active
-            # slot: over the full-length lanes, over the rings (0: none)
-            live = positions[active] + 1
+            # columns the step just read that hold a token of one of its
+            # slots: over the full-length lanes, over the rings (0: none)
+            live = positions[[slot for slot, _ in flight.rows]] + 1
             now = time.perf_counter_ns()
             tr.record_phase("serve/kv_live", now, now, int(live.sum()),
                             int(np.minimum(live, self._ring_window).sum()))
@@ -1017,21 +1053,20 @@ class ContinuousBatchingScheduler:
         now = time.perf_counter_ns()
         tr.record_phase("serve/kv_read", now, now, read, pool_cols)
         dt = self.clock() - t0
-        self.metrics.record_decode_step(dt, len(active))
+        self.metrics.record_decode_tick(flight.sampled, flight.pipelined)
+        self.metrics.record_dropped_rows(len(flight.rows) - len(rows))
+        self.metrics.record_decode_step(dt, len(rows))
         if self.cost is not None:
-            # every active slot emits exactly one token this tick: the
-            # fused step's wall splits equally (weight 1 each). Charged
-            # BEFORE the retire loop, while every slot is still bound.
+            # every delivered row emits exactly one token this tick: the
+            # tick's decode wall splits equally (weight 1 each)
             self.cost.charge_decode(
-                dt, [(self.cost.record_for(self.pool.requests[s]), 1)
-                     for s in active])
+                dt, [(self.cost.record_for(req), 1) for _, req in rows])
         now = self.clock()
-        with tr.phase("serve/deliver", len(active)) as deliver:
-            for slot in active:
-                req = self.pool.requests[slot]
+        with tr.phase("serve/deliver", len(rows)) as deliver:
+            for slot, req in rows:
                 tok = int(nxt[slot])
-                self.pool.lengths[slot] += 1  # fed token's K/V is in cache
-                self.pool.pending[slot] = tok
+                pool.lengths[slot] += 1     # fed token's K/V is in cache
+                pool.pending[slot] = tok
                 finishing = self._should_finish(req, tok, pending=1)
                 if finishing and req.trace is not None:
                     # the token loop ends here; what follows (final
@@ -1044,6 +1079,35 @@ class ContinuousBatchingScheduler:
                     self._finish(req, RequestState.FINISHED, now)
                     self._release_slot(slot, req)
                     deliver.b += 1
+        if self._flight is not None and not pool.active_slots:
+            self._settle()
+
+    def _dispatch(self, slots, behind=None, fed=()):
+        """Send the decode step that advances ``slots``. ``behind`` is the
+        step in flight and ``fed`` the slots whose token it holds for this
+        one, taken on the device; after an idle pool there is neither and
+        every token is the host's. Returns the step un-read."""
+        pool = self.pool
+        with self.tracer.phase("serve/decode_prep", len(slots)):
+            rows = [(slot, pool.requests[slot]) for slot in slots]
+            toks, positions, temps, top_ks, top_ps, seeds, from_host = \
+                pool.dispatch_arrays(slots, fed)
+        pool.cache, out = self.engine.slot_decode_dispatch(
+            pool.cache, toks, positions, temps, top_ks=top_ks,
+            top_ps=top_ps, seeds=seeds,
+            prev=None if behind is None else behind.out,
+            from_host=from_host)
+        return _Flight(out, rows, positions, bool((temps > 0).any()),
+                       behind is not None)
+
+    def _settle(self):
+        """No slot is live: the step in flight, if there is one, holds
+        only rows of requests that have ended. It is dropped un-read (the
+        pool it returned is the pool), so ``run_until_idle``, ``drain`` and
+        ``shutdown`` come back with nothing in flight."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self.metrics.record_dropped_rows(len(flight.rows))
 
     def _decode_speculative(self, active):
         """One speculative tick: the draft proposes k tokens per slot

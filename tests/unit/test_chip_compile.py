@@ -50,6 +50,16 @@ def one_chip(topo):
     compilation_cache.reset_cache()
 
 
+def _fed_back(vi):
+    """The two arguments behind a decode step's six registers, as shapes
+    placed like ``vi`` [slots]: ``prev`` (the output of the step before:
+    its tokens, and where the model routes two stats behind them) and
+    ``from_host``."""
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(    # noqa: E731
+        shape, dtype, sharding=vi.sharding)
+    return like((vi.shape[0] + 2,), jnp.int32), like(vi.shape, jnp.bool_)
+
+
 def _compile(fn, one_chip, shape, dtype, grad=True):
     x = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     step = fn
@@ -209,10 +219,11 @@ def test_slot_decode_writes_its_rows_in_place(one_chip):
     pool = jax.tree.map(
         lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
     vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
-    # dec(params, pool, toks, positions, temps, top_ks, top_ps, seeds)
+    # dec(params, pool, toks, positions, temps, top_ks, top_ps, seeds,
+    #     prev, from_host)
     compiled = jax.jit(
         fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        params, pool, vi, vi, vf, vi, vf, vi).compile()
+        params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi)).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pool_nbytes(pool)
     assert mem.temp_size_in_bytes < pool_nbytes(pool) // slots
@@ -278,7 +289,7 @@ def test_routed_decode_reads_expert_leaves_in_place(one_chip, program):
         zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
         engine.slot_decode_step(tiny, zi, zi, zf)
         fn = engine._slot_fns[("slot_decode", 1, max_len)]
-        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        args = (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
     else:
         engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
         fn = engine._slot_fns[("slot_prefill", 1, max_len)]
@@ -361,7 +372,7 @@ def test_hybrid_stack_reads_every_leaf_in_place(one_chip, program):
         zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
         engine.slot_decode_step(tiny, zi, zi, zf)
         fn = engine._slot_fns[("slot_decode", 1, max_len)]
-        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        args = (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
         first = len(jax.tree.leaves(params))
     else:
         engine.slot_prefill(tiny, 0, np.zeros(1, np.int32))
@@ -444,7 +455,7 @@ def test_window_rings_are_written_and_read_in_place(one_chip, program):
         zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
         engine.slot_decode_step(tiny, zi, zi, zf)
         fn = engine._slot_fns[("slot_decode", 1, max_len)]
-        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        args = (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
         first = len(jax.tree.leaves(params))
         limit = 160 * 2 ** 20
     else:
@@ -532,7 +543,7 @@ def test_latent_pool_is_written_and_attended_in_place(one_chip, program):
         zi, zf = np.zeros(1, np.int32), np.zeros(1, np.float32)
         engine.slot_decode_step(tiny, zi, zi, zf)
         fn = engine._slot_fns[("slot_decode", 1, max_len)]
-        args = (params, pool, vi, vi, vf, vi, vf, vi)
+        args = (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
         first = len(jax.tree.leaves(params))
         # a layer's scores and probabilities [48, 32, 8192] in float32
         limit = 768 * 2 ** 20
@@ -637,7 +648,7 @@ def test_decode_step_takes_the_length_aware_kernel_in_place(
     vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
     compiled = jax.jit(
         fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        params, pool, vi, vi, vf, vi, vf, vi).compile()
+        params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi)).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     calls = re.findall(
         r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", text, re.M)
@@ -689,7 +700,7 @@ def test_decode_step_over_a_mesh_compiles_on_the_xla_attend(
         compiled = jax.jit(
             fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums,
             out_shardings=(jax.tree.map(moved, pool_sh), None)).lower(
-            params, pool, vi, vi, vf, vi, vf, vi).compile()
+            params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi)).compile()
     text = compiled.as_text()
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text         # the row-parallel matmuls' sum
@@ -735,7 +746,7 @@ def _longprompt_text(one_chip, monkeypatch, programs, program):
         vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
         si, sf = on_chip((), jnp.int32), on_chip((), jnp.float32)
         if program == "jit_dec":
-            args = (params, pool, vi, vi, vf, vi, vf, vi)
+            args = (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
         else:
             args = (params, on_chip((1, bucket), jnp.int32), pool, si, si,
                     sf, si, sf, si)

@@ -327,8 +327,8 @@ def test_compiled_decode_step_updates_the_donated_pool_in_place():
     vf = jax.ShapeDtypeStruct((slots,), jnp.float32)
     compiled = jax.jit(
         fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        shaped(engine.params), shaped(pool), vi, vi, vf, vi, vf,
-        vi).compile()
+        shaped(engine.params), shaped(pool), vi, vi, vf, vi, vf, vi,
+        vi, jax.ShapeDtypeStruct((slots,), jnp.bool_)).compile()
     first = len(jax.tree.leaves(engine.params))
     assert donated_params_from_hlo(compiled.as_text()) == {first, first + 1}
     mem = compiled.memory_analysis()

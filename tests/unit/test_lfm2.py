@@ -349,6 +349,19 @@ def test_generate_runs_the_cached_forward():
     assert out[0, 13:].tolist() == got
 
 
+def test_recurrent_streams_hold_through_the_decode_pipeline():
+    """The scheduler keeps one decode step in flight: a request that times
+    out leaves a row behind it that is computed and dropped, and it pushes
+    into the slot's conv state like the dummy row of a free slot does. The
+    request bound to that slot in the same tick prefills from nothing and
+    streams ``generate()``'s tokens, as do the others."""
+    from .test_serving import serve_past_a_deadline
+    engine, _ = engine_of()
+    m = serve_past_a_deadline(
+        engine, [IDS[0, :7], IDS[1, :19], IDS[0, 20:31], IDS[1, 5:10]])
+    assert m.dropped_rows == 1 and m.pipelined_ticks == m.decode_ticks - 1
+
+
 def test_rules_cover_the_new_leaves():
     """Every parameter and every pool leaf meets a rule of its own rank."""
     from deepspeed_tpu.models.api import match_rule, param_path_tree

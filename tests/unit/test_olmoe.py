@@ -243,6 +243,29 @@ def test_served_tokens_equal_generate_and_routing_is_taken_once():
     assert nxt.shape == (2,) and engine.take_routing() is not None
 
 
+def test_routed_streams_hold_through_the_decode_pipeline():
+    """The scheduler keeps one decode step in flight (a request that times
+    out leaves a row behind it that is computed and dropped; its slot is
+    bound again under that step): a routed model's streams are still
+    ``generate()``'s, its stats still ride one read-back a step, read with
+    their own step's tokens, and ``serve/moe_decode`` is one record a step
+    read."""
+    import time
+    import deepspeed_tpu
+    from deepspeed_tpu.telemetry import get_tracer
+    from .test_serving import serve_past_a_deadline
+    engine = deepspeed_tpu.init_inference(
+        tiny(), config={"dtype": "float32", "max_tokens": 64})
+    mark = time.perf_counter_ns()
+    m = serve_past_a_deadline(
+        engine, [IDS[0, :7], IDS[1, :19], IDS[0, 20:31], IDS[1, 5:10]])
+    assert m.dropped_rows == 1 and m.pipelined_ticks == m.decode_ticks - 1
+    records = [r for r in get_tracer().phases()
+               if r[1] >= mark and r[0] == "serve/moe_decode"]
+    assert len(records) == m.decode_ticks
+    assert engine.take_routing() is None        # each was taken, once
+
+
 def test_int8_weights_cover_the_expert_leaves():
     """The program's own lower precision (the cell's control) quantizes the
     experts and the router, and the routed path runs on them."""

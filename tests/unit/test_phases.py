@@ -158,18 +158,34 @@ def test_tick_with_one_prefill_holds_every_phase(engine, monkeypatch):
             "serve/decode_dispatch", "serve/decode_wait", "serve/kv_read",
             "serve/deliver", "serve/bookkeeping", "serve/tick"}
     assert {r[0] for r in recs} == want
-    assert 13 <= len(recs) <= 15
     by = {}
     for r in recs:
         by.setdefault(r[0], []).append(r)
+    # the tick found nothing in flight, so it sends TWO decode steps (the
+    # scheduler's own prep, then the engine's prep and dispatch, each) and
+    # waits on the first alone: 6 records of the admission, 6 of the two
+    # dispatches, wait, kv_read, deliver, two bookkeepings, the tick
+    assert len(recs) == 18
+    assert [len(by[n]) for n in ("serve/decode_prep", "serve/decode_dispatch",
+                                 "serve/decode_wait", "serve/kv_read",
+                                 "serve/deliver")] == [4, 2, 1, 1, 1]
     prep, = by["serve/prefill_prep"]
     assert prep[3:] == (11, 16)                 # prompt tokens, pow2 bucket
     assert by["serve/prefill_dispatch"][0][3] == 16
     assert by["serve/first_token"][0][3] == rid
     assert by["serve/admit"][0][3:] == (1, 1)   # admitted, queue depth
     assert by["serve/queue_wait"][0][3:] == (rid, 11)
-    assert by["serve/decode_prep"][0][3] == 1   # slots active
+    # slots each step advances: the request goes on after the first
+    assert [r[3] for r in by["serve/decode_prep"]] == [1, 0, 1, 0]
     assert by["serve/deliver"][0][3:] == (1, 0)
+    # it dispatches before it waits: the second step is sent, fed by the
+    # first on the device, before the first is read; then the delivery
+    wait, = by["serve/decode_wait"]
+    first, second = by["serve/decode_dispatch"]
+    assert first[2] <= second[1] and second[2] <= wait[1]
+    assert wait[2] <= by["serve/kv_read"][0][1] <= by["serve/deliver"][0][1]
+    assert srv.scheduler._flight is not None    # the second, un-read
+    assert len(srv.result(rid).tokens) == 2
     # every host phase nests in the tick, the prefill's in serve/admit
     host = [r for r in recs[:-1] if r[0] != "serve/queue_wait"]
     assert all(tick[1] <= r[1] <= r[2] <= tick[2] for r in host)
@@ -222,6 +238,11 @@ def test_routed_model_tick_records_its_routing_and_a_dense_one_none(engine):
     assert tick[1] <= prefill[1] <= decode[1] <= tick[2]
     wait, = by["serve/prefill_wait"]
     assert wait[2] <= prefill[1]        # taken after the token was read
+    # a decode step's stats are read with ITS tokens: after the wait, which
+    # comes after the next step's dispatch (the tick sends two, reads one)
+    wait, = by["serve/decode_wait"]
+    assert len(by["serve/decode_dispatch"]) == 2
+    assert by["serve/decode_dispatch"][1][2] <= wait[1] <= wait[2] <= decode[1]
 
 
 def test_queue_wait_spans_the_ticks_a_request_waited(engine):
@@ -310,7 +331,8 @@ def test_serving_program_names_are_what_the_cell_files_read(engine):
         dec_text = dec.lower(engine.params, pool, vec(jnp.int32),
                              vec(jnp.int32), vec(jnp.float32),
                              vec(jnp.int32), vec(jnp.float32),
-                             vec(jnp.int32)).as_text()
+                             vec(jnp.int32), vec(jnp.int32),
+                             vec(jnp.bool_)).as_text()
     module = lambda text: re.search(r"module @(\w+)", text).group(1)
     assert module(pf_text) == "jit_pf"
     assert module(dec_text) == "jit_dec"

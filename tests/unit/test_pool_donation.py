@@ -279,7 +279,8 @@ BUILT = {
     "slot_extract_lane": ("slot_extract", "ex", ("pool", "slot")),
     "slot_insert_lane": ("slot_insert", "ins", ("pool", "lane", "slot")),
     "slot_decode_step": ("slot_decode", "dec",
-                         ("params", "pool") + _DECODE_ARGS),
+                         ("params", "pool") + _DECODE_ARGS
+                         + ("prev", "from_host")),
     "slot_verify_step": ("slot_verify", "ver",
                          ("params", "pool", "toks", "draft_toks")
                          + _DECODE_ARGS[1:]),

@@ -90,6 +90,277 @@ def test_eos_retires_and_slot_is_reused(engine):
             assert (gen[len(req.tokens):] == eos).all()
 
 
+# ------------------------------------------------- the one-step decode pipeline
+
+def _generated(engine, prompt, n, eos=None):
+    """``engine.generate``'s tokens after the prompt: the parent's order of
+    work, one request at a time, nothing in flight."""
+    out = np.asarray(engine.generate(prompt[None], max_new_tokens=n,
+                                     eos_token_id=eos))[0]
+    return out[len(prompt):].tolist()
+
+
+def _ends_by_eos(engine, length, n, least=2):
+    """(prompt, its ``n`` greedy tokens, k): a prompt of ``length`` whose
+    token ``k`` (``least`` <= k < n - 1) equals none before it, so that as
+    the EOS id it ends the request exactly there, short of
+    ``max_new_tokens``. The tiny model repeats itself: most prompts have no
+    such token, so seeds are tried in order."""
+    for seed in range(64):
+        prompt = _prompts((length,), seed=seed)[0]
+        ref = _generated(engine, prompt, n)
+        for k in range(least, n - 1):
+            if ref[k] not in ref[:k]:
+                return prompt, ref, k
+    raise AssertionError("no prompt of that length ends by a fresh token")
+
+
+def _pipeline_counts(srv):
+    m = srv.metrics
+    return m.decode_ticks, m.pipelined_ticks, m.dropped_rows
+
+
+def test_pipelined_streams_are_generates_whatever_ends_a_request(engine):
+    """A decode step is dispatched before the step before it is read, so
+    an ending the host learns from a token (EOS) or between ticks (a
+    deadline) comes one step late, and an admission joins a pipeline that
+    is running. None of it shows in the tokens: two slots serve an EOS
+    ending, a ``max_new_tokens`` ending, a deadline, a cancelled request,
+    admissions in mid-flight and a slot bound again in the very tick its
+    request timed out (the step in flight still holds the old request's
+    row), each stream bitwise ``engine.generate``'s. The two gauges count
+    what happened: every step but the first was sent behind another, and
+    exactly the EOS ending's and the deadline's rows were dropped."""
+    from deepspeed_tpu.telemetry import get_tracer
+    now = [0.0]
+    srv = ServingEngine(engine, {"num_slots": 2, "max_model_len": 64},
+                        clock=lambda: now[0])
+    pb, pc, pd, pe, pf = _prompts((9, 3, 12, 4, 7), seed=11)
+    pa, ref_a, k = _ends_by_eos(engine, 5, 6)
+    eos = ref_a[k]
+    ra = srv.submit(pa, SamplingParams(max_new_tokens=6, eos_token_id=eos))
+    rb = srv.submit(pb, SamplingParams(max_new_tokens=4))
+    rc = srv.submit(pc, SamplingParams(max_new_tokens=8, timeout_s=5))
+    rd = srv.submit(pd, SamplingParams(max_new_tokens=5))
+    re_ = srv.submit(pe, SamplingParams(max_new_tokens=5))
+    pool = srv.scheduler.pool
+    # one admission a tick: B and C join while A's steps are in flight
+    while srv.result(rc).state is RequestState.QUEUED:
+        srv.step()
+    assert srv.result(ra).state is RequestState.FINISHED    # by EOS
+    assert srv.cancel(re_) and not srv.cancel(rc)           # queued / running
+    while srv.result(rb).state is not RequestState.FINISHED:
+        srv.step()
+    slot_c = pool.requests.index(srv.result(rc))
+    assert srv.scheduler._flight is not None
+    now[0] = 10.0                       # past C's deadline, a step in flight
+    srv.step()
+    assert srv.result(rc).state is RequestState.TIMEOUT
+    assert pool.requests[slot_c] is srv.result(rd)          # the very slot
+    rf = srv.submit(pf, SamplingParams(max_new_tokens=3))   # mid-flight
+    srv.run_until_idle()
+    assert srv.scheduler._flight is None and pool.free_count == 2
+    assert srv.result(ra).tokens == ref_a[:k + 1]
+    assert srv.result(rb).tokens == _generated(engine, pb, 4)
+    got_c = srv.result(rc).tokens
+    assert 1 <= len(got_c) < 8 and got_c == _generated(engine, pc, 8)[:len(got_c)]
+    assert srv.result(rd).tokens == _generated(engine, pd, 5)
+    assert srv.result(re_).state is RequestState.CANCELLED
+    assert srv.result(re_).tokens == []
+    assert srv.result(rf).tokens == _generated(engine, pf, 3)
+    assert srv.decode_executables() == 1
+    ticks, pipelined, dropped = _pipeline_counts(srv)
+    assert pipelined == ticks - 1       # the pool was idle once: at the start
+    assert dropped == 2                 # A's row after its EOS, C's at its deadline
+    tr = get_tracer()
+    assert tr.counter_value("serve/pipelined_ticks") == pipelined
+    assert tr.counter_value("serve/dropped_rows") == dropped
+    srv.shutdown()
+    assert tr.counter_value("serve/pipelined_ticks") is None
+
+
+def serve_past_a_deadline(engine, prompts):
+    """Two slots serve four ``prompts``, one admission a tick: the second
+    request times out with a step in flight, the third is bound to its slot
+    in that very tick (the step in flight still holds the old request's
+    row, and where the model keeps a recurrent state that row pushes into
+    the slot's), the fourth joins in mid-flight. Every stream is
+    ``engine.generate``'s; returns the metrics of the engine, shut down.
+    For the model families' own files (``test_olmoe.py``, ``test_lfm2.py``)."""
+    now = [0.0]
+    srv = ServingEngine(engine, {"num_slots": 2, "max_model_len": 64},
+                        clock=lambda: now[0])
+    first, late, third, fourth = prompts
+    rids = [srv.submit(first, SamplingParams(max_new_tokens=9)),
+            srv.submit(late, SamplingParams(max_new_tokens=9, timeout_s=5)),
+            srv.submit(third, SamplingParams(max_new_tokens=4))]
+    pool = srv.scheduler.pool
+    for _ in range(3):
+        srv.step()
+    slot = pool.requests.index(srv.result(rids[1]))
+    now[0] = 10.0
+    srv.step()
+    assert srv.result(rids[1]).state is RequestState.TIMEOUT
+    assert pool.requests[slot] is srv.result(rids[2])
+    rids.append(srv.submit(fourth, SamplingParams(max_new_tokens=5)))
+    srv.run_until_idle()
+    for rid, prompt, n in zip(rids, prompts, (9, 9, 4, 5)):
+        got = srv.result(rid).tokens
+        assert len(got) == n or rid == rids[1]
+        assert got and got == _generated(engine, prompt, n)[:len(got)]
+    assert srv.decode_executables() == 1 and srv.scheduler._flight is None
+    srv.shutdown()
+    return srv.metrics
+
+
+def test_sampled_rows_ride_the_pipeline_and_nothing_is_dropped_without_eos(
+        engine):
+    """A sampled request's token is fed to the next step on the device like
+    a greedy one's: its stream is what ``slot_prefill`` and then one
+    ``slot_decode_step`` at a time, each read before the next is sent, give
+    for the same ``(seed, position)`` keys. Where every request ends by
+    ``max_new_tokens`` the scheduler knows each ending a step early: no row
+    is computed for a request that has ended, and between two idle pools
+    every step but the first is pipelined."""
+    sp = SamplingParams(max_new_tokens=7, temperature=0.8, top_k=20, seed=5)
+    hot, cold = _prompts((6, 10), seed=12)
+    pool = engine.init_slot_pool(2, 64)
+    pool, tok = engine.slot_prefill(pool, 0, hot, temperature=0.8, top_k=20,
+                                    seed=5)
+    want, pos = [tok], len(hot)
+    while len(want) < 7:
+        pool, nxt = engine.slot_decode_step(
+            pool, np.array([want[-1], 0], np.int32),
+            np.array([pos, 0], np.int32), np.array([0.8, 0], np.float32),
+            top_ks=np.array([20, 0], np.int32), top_ps=np.ones(2, np.float32),
+            seeds=np.array([5, 0], np.int32))
+        want.append(int(nxt[0]))
+        pos += 1
+    srv = ServingEngine(engine, {"num_slots": 2, "max_model_len": 64})
+    for _ in range(2):                  # two busy periods, idle between
+        rh = srv.submit(hot, sp)
+        rc = srv.submit(cold, SamplingParams(max_new_tokens=4))
+        srv.run_until_idle()
+        assert srv.result(rh).tokens == want
+        assert srv.result(rc).tokens == _generated(engine, cold, 4)
+    ticks, pipelined, dropped = _pipeline_counts(srv)
+    assert dropped == 0 and pipelined == ticks - 2 and ticks >= 12
+    assert srv.decode_executables() == 1
+    srv.shutdown()
+
+
+@pytest.mark.parametrize("how", ["run_until_idle", "drain", "shutdown"])
+@pytest.mark.parametrize("ending", ["max_new_tokens", "eos"])
+def test_a_step_is_in_flight_after_step_and_none_once_idle(engine, how,
+                                                           ending):
+    """``step()`` returns with the next decode step on the device, un-read;
+    ``run_until_idle``, ``drain`` and ``shutdown`` return with none: an
+    ending known ahead leaves nothing dispatched behind the last step, one
+    learnt from the token leaves a step of dropped rows, which is let go."""
+    prompt, ref, k = _ends_by_eos(engine, 6, 8)
+    if ending == "max_new_tokens":
+        k = 7
+    srv = ServingEngine(engine, {"num_slots": 2, "max_model_len": 64})
+    rid = srv.submit(prompt, SamplingParams(
+        max_new_tokens=8, eos_token_id=ref[k] if ending == "eos" else None))
+    srv.step()
+    flight = srv.scheduler._flight
+    assert flight is not None and [s for s, _ in flight.rows] == [0]
+    assert len(srv.result(rid).tokens) == 2     # the prefill's and a step's
+    getattr(srv, how)()
+    assert srv.scheduler._flight is None
+    assert srv.result(rid).tokens == ref[:k + 1]
+    assert srv.metrics.dropped_rows == (ending == "eos")
+    assert srv.scheduler.pool.free_count == 2
+    if how != "shutdown":
+        srv.shutdown()
+
+
+@pytest.mark.parametrize("ending", ["max_new_tokens", "eos"])
+def test_rows_past_an_ended_request_land_inside_its_lane(engine, ending,
+                                                         monkeypatch):
+    """A request that fills its lane to the last column but one (prompt +
+    ``max_new_tokens`` = ``max_model_len``) ends while its neighbour goes
+    on. Every step after its last carries a row for its parked slot — the
+    dropped row of the step in flight at an EOS, then dummy rows — at the
+    column one past what the lane holds, never past ``max_model_len - 1``:
+    the columns it donated to the prefix cache stay bitwise what they were,
+    the donation holds the DELIVERED length (the step in flight counted
+    one more), and a later prompt that hits it streams what a cold prefill
+    streams."""
+    max_len = 32
+    other = _prompts((4,), seed=14)[0]
+    filler, ref, k = _ends_by_eos(engine, 24, 8, least=4)
+    if ending == "max_new_tokens":
+        k = 7
+    sp = SamplingParams(max_new_tokens=8,
+                        eos_token_id=ref[k] if ending == "eos" else None)
+    srv = ServingEngine(engine, {       # a third slot: no lane is evicted
+        "num_slots": 3, "max_model_len": max_len,
+        "prefix_cache": {"enabled": True, "min_prefix_len": 8}})
+    sched, inner = srv.scheduler, engine.slot_decode_dispatch
+    fed_at = []
+    monkeypatch.setattr(
+        engine, "slot_decode_dispatch", lambda pool, toks, positions, *a,
+        **kw: (fed_at.append(np.array(positions)),
+               inner(pool, toks, positions, *a, **kw))[1])
+    rf = srv.submit(filler, sp)
+    ro = srv.submit(other, SamplingParams(max_new_tokens=24))
+    while srv.result(rf).state is not RequestState.FINISHED:
+        srv.step()
+    assert srv.result(rf).tokens == ref[:k + 1]
+    held = len(filler) + k              # every token but the last is in the lane
+    entry, = sched.prefix_cache.entries.values()
+    assert entry.kv_len == held == sched.pool.lengths[entry.slot]
+    assert (held == max_len - 1) == (ending == "max_new_tokens")
+    lane = {name: np.array(leaf[:, entry.slot, :held], copy=True)
+            for name, leaf in sched.pool.cache.items()}
+    before = len(fed_at)
+    srv.run_until_idle()                # the neighbour's further steps
+    assert len(fed_at) - before >= 8
+    rows = np.stack(fed_at)[:, entry.slot]
+    assert rows.max() == held <= max_len - 1
+    assert (rows[before:] == held).all()    # one past what the lane holds
+    for name, leaf in sched.pool.cache.items():
+        np.testing.assert_array_equal(
+            np.asarray(leaf[:, entry.slot, :held]), lane[name])
+    assert srv.result(ro).tokens == _generated(engine, other, 24)
+    # a later prompt over the donated lane: its first `held` tokens
+    again = np.concatenate([srv.result(rf).output_ids[:held - 6],
+                            _prompts((3,), seed=15)[0]]).astype(np.int32)
+    rid = srv.submit(again, SamplingParams(max_new_tokens=4))
+    srv.run_until_idle()
+    assert sched.prefix_cache.hits == 1
+    assert srv.result(rid).tokens == _generated(engine, again, 4)
+    srv.shutdown()
+
+
+def test_dispatch_arrays_clamp_a_row_into_the_lane(engine):
+    """``SlotPool.dispatch_arrays``: a slot that takes no part in the step
+    carries a greedy dummy row at the column one past what it holds, and
+    that column is clamped to the lane's last whatever the registers say."""
+    from deepspeed_tpu.serving.kv_slots import SlotPool
+    pool = SlotPool(engine, 3, 16)
+    pool.bind(0, object(), 9, 41, SamplingParams(temperature=0.5, seed=3))
+    pool.bind(1, object(), 12, 42, SamplingParams(temperature=0.9, top_k=4))
+    pool.set_length(2, 16)              # past the end: never in a valid run
+    toks, pos, temps, top_ks, top_ps, seeds, from_host = \
+        pool.dispatch_arrays([0], fed=())
+    assert toks.tolist() == [41, 0, 0] and pos.tolist() == [9, 12, 15]
+    assert temps.tolist() == [0.5, 0, 0] and top_ks.tolist() == [0, 0, 0]
+    assert top_ps.tolist() == [1, 1, 1] and seeds.tolist() == [3, 0, 0]
+    assert from_host.all()              # nothing in flight to feed from
+    assert pool.dispatched.tolist() == [10, 12, 16]
+    assert pool.lengths.tolist() == [9, 12, 16]
+    # slot 0's token is in the step in flight; slot 1 was bound since
+    _, pos, *_, from_host = pool.dispatch_arrays([0, 1], fed={0, 2})
+    assert pos.tolist() == [10, 12, 15]
+    assert from_host.tolist() == [False, True, True]
+    for a in (toks, pos, top_ks, seeds):
+        assert a.dtype == np.int32
+    assert temps.dtype == top_ps.dtype == np.float32
+
+
 def test_backpressure_queue_full(engine):
     srv = ServingEngine(engine, {"num_slots": 1, "max_model_len": 64,
                                  "max_queue": 2,

@@ -648,9 +648,12 @@ def test_sampled_ticks_counter(engine, monkeypatch, speculative):
     """``serve/sampled_ticks`` of ``serve/decode_ticks``: how often a
     decode tick's sampler ran its sort-and-draw branch. Greedy requests
     leave it at 0 however many ticks run; one request at temperature 0.7
-    makes exactly the ticks it lives in count (its greedy neighbour's
-    further ticks do not); both gauges go with the engine that owns
-    them."""
+    makes exactly the steps that advance it count (its greedy neighbour's
+    further ticks do not): on the speculative path the ticks it lives in,
+    on the plain one, which keeps a step in flight, the three steps behind
+    its prefill's token — it joins the step dispatched in the tick that
+    admits it, which the next tick reads, so it lives one tick longer than
+    it has steps. Both gauges go with the engine that owns them."""
     from deepspeed_tpu.telemetry import get_tracer
     tr = get_tracer()
     tr.clear()
@@ -677,7 +680,9 @@ def test_sampled_ticks_counter(engine, monkeypatch, speculative):
                 for r in pool.requests)), decode())[1])
         srv.run_until_idle()
         assert srv.result(long_rid).state is RequestState.FINISHED
-        assert sum(lived) >= 1 and read("serve/sampled_ticks") == sum(lived)
+        hot_steps = sum(lived) if speculative else 4 - 1
+        assert sum(lived) >= hot_steps >= 1
+        assert read("serve/sampled_ticks") == hot_steps
         assert read("serve/decode_ticks") == greedy_ticks + len(lived)
         assert len(lived) > sum(lived)
     finally:
