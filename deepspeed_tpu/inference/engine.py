@@ -41,7 +41,8 @@ from .config import DeepSpeedInferenceConfig
 from .kv_quant import (QuantizedSlotPool, init_pool, insert_lane,
                        is_quantized_pool, lane_slice, lane_update,
                        pool_from_fp, pool_to_fp, read_lane, write_lane)
-from .speculative import row_keys, sample_rows, sampling_arrays
+from .speculative import (row_keys, sample_rows, sampling_arrays,
+                          unmask_rows)
 
 # compile-ledger labels of the pool programs whose key in ``_slot_fns``
 # spells its kind shorter; every other kind is its own label
@@ -802,7 +803,7 @@ class InferenceEngine:
         if any(x is None for x in sampling):
             sampling = tuple(
                 d if x is None else x for x, d in zip(
-                    sampling, sampling_arrays(len(np.reshape(toks, -1)))))
+                    sampling, sampling_arrays(len(np.reshape(positions, -1)))))
         return (jnp.asarray(toks, jnp.int32),
                 jnp.asarray(positions, jnp.int32),
                 *(jnp.asarray(x, dt) for x, dt in zip(
@@ -854,6 +855,12 @@ class InferenceEngine:
         if not 0 < t <= max_len:
             raise ValueError(f"prompt length {t} not in [1, {max_len}]")
         bucket = min(_next_pow2(t), max_len)
+        blocks = getattr(model, "block_length", 1) > 1
+        if blocks and t % model.block_length:
+            raise ValueError(
+                f"a prompt of {t} tokens is no whole number of blocks of "
+                f"{model.block_length}: the tokens left over open the first "
+                f"block (slot_block_dispatch)")
 
         @self._pool_program("slot_prefill", (bucket, max_len), shape, outs=1)
         def pf(params, ids, pool, slot, last_idx, temperature, top_k,
@@ -865,11 +872,16 @@ class InferenceEngine:
                 **self._real_length(last_idx))
             with jax.named_scope("kv_write"):
                 pool = write_lane(pool, mini, slot)
-            # the first token is FED at column last_idx + 1
-            with jax.named_scope("sample"):
-                last = jnp.take(logits[0], last_idx, axis=0)
-                tok = _sample_one(last, temperature, top_k, top_p, seed,
-                                  last_idx + 1, vocab)
+            if blocks:
+                # whole blocks of context: the first tokens are the first
+                # block's (``slot_block_dispatch``), the head is dead code
+                tok = jnp.int32(0)
+            else:
+                # the first token is FED at column last_idx + 1
+                with jax.named_scope("sample"):
+                    last = jnp.take(logits[0], last_idx, axis=0)
+                    tok = _sample_one(last, temperature, top_k, top_p, seed,
+                                      last_idx + 1, vocab)
             # routed experts: [token, touched, largest], one read-back
             return pool, \
                 jnp.concatenate([tok[None], *stats]) if stats else tok
@@ -1188,6 +1200,80 @@ class InferenceEngine:
         with self._tracer.phase("serve/decode_wait"):
             out = np.asarray(out)
             return self._read_back(out, out.shape[0] - 2 * self._routed)
+
+    # ------------------------------------------------- block-diffusion protocol
+    def slot_block_dispatch(self, pool, ids, flags, positions, temps,
+                            top_ks=None, top_ps=None, seeds=None, fix=1,
+                            prev=None, from_host=None):
+        """One PASS over all slots of a family that generates by diffusion
+        over blocks (``model.block_length`` B > 1): slot s's block ``ids[s]``
+        [B] stands at columns ``positions[s] .. positions[s] + B - 1``, the
+        ``[MASK]`` row at the positions ``flags[s]`` says are still masked.
+        The pass forwards it under the family's block mask, writes the B
+        columns' keys and values (a later pass of the block overwrites
+        them; the pass that finds nothing flagged leaves the final ones),
+        and fixes of each slot's flagged positions the ``fix`` most
+        confident (``speculative.unmask_rows``). Returns (new_pool, out)
+        with ``out`` on the device, un-read: the blocks and flags as they
+        stand after the pass (a routed model's two stats behind them).
+        ``prev`` / ``from_host`` as in ``slot_decode_dispatch``: a row whose
+        ``from_host`` is unset takes its block and flags from ``prev``, the
+        ``out`` of the pass before, on the device, so a scheduler sends
+        pass k + 1 before it reads pass k. ONE program a pool shape and
+        ``fix``, whatever the passes are of (unmasking or writing)."""
+        model = self.module
+        vocab = model.config.vocab_size
+        b, mask_id = model.block_length, model.mask_token_id
+        shape = self._pool_dims(pool)
+        num_slots = shape[0]
+
+        @self._pool_program("slot_block", shape[:2] + (fix,), shape, outs=1)
+        def blk(params, pool, ids, flags, positions, temps, top_ks, top_ps,
+                seeds, prev, from_host):
+            held = prev[:num_slots * 2 * b].reshape(num_slots, 2, b)
+            ids = jnp.where(from_host[:, None], ids, held[:, 0])
+            flags = jnp.where(from_host[:, None], flags, held[:, 1] > 0)
+            logits, fp, *stats = model.verify_with_slots(
+                params, jnp.where(flags, mask_id, ids),
+                pool_to_fp(pool, self.dtype), positions,
+                routing=self._routed)
+            # a position's key is its own column's, whatever the pass
+            with jax.named_scope("unmask"):
+                cols = positions[:, None] + jnp.arange(b)[None, :]
+                ids, flags = unmask_rows(
+                    logits, ids, flags, temps, top_ks, top_ps,
+                    row_keys(jnp.repeat(seeds, b), cols.reshape(-1)),
+                    vocab, fix)
+                out = jnp.stack([ids, flags.astype(jnp.int32)],
+                                axis=1).reshape(-1)
+            return pool_from_fp(fp, pool), \
+                jnp.concatenate([out, *stats]) if stats else out
+
+        def prep():
+            fed = jax.device_put(
+                np.zeros(num_slots * 2 * b + 2 * self._routed, np.int32),
+                NamedSharding(self.mesh, P())) if prev is None else prev
+            mask = np.ones(num_slots, bool) if from_host is None \
+                else from_host
+            block, pos, *sampling = self._slot_arrays(
+                ids, positions, temps, top_ks, top_ps, seeds)
+            return (self.params, pool, block, jnp.asarray(flags, bool), pos,
+                    *sampling, fed, jnp.asarray(mask, bool))
+
+        tr = self._tracer
+        return self._pool_call(
+            blk, prep, (tr.phase("serve/decode_prep"),
+                        tr.phase("serve/decode_dispatch")))
+
+    def slot_block_read(self, out):
+        """Wait for a dispatched pass and read it back: (ids [S, B], flags
+        [S, B] bool) as they stand after it (a routed model's stats are
+        kept for ``take_routing``)."""
+        with self._tracer.phase("serve/decode_wait"):
+            out = np.asarray(out)
+            out = self._read_back(out, out.shape[0] - 2 * self._routed)
+        out = out.reshape(-1, 2, self.module.block_length)
+        return out[:, 0], out[:, 1] > 0
 
     # -------------------------------------------- speculative decode protocol
     # Draft-model speculation over the slot pool (inference/speculative.py):

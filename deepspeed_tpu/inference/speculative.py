@@ -210,6 +210,33 @@ def sample_rows(logits, temps, top_ks, top_ps, keys, vocab):
     return lax.cond(jnp.any(temps > 0.0), sampled, lambda: greedy)
 
 
+def unmask_rows(logits, ids, flags, temps, top_ks, top_ps, keys, vocab,
+                fix: int):
+    """One denoising pass of a block of ``B`` positions a slot
+    (``models/sdar.py``; the published ``low_confidence_static`` rule).
+    logits [S, B, V_padded]; ids [S, B] int32 and flags [S, B] bool: the
+    block as it stands and which of it is still masked; temps / top_ks /
+    top_ps [S] and keys [S * B] (one a position). Every position draws an
+    id as ``sample_rows`` draws it (greedy where ``temps <= 0``); its
+    confidence is that id's probability under the soft-max of the row at
+    the slot's temperature (1 where greedy; before any truncation). Of a
+    slot's FLAGGED positions the ``fix`` most confident (all of them where
+    fewer are flagged; ties to the lower position) take their id and drop
+    their flag; no other position changes. Returns (ids, flags)."""
+    s, b = ids.shape
+    rep = lambda a: jnp.repeat(a, b)
+    rows = logits.reshape(s * b, -1)
+    x0 = sample_rows(rows, rep(temps), rep(top_ks), rep(top_ps), keys, vocab)
+    last = rows[:, :vocab].astype(jnp.float32) / \
+        jnp.where(temps > 0.0, temps, 1.0).repeat(b)[:, None]
+    conf = jnp.exp(jnp.take_along_axis(last, x0[:, None], axis=-1)[:, 0] -
+                   jax.nn.logsumexp(last, axis=-1)).reshape(s, b)
+    _, idx = lax.top_k(jnp.where(flags, conf, -1.0), fix)
+    take = jnp.zeros_like(flags).at[jnp.arange(s)[:, None], idx].set(True) \
+        & flags
+    return jnp.where(take, x0.reshape(s, b), ids), flags & ~take
+
+
 def sampling_arrays(n: int):
     """Neutral per-slot sampling registers (greedy, no truncation):
     (temps f32, top_ks i32, top_ps f32, seeds i32)."""

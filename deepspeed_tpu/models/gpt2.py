@@ -223,6 +223,16 @@ class GPT2Model(ModelSpec):
     #: ``k``, ``v=None`` and ``latent=`` the up-projection. A row per token,
     #: so the lane is valid up to any column, as K and V are
     latent_cache = ()
+    #: positions a pass of the serving tick advances a slot by: 1, a token
+    #: a step; a family that generates by diffusion over blocks
+    #: (``models/sdar.py``) says its block's length, and its mask keeps a
+    #: query's whole block (``_decode_attn_mask``)
+    block_length = 1
+    #: the pool leaves whose columns past the last whole block are
+    #: PROVISIONAL in such a family: every pass of a block writes its
+    #: columns and only the pass after the last unmasking leaves them
+    #: final, so a lane is valid up to a block's boundary and no further
+    denoised_blocks = ()
 
     @property
     def lane_leaves(self):
@@ -780,8 +790,15 @@ class GPT2Model(ModelSpec):
         return pool.at[layer, jnp.arange(s)[:, None], cols].set(
             new, mode="drop", unique_indices=True, indices_are_sorted=True)
 
+    #: the queries a slot from which ``_kv_attend`` sees stored rows that
+    #: hold several whole-lane heads as heads (one re-laying of the keys it
+    #: reads) and below which it lays each query into its own head's lanes:
+    #: a prefill against a decode step. A family whose tick carries a few
+    #: queries a slot (``models/sdar.py``: a block) raises it past them
+    _rows_as_heads_from = 2
+
     @staticmethod
-    def _kv_attend(q, k_pool, v_pool, layer, mask, bias):
+    def _kv_attend(q, k_pool, v_pool, layer, mask, bias, heads_from=2):
         """Attention of ``q`` [S, H, T, hd] over every column of layer
         ``layer``'s slab of the pool, read where it lies: no copy, no
         transpose, and grouped KV heads are contracted per group, not
@@ -796,7 +813,7 @@ class GPT2Model(ModelSpec):
         vs = lax.dynamic_index_in_dim(v_pool, layer, 0, keepdims=False)
         s, h, t, hd = q.shape
         max_len, g, w = ks.shape[1:]
-        if t > 1 and w > hd and hd % _LANES == 0:
+        if t >= heads_from and w > hd and hd % _LANES == 0:
             # a prefill over rows that hold several whole-lane heads (a
             # family's own choice for its decode step: ``models/lfm2.py``):
             # the zero-padded query below would multiply w / hd times the
@@ -1170,6 +1187,10 @@ class GPT2Model(ModelSpec):
             and self.decode_kernel_block(cache) is not None
         if kernel:
             from ..ops.pallas.decode_attention import decode_attend
+        # a family whose tick carries a few queries a slot says from how
+        # many its stored rows are seen as heads; every other call is as it was
+        few = {} if self._rows_as_heads_from == 2 else \
+            {"heads_from": self._rows_as_heads_from}
         # one piece: mask and bias are made once, outside the layers
         whole_mask = block == t and extras is None
         base_mask = keep_mask(None) if whole_mask else None
@@ -1191,7 +1212,7 @@ class GPT2Model(ModelSpec):
 
             def attend(at, q, q_pos):
                 return self._kv_attend(q, pool["k"], pool["v"], layer,
-                                       *mask_and_bias(q_pos)), None
+                                       *mask_and_bias(q_pos), **few), None
 
             def cached_attn(q, k, v, ring=None, latent=None):
                 # q, k, v arrive [S, H, T, hd]. The kv_write / kv_read
@@ -1309,7 +1330,8 @@ class GPT2Model(ModelSpec):
         return self._forward_with_cache(params, input_ids, cache, positions,
                                         routing=routing)
 
-    def verify_with_slots(self, params, input_ids, cache, positions):
+    def verify_with_slots(self, params, input_ids, cache, positions,
+                          routing=False):
         """Multi-token block forward with PER-ROW cache positions — the
         speculative-decoding verify step (deepspeed_tpu/serving/): row
         ``s`` feeds a block of T tokens (its pending token followed by
@@ -1329,8 +1351,12 @@ class GPT2Model(ModelSpec):
         every live position in range). Returns (logits [S, T, V],
         new_cache). T=1 is ``decode_with_slots`` bit for bit (which stays
         the steady-state program — its compiled flavor is pinned by the
-        serving tests)."""
-        return self._forward_with_cache(params, input_ids, cache, positions)
+        serving tests). A family that generates by diffusion over blocks
+        (``block_length``) runs its pass of the tick through here, under
+        its own mask: the same forward, ``routing`` as in
+        ``apply_with_cache``."""
+        return self._forward_with_cache(params, input_ids, cache, positions,
+                                        routing=routing)
 
     def cache_partition_rules(self):
         """Sharding for the KV cache: heads over 'model' (TP), batch over the

@@ -84,7 +84,6 @@ def apply_rope(x, cos, sin):
 class LlamaModel(GPT2Model):
 
     def __init__(self, config: LlamaConfig = LLAMA_7B):
-        assert config.n_embd == config.n_head * config.head_dim
         assert config.n_head % config.kv_head_count == 0, \
             "n_head must be a multiple of n_kv_head"
         super().__init__(config)
@@ -108,7 +107,7 @@ class LlamaModel(GPT2Model):
         blocks = {
             "ln1_scale": jnp.ones((l, d)),
             "qkv_w": norm(keys[0], (l, d, (cfg.n_head + 2 * hk) * hd), std),
-            "attn_proj_w": norm(keys[1], (l, d, d), proj_std),
+            "attn_proj_w": norm(keys[1], (l, cfg.n_head * hd, d), proj_std),
             "ln2_scale": jnp.ones((l, d)),
             "gate_w": norm(keys[2], (l, d, m), std),
             "up_w": norm(keys[3], (l, d, m), std),
@@ -171,7 +170,10 @@ class LlamaModel(GPT2Model):
             if hk != h:                   # GQA: repeat kv heads for the kernel
                 k = jnp.repeat(k, h // hk, axis=1)
                 v = jnp.repeat(v, h // hk, axis=1)
-            attn = sp_attention(q, k, v, causal=True,
+            # a family whose mask is not the causal one (``models/sdar.py``:
+            # whole blocks) says so and hands the mask over as a bias
+            attn = sp_attention(q, k, v, causal=self.causal_attention,
+                                bias=self._train_attn_bias_ex(t, extra),
                                 dropout_rate=cfg.dropout if train else 0.0,
                                 dropout_rng=(jax.random.fold_in(rng, 3)
                                              if train and cfg.dropout > 0 and
@@ -180,7 +182,8 @@ class LlamaModel(GPT2Model):
                                 backend=cfg.attn_backend,
                                 window=cfg.sliding_window)
         with jax.named_scope("out_proj"):
-            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, d)
+            # heads of a width of their own: H * hd, not always D
+            attn = attn.transpose(0, 2, 1, 3).reshape(b, t, h * hd)
             attn = attn @ p["attn_proj_w"].astype(attn.dtype)
             return x + self._dropout(attn, rng, train, 0)
 
@@ -208,7 +211,7 @@ class LlamaModel(GPT2Model):
         cfg = self.config
         d, l, m = cfg.n_embd, cfg.n_layer, cfg.intermediate
         hd, hk = cfg.head_dim, cfg.kv_head_count
-        block = l * (d * (cfg.n_head + 2 * hk) * hd + d * d + 3 * d * m)
+        block = l * (d * (2 * cfg.n_head + 2 * hk) * hd + 3 * d * m)
         flops = 6 * (block + cfg.padded_vocab * d)  # one V×d head matmul
         if seq_len:
             flops += 12 * l * d * seq_len

@@ -91,6 +91,26 @@ class KVQuantConfig(DeepSpeedConfigModel):
 
 
 @dataclasses.dataclass
+class BlockDiffusionConfig(DeepSpeedConfigModel):
+    """The ``"block_diffusion"`` block: how a family that generates by
+    diffusion over blocks (``models/sdar.py``: ``block_length`` B > 1) is
+    denoised. A block is fixed over ``denoising_steps`` unmasking passes of
+    ``B / denoising_steps`` positions each, the most confident first, and
+    one writing pass more: the published ``low_confidence_static`` rule,
+    the one schedule served (so there is no key to choose it by). ``None``:
+    B steps, a position a pass. The schedule is static, so the scheduler
+    knows a pass early which pass of its block a slot is in and keeps one
+    pass in flight (serving/scheduler.py ``_decode_blocks``); a dynamic
+    rule fixes as many positions as pass a confidence threshold, so the
+    passes a block takes would be known only once each is read."""
+    denoising_steps: Optional[int] = None
+
+    def validate(self):
+        if self.denoising_steps is not None and self.denoising_steps < 1:
+            raise ConfigError("block_diffusion.denoising_steps must be >= 1")
+
+
+@dataclasses.dataclass
 class ChunkedPrefillConfig(DeepSpeedConfigModel):
     """The ``"chunked_prefill"`` block (serving/scheduler.py): Sarathi-
     style stall-free batching on static shapes. A prompt whose unshared
@@ -493,6 +513,10 @@ class ServingConfig(DeepSpeedConfigModel):
     # decoding — 1..k+1 tokens per tick at bitwise-identical output
     speculative: Any = None
 
+    # block_diffusion (dict -> BlockDiffusionConfig): the denoising
+    # schedule of a family that generates by diffusion over blocks
+    block_diffusion: Any = None
+
     # chunked_prefill (dict -> ChunkedPrefillConfig): interleave long
     # prompts' prefill with decode ticks in chunk_tokens-sized chunks —
     # bounded in-flight TPOT regardless of prompt length
@@ -592,6 +616,12 @@ class ServingConfig(DeepSpeedConfigModel):
         elif self.speculative is None:
             self.speculative = SpeculativeConfig()
         self.speculative.validate()
+        if isinstance(self.block_diffusion, dict):
+            self.block_diffusion = BlockDiffusionConfig.from_dict(
+                self.block_diffusion)
+        elif self.block_diffusion is None:
+            self.block_diffusion = BlockDiffusionConfig()
+        self.block_diffusion.validate()
         if isinstance(self.chunked_prefill, dict):
             self.chunked_prefill = ChunkedPrefillConfig.from_dict(
                 self.chunked_prefill)

@@ -76,6 +76,24 @@ class ServingEngine:
              "keys and values a verify step (the block path of "
              "_latent_attend), and the family's own drafter, its "
              "multi-token-prediction block, is not built (ROADMAP B9)"}),
+        "denoised_blocks": (
+            "generates by diffusion over blocks: the columns of {names} past "
+            "a lane's last whole block are provisional until the block's "
+            "writing pass",
+            {"speculative": "a block is denoised in place over passes of "
+             "its own and nothing is rolled back; a draft of the next "
+             "tokens has no place in it",
+             "prefix_cache": "a shared prefix and a donated lane end where "
+             "their tokens do, which is inside a block as often as not, and "
+             "the keys of a block's first positions were computed seeing "
+             "its last: rounding hits down to whole blocks is not built",
+             "chunked_prefill": "a chunk that ended inside a block would "
+             "leave its positions without the keys of the rest of their "
+             "block; chunks of whole blocks are not built",
+             "kv_quant": "every pass of a block rewrites its columns and "
+             "the next pass attends them: an int8 round trip a pass would "
+             "quantize keys that are written once more before they are "
+             "final"}),
     }
 
     @classmethod
@@ -101,6 +119,39 @@ class ServingEngine:
                     raise ConfigError(
                         f"{block}: {type(module).__name__} "
                         f"{what.format(names=names)}; {reason}")
+        cls._fence_blocks(module, config)
+
+    @staticmethod
+    def _fence_blocks(module, config):
+        """The ``block_diffusion`` block against the model's
+        ``block_length``: a schedule the blocks cannot follow, and what a
+        pool of blocks cannot hand over, are refused with the reason."""
+        from ..runtime.config_utils import ConfigError
+        b = getattr(module, "block_length", 1)
+        steps = getattr(getattr(config, "block_diffusion", None),
+                        "denoising_steps", None)
+        name = type(module).__name__
+        if b == 1:
+            if steps is not None:
+                raise ConfigError(
+                    f"block_diffusion: {name} decodes a token a step "
+                    f"(block_length 1); there is no block to denoise")
+            return
+        if steps is not None and (steps > b or b % steps):
+            raise ConfigError(
+                f"block_diffusion.denoising_steps={steps}: a pass fixes "
+                f"block_length / denoising_steps positions, and {steps} "
+                f"does not divide {name}'s block_length {b}")
+        if config.max_model_len % b:
+            raise ConfigError(
+                f"max_model_len={config.max_model_len}: a lane of {name} "
+                f"holds whole blocks of {b}, and its last block would end "
+                f"past the lane")
+        if getattr(config, "role", "unified") != "unified":
+            raise ConfigError(
+                f"role={config.role}: {name} generates by diffusion over "
+                f"blocks, and a handed-off lane carries a first token and "
+                f"a length, not a block half denoised and its flags")
 
     def __init__(self, engine, config: Union[ServingConfig, dict, None] = None,
                  clock: Callable[[], float] = time.monotonic, seed: int = 0,
@@ -714,7 +765,12 @@ class ServingEngine:
     def decode_executables(self) -> int:
         """Compiled-executable count of the fused decode step (the
         compile-once contract: stays 1 across differing prompt lengths),
-        for THIS engine's pool flavor (fp vs quantized)."""
+        for THIS engine's pool flavor (fp vs quantized). A family that
+        generates by diffusion over blocks: of its pass over blocks."""
+        sched = self.scheduler
+        kind = ("slot_block", self.config.num_slots,
+                self.config.max_model_len, sched.block_fix) \
+            if sched.block > 1 else \
+            ("slot_decode", self.config.num_slots, self.config.max_model_len)
         return self.engine.slot_executables(
-            "slot_decode", self.config.num_slots, self.config.max_model_len,
-            quantized=self.scheduler.pool.quantized)
+            *kind, quantized=sched.pool.quantized)

@@ -18,6 +18,13 @@ decode step in flight, so a slot has two lengths while its step is out:
 ``lengths`` counts the columns whose token was DELIVERED (what a donated or
 handed-off lane holds), ``dispatched`` the columns a step was sent for (where
 the next step writes).
+
+A family that generates by diffusion over blocks (``block`` > 1:
+``models/sdar.py``) advances a slot B columns at a time, over several passes
+of the tick a block: ``lengths`` is then the columns whose FINAL keys and
+values are written (whole blocks), ``dispatched`` the column of the block the
+next pass is sent for, and the ``block_*`` registers hold the block itself
+(``bind_block``, ``dispatch_block_arrays``).
 """
 
 from typing import Collection, List, Optional
@@ -29,8 +36,26 @@ class SlotPool:
     """Fixed pool of decode slots over one static KV cache."""
 
     def __init__(self, engine, num_slots: int, max_model_len: int,
-                 quantize: bool = False):
+                 quantize: bool = False, block: int = 1):
         self.engine = engine
+        #: positions a pass advances a slot by (``GPT2Model.block_length``)
+        self.block = block
+        if block > 1:
+            # the block a slot is denoising, as the host last read it, and
+            # which of its positions are still masked; the pass of the
+            # block at which each position was fixed
+            self.block_ids = np.zeros((num_slots, block), np.int32)
+            self.block_flags = np.zeros((num_slots, block), bool)
+            self.block_fixed = np.zeros((num_slots, block), np.int32)
+            # of the block the next pass is SENT for: the passes sent, and
+            # the masked positions it opened with (B but for a first block,
+            # which the prompt's last tokens open)
+            self.block_sent = np.zeros((num_slots,), np.int32)
+            self.block_open = np.zeros((num_slots,), np.int32)
+            # the column a request's last token lies before, and whether
+            # the last pass it needs has been sent
+            self.block_end = np.zeros((num_slots,), np.int32)
+            self.block_done = np.zeros((num_slots,), bool)
         self.num_slots = num_slots
         self.max_model_len = max_model_len
         self.quantized = bool(quantize)
@@ -114,6 +139,32 @@ class SlotPool:
         self.top_ps[slot] = getattr(sampling, "top_p", 1.0)
         self.seeds[slot] = getattr(sampling, "seed", 0)
 
+    def bind_block(self, slot: int, request, start: int, held, end: int,
+                   sampling=None):
+        """``bind`` for a family that generates by blocks: the lane holds
+        the final keys and values of columns below ``start`` (the prompt's
+        whole blocks), the ``held`` tokens (the prompt's last
+        ``len % B``) open the block at ``start`` beside masked positions,
+        and the request's last token lies before column ``end``."""
+        self.bind(slot, request, start, 0, sampling)
+        held = np.asarray(held, np.int32)
+        self.block_ids[slot] = 0
+        self.block_ids[slot, :held.size] = held
+        self.block_flags[slot] = np.arange(self.block) >= held.size
+        self.block_fixed[slot] = 0
+        self.block_sent[slot] = 0
+        self.block_open[slot] = self.block - held.size
+        self.block_end[slot] = end
+        self.block_done[slot] = False
+
+    def next_block(self, slot: int):
+        """The writing pass of a slot's block was read: its columns are
+        final, and the block the host holds is the next one, all masked."""
+        self.lengths[slot] += self.block
+        self.block_ids[slot] = 0
+        self.block_flags[slot] = True
+        self.block_fixed[slot] = 0
+
     def set_length(self, slot: int, length: int):
         """The valid columns of a slot no step is in flight for (free,
         parked, mid-prefill, just bound): a dummy row lands one past
@@ -176,3 +227,51 @@ class SlotPool:
                 np.where(live, self.top_ks, 0),
                 np.where(live, self.top_ps, np.float32(1)),
                 np.where(live, self.seeds, 0), from_host)
+
+    def dispatch_block_arrays(self, slots: List[int], fed: Collection[int],
+                              fix: int):
+        """``dispatch_arrays`` for a pass over blocks: (ids [S, B], flags
+        [S, B], positions, temps, top_ks, top_ps, seeds, from_host) and
+        ``{slot: (pass of its block, its last unmasking pass, its writing
+        pass)}`` for ``slots``. A pass fixes ``fix`` positions a slot, so a
+        block that opened with m masked positions takes ``ceil(m / fix)``
+        unmasking passes and one writing pass: which pass this is follows
+        from how many were sent, never from the pass in flight. A row of
+        ``fed`` takes its block from that pass on the device; the pass
+        after a writing pass in flight opens the next block, all masked,
+        from here; every other row takes the block as the host last read
+        it. A slot that takes no part carries a dummy row (nothing flagged)
+        at the block past what it holds, clamped into the lane. Counts the
+        pass as dispatched: a writing pass moves the slot B columns on,
+        and the last unmasking pass of the block that holds the request's
+        last token ends its part (``block_done``: no writing pass)."""
+        b = self.block
+        live = np.zeros((self.num_slots,), bool)
+        live[slots] = True
+        ids = np.zeros((self.num_slots, b), np.int32)
+        flags = np.zeros((self.num_slots, b), bool)
+        from_host = np.ones_like(live)
+        positions = np.minimum(self.dispatched, self.max_model_len - b)
+        passes = {}
+        for s in slots:
+            sent = int(self.block_sent[s])
+            unmasking = -(-int(self.block_open[s]) // fix)
+            if s in fed and sent:
+                from_host[s] = False
+            elif s in fed:
+                flags[s] = True
+            else:
+                ids[s], flags[s] = self.block_ids[s], self.block_flags[s]
+            passes[s] = (sent, sent == unmasking - 1, sent == unmasking)
+            if sent == unmasking:
+                self.dispatched[s] += b
+                self.block_sent[s], self.block_open[s] = 0, b
+            else:
+                self.block_sent[s] = sent + 1
+                self.block_done[s] = sent == unmasking - 1 and \
+                    positions[s] + b >= self.block_end[s]
+        return (ids, flags, positions,
+                np.where(live, self.temps, np.float32(0)),
+                np.where(live, self.top_ks, 0),
+                np.where(live, self.top_ps, np.float32(1)),
+                np.where(live, self.seeds, 0), from_host), passes

@@ -105,6 +105,13 @@ class ServingMetrics:
         self.sampled_ticks = 0    # ... with a slot at temperature > 0
         self.pipelined_ticks = 0  # ... sent while another step was in flight
         self.dropped_rows = 0     # rows computed for a request that had ended
+        # a family that generates by diffusion over blocks
+        # (serving/scheduler.py _decode_blocks): the slots' passes by kind,
+        # and the tokens their blocks gave
+        self.unmask_passes = 0    # slot-passes that fixed positions
+        self.write_passes = 0     # ... that wrote a finished block's K/V
+        self.block_tokens = 0     # tokens delivered from finished blocks
+        self.block_cut = 0        # ... dropped at max_new_tokens or EOS
         self.handoffs_in = 0      # KV lanes received into this pool
         self.handoffs_out = 0     # KV lanes extracted and handed off
         self.handoffs_refused = 0  # lanes rejected at a weights_version
@@ -216,7 +223,8 @@ class ServingMetrics:
     def record_decode_step(self, seconds: float, n_active: int):
         """One fused decode step advanced ``n_active`` requests by one
         token: the per-token latency every active request observed is the
-        step wall time."""
+        step wall time. (A pass over blocks hands in the tokens its
+        finished blocks delivered, which is not its rows.)"""
         self.token_ms.append(seconds * 1e3)
         self._token_t.append(self._now())
         self.tokens_out += n_active
@@ -244,6 +252,23 @@ class ServingMetrics:
         if n:
             self.dropped_rows += n
             self._gauge("serve/dropped_rows", self.dropped_rows)
+
+    def record_block_pass(self, unmasking: int, writing: int,
+                          delivered: int, cut: int):
+        """One pass over blocks was read: ``unmasking`` of its slots' rows
+        fixed positions and ``writing`` wrote a finished block's keys and
+        values; the blocks it finished gave ``delivered`` tokens, and
+        ``cut`` more that lay past a request's ``max_new_tokens`` or its
+        EOS were dropped. ``serve/block_tokens`` over the sum of the two
+        pass gauges is the tokens a slot's pass yields."""
+        self.unmask_passes += unmasking
+        self.write_passes += writing
+        self.block_tokens += delivered
+        self.block_cut += cut
+        self._gauge("serve/unmask_passes", self.unmask_passes)
+        self._gauge("serve/write_passes", self.write_passes)
+        self._gauge("serve/block_tokens", self.block_tokens)
+        self._gauge("serve/block_cut", self.block_cut)
 
     def record_tenant_tokens(self, tenant, n: int = 1):
         """Attribute ``n`` decode tokens to ``tenant`` (the aggregate

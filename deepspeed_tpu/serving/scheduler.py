@@ -141,6 +141,9 @@ class Request:
     #: ``perf_counter_ns`` at enqueue: where this request's
     #: ``serve/queue_wait`` phase record starts (never the injectable clock)
     enqueue_ns: int = 0
+    #: a family that generates by diffusion over blocks: beside each of
+    #: ``tokens``, the pass of its block at which it was fixed
+    fixed_pass: List[int] = dataclasses.field(default_factory=list)
 
     @property
     def tenant(self) -> str:
@@ -166,6 +169,9 @@ class _Flight:
     positions: np.ndarray       # [S] the column each row was fed at
     sampled: bool               # some row's temperature is on
     pipelined: bool             # sent while another step was in flight
+    #: a pass over blocks: {slot: (pass of its block, its last unmasking
+    #: pass, its writing pass)} (``SlotPool.dispatch_block_arrays``)
+    passes: Optional[dict] = None
 
 
 class TenantQueues:
@@ -330,12 +336,18 @@ class ContinuousBatchingScheduler:
         self.metrics = metrics or ServingMetrics()
         quantize = bool(getattr(getattr(config, "kv_quant", None),
                                 "enabled", False))
+        module = getattr(engine, "module", None)
+        # a family that generates by diffusion over blocks: the positions a
+        # pass advances a slot by, and how many of them a pass fixes
+        self.block = int(getattr(module, "block_length", 1))
+        steps = getattr(getattr(config, "block_diffusion", None),
+                        "denoising_steps", None)
+        self.block_fix = self.block // (steps or self.block)
         self.pool = SlotPool(engine, config.num_slots, config.max_model_len,
-                             quantize=quantize)
+                             quantize=quantize, block=self.block)
         # a model with window layers: the columns a ring keeps of a lane,
         # read off the pool's own leaf (``serve/kv_live``); 0 where every
         # attending layer keeps them all
-        module = getattr(engine, "module", None)
         rings = getattr(module, "window_rings", ())
         leaves = getattr(self.pool.cache, "q", self.pool.cache)
         self._ring_window = int(leaves[rings[0]].shape[2]) if rings else 0
@@ -722,7 +734,9 @@ class ContinuousBatchingScheduler:
             # whale prompt below the chunking threshold entirely
             suffix = int(req.prompt.size) - \
                 (hit.matched if hit is not None else 0)
-            if self.chunked is not None and \
+            if self.block > 1:
+                spent = self._admit_blocks(slot, req)
+            elif self.chunked is not None and \
                     suffix > self.chunked.chunk_tokens:
                 spent = self._start_chunked(slot, req, hit)
             else:
@@ -935,6 +949,37 @@ class ContinuousBatchingScheduler:
                                      int(req.prompt.size))
         return first
 
+    def _admit_blocks(self, slot: int, req: Request) -> int:
+        """Admission of a family that generates by diffusion over blocks:
+        the prompt's WHOLE blocks are prefilled under the block mask (no
+        token is sampled: a prompt shorter than a block prefills nothing),
+        and the ``len % B`` tokens left over open the slot's first block
+        beside masked positions. The first tokens come when that block's
+        last unmasking pass is read (``_decode_blocks``), and TTFT with
+        them. Returns the prefill tokens spent."""
+        tr = self.tracer
+        whole = int(req.prompt.size) // self.block * self.block
+        if whole:
+            t0 = self.clock()
+            with tr.span("prefill", cat="serving",
+                         args={"request_id": req.request_id, "slot": slot,
+                               "prompt_len": int(req.prompt.size),
+                               "replica": self.replica_name,
+                               **(req.trace.span_args()
+                                  if req.trace is not None else {})}
+                         if tr.enabled else None):
+                self.pool.cache, _ = self.engine.slot_prefill(
+                    self.pool.cache, slot, req.prompt[:whole])
+            self._record_routing("serve/moe_prefill")
+            if self.cost is not None:
+                self.cost.charge_prefill(self.cost.record_for(req),
+                                         self.clock() - t0, whole)
+        req.state = RequestState.RUNNING
+        self.pool.bind_block(slot, req, whole, req.prompt[whole:],
+                             int(req.prompt.size) + req.max_new_tokens,
+                             req.sampling)
+        return whole
+
     def _record_routing(self, name: str):
         """A model with routed experts: the program's last call read back
         (experts touched, largest count any expert got), summed over
@@ -1015,6 +1060,8 @@ class ContinuousBatchingScheduler:
             # a free slot's temperature is 0: what the sampler will see
             self.metrics.record_decode_tick((self.pool.temps > 0).any())
             return self._decode_speculative(active)
+        if self.block > 1:
+            return self._decode_blocks(active)
         tr = self.tracer
         pool = self.pool
         t0 = self.clock()
@@ -1099,6 +1146,140 @@ class ContinuousBatchingScheduler:
             from_host=from_host)
         return _Flight(out, rows, positions, bool((temps > 0).any()),
                        behind is not None)
+
+    def _decode_blocks(self, active, pipelined: bool = True):
+        """``_decode`` for a family that generates by diffusion over blocks
+        (``models/sdar.py``): one PASS of the tick advances every slot that
+        goes on by a pass of its block of B columns, and delivers nothing
+        from most rows. The pipeline is ``_decode``'s, one pass deep: the
+        next pass is sent, fed on the device by the one in flight, before
+        that one is read. What a dispatch must know a pass early follows
+        from the static schedule (``SlotPool.dispatch_block_arrays``):
+        which pass of its block a slot is in, when it moves B columns on,
+        and that a request whose last token lies in the block whose last
+        unmasking pass was sent takes no further part (no writing pass). A
+        block's tokens are delivered, in order, when its last unmasking
+        pass is read; ``max_new_tokens`` or EOS may end a request inside a
+        block, and the rest of it is dropped. An ending by EOS comes a
+        pass late: the request's row of the pass in flight is computed and
+        dropped. ``pipelined=False`` is the tests': every pass is read
+        before the next is sent, and they hold the two streams bitwise
+        equal."""
+        tr, pool, b = self.tracer, self.pool, self.block
+        t0 = self.clock()
+
+        def going():
+            return [s for s in pool.active_slots if not pool.block_done[s]]
+
+        with tr.span("decode_step", cat="serving",
+                     args={"n_active": len(active), "tick": self._tick_no,
+                           "replica": self.replica_name}
+                     if tr.enabled else None):
+            flight = self._flight or self._dispatch_blocks(going())
+            rows = [(slot, req) for slot, req in flight.rows
+                    if pool.requests[slot] is req]
+            behind = going() if pipelined else []
+            self._flight = self._dispatch_blocks(
+                behind, flight, {slot for slot, _ in rows}) \
+                if behind else None
+            ids, flags = self.engine.slot_block_read(flight.out)
+        self._record_routing("serve/moe_decode")
+        # columns of one layer the pass's attention read, of the pool's:
+        # the XLA attend contracts over every column of every lane
+        pool_cols = flight.positions.size * self.config.max_model_len
+        now = time.perf_counter_ns()
+        tr.record_phase("serve/kv_read", now, now, pool_cols, pool_cols)
+        writing = sum(flight.passes[slot][2] for slot, _ in flight.rows)
+        unmasking = len(flight.rows) - writing
+        flagged = sum(int(pool.block_flags[slot].sum()) for slot, _ in rows
+                      if not flight.passes[slot][2])
+        tr.record_phase("serve/block_pass", now, now, unmasking, flagged)
+        tr.record_phase("serve/block_write", now, now, writing,
+                        len(flight.rows))
+        dt = self.clock() - t0
+        self.metrics.record_decode_tick(flight.sampled, flight.pipelined)
+        self.metrics.record_dropped_rows(len(flight.rows) - len(rows))
+        now = self.clock()
+        delivered = firsts = cut = 0
+        costs, tokens = [] if self.cost is not None else None, []
+        with tr.phase("serve/deliver", len(rows)) as deliver:
+            for slot, req in rows:
+                sent, last, is_write = flight.passes[slot]
+                gave = 0
+                if is_write:
+                    pool.next_block(slot)
+                else:
+                    fixed = pool.block_flags[slot] & ~flags[slot]
+                    pool.block_fixed[slot][fixed] = sent
+                    pool.block_ids[slot] = ids[slot]
+                    pool.block_flags[slot] = flags[slot]
+                if last:
+                    gave, first = self._deliver_block(slot, req, now)
+                    firsts += first
+                    if req.done:
+                        cut += b - max(int(req.prompt.size) -
+                                       int(flight.positions[slot]), 0) - gave
+                        self._release_slot(slot, req)
+                        deliver.b += 1
+                delivered += gave
+                if costs is not None:
+                    costs.append((self.cost.record_for(req), b))
+                    tokens.append(gave)
+        # a request's first token is counted with its TTFT
+        self.metrics.record_decode_step(dt, delivered - firsts)
+        self.metrics.record_block_pass(unmasking, writing, delivered, cut)
+        if costs:
+            # the wall by the columns a row advanced, the tokens as delivered
+            self.cost.charge_decode(dt, costs, tokens)
+        if self._flight is not None and not pool.active_slots:
+            self._settle()
+
+    def _deliver_block(self, slot: int, req: Request, now: float):
+        """A slot's block has no masked position left: deliver its tokens
+        in order (those past the prompt's, in a first block), the first of
+        a request with its TTFT, up to ``max_new_tokens`` or EOS. Returns
+        (the tokens delivered, whether the request's first is among them);
+        the request is finished where one ended it."""
+        pool = self.pool
+        held = max(int(req.prompt.size) - int(pool.lengths[slot]), 0)
+        gave, first = 0, req.first_token_time is None
+        for j in range(held, self.block):
+            tok = int(pool.block_ids[slot, j])
+            if req.first_token_time is None:
+                # no ``serve/first_token`` phase: that record is a part of
+                # a prefill's cost, and this is ticks after the prefill
+                if req.trace is not None:
+                    req.trace.mark("first_token")
+                req.first_token_time = now
+                self.metrics.record_ttft(now - req.submit_time,
+                                         tenant=req.tenant)
+            finishing = self._should_finish(req, tok, pending=1)
+            if finishing and req.trace is not None:
+                req.trace.mark("decode_done")
+            req.fixed_pass.append(int(pool.block_fixed[slot, j]))
+            self._deliver(req, tok)
+            gave += 1
+            if finishing:
+                self._finish(req, RequestState.FINISHED, now)
+                break
+        self.metrics.record_tenant_tokens(req.tenant, gave - first)
+        return gave, int(first)
+
+    def _dispatch_blocks(self, slots, behind=None, fed=()):
+        """``_dispatch`` for a pass over blocks."""
+        pool = self.pool
+        with self.tracer.phase("serve/decode_prep", len(slots)):
+            rows = [(slot, pool.requests[slot]) for slot in slots]
+            (ids, flags, positions, temps, top_ks, top_ps, seeds,
+             from_host), passes = pool.dispatch_block_arrays(
+                slots, fed, self.block_fix)
+        pool.cache, out = self.engine.slot_block_dispatch(
+            pool.cache, ids, flags, positions, temps, top_ks=top_ks,
+            top_ps=top_ps, seeds=seeds, fix=self.block_fix,
+            prev=None if behind is None else behind.out,
+            from_host=from_host)
+        return _Flight(out, rows, positions, bool((temps > 0).any()),
+                       behind is not None, passes)
 
     def _settle(self):
         """No slot is live: the step in flight, if there is one, holds

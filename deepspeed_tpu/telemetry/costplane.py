@@ -237,24 +237,28 @@ class CostLedger:
 
     # ------------------------------------------------------------- charging
     def charge_decode(self, dt_s: float,
-                      weighted: List[Tuple[CostRecord, int]]):
+                      weighted: List[Tuple[CostRecord, int]],
+                      tokens: Optional[List[int]] = None):
         """Split one decode tick's wall over its records, weighted by
         tokens emitted (equal on the non-speculative path, where every
-        weight is 1)."""
+        weight is 1). A pass over blocks (serving/scheduler.py
+        ``_decode_blocks``) weighs a record by the columns its row advanced
+        and hands in beside them the ``tokens`` its block delivered (none
+        from most passes): those are what the records then count."""
         total_w = sum(max(0, w) for _r, w in weighted)
         if total_w <= 0 or dt_s <= 0:
             return
         self._tick_attr_s += dt_s
-        for rec, w in weighted:
+        for (rec, w), n in zip(weighted, tokens or [w for _r, w in weighted]):
             if w <= 0:
                 continue
             ms = dt_s * 1e3 * w / total_w
             rec.charge(ms, decode=True)
-            rec.tokens += w
+            rec.tokens += n
             t = self._tenant(rec.tenant)
             t.decode_ms += ms
             t.chip_ms += ms
-            t.tokens += w
+            t.tokens += n
 
     def charge_spec(self, dt_s: float, draft_s: float, verify_s: float,
                     weighted: List[Tuple[CostRecord, int]]):

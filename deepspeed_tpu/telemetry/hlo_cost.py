@@ -83,7 +83,7 @@ SCOPES = ("embed", "layers", "hc_maps", "hc_mix", "attn", "qkv", "out_proj",
           "kv_write", "kv_read", "attend_window", "attend_full",
           "attend_latent", "latent_up", "absorb", "mlp", "dense_mlp",
           "moe", "router", "moe_experts", "shared_expert", "conv", "head",
-          "loss", "sample", "verify", "optimizer")
+          "loss", "sample", "unmask", "verify", "optimizer")
 
 #: the pass of a differentiated program an instruction belongs to, by how
 #: autodiff wraps the name stack; put in front of the scope

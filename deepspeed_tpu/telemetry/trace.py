@@ -253,7 +253,11 @@ class Tracer:
     # ------------------------------------------------------------ configure
     def configure(self, config=None, **overrides):
         """Apply a ``TelemetryConfig`` (or kwargs): enabled, buffer_size,
-        sync_spans. Resizing the buffer clears recorded spans."""
+        sync_spans. Resizing the buffer clears recorded spans. The kwarg
+        ``phase_buffer_size`` resizes the phase ring and clears the
+        recorded phases: a process whose readers look back over more than
+        32,768 records asks for a longer ring before its first tick (a
+        serving tick writes about twelve)."""
         kv = {}
         if config is not None:
             for k in ("enabled", "buffer_size", "sync_spans"):
@@ -266,6 +270,16 @@ class Tracer:
                 self._ring = [None] * self._cap
                 self._head = 0
                 self._total = 0
+        if "phase_buffer_size" in kv and \
+                max(16, int(kv["phase_buffer_size"])) != self._phase_cap:
+            # the writers take no lock: the ring is made before the
+            # capacity that indexes it grows, and shrunk after it falls
+            cap = max(16, int(kv["phase_buffer_size"]))
+            self._phase_cap = min(cap, self._phase_cap)
+            self._phase_ring = [None] * cap
+            self._phase_cap = cap
+            self._phase_seq = itertools.count()
+            self._phase_total = 0
         if "sync_spans" in kv:
             self.sync_spans = bool(kv["sync_spans"])
         if "enabled" in kv:

@@ -859,3 +859,68 @@ def test_sampler_sorts_inside_a_conditional_only(
              if re.match(r"(sort|conditional)[.\d]*$", n)}
     assert len(named) >= 3 and \
         {s.lstrip("?") for s in named.values()} == {"sample"}, named
+
+
+def test_block_pass_reads_the_slab_where_it_lies(one_chip):
+    """The pass over blocks of ``sdar-30b-a3b.serve-reason-4k``'s pool (48
+    slots x 4,096 columns, a token's four KV heads of 128 in ONE stored row
+    of 512; the cell's widths, one layer, a small vocabulary), compiled for
+    the chip: the donated pool is aliased to the output, no instruction
+    copies a layer's slab (bf16 [1, 48, 4096, 512], 201 MB: with rows of
+    ``(4, 128)``, or with a block's four queries seen as a prefill's, the
+    compiler copies K's and V's each pass, ``SDARModel.init_kv_cache``),
+    the grouped matmuls are the ``ragged-dot`` custom calls, and the
+    unmasking is named in the scope table."""
+    import re
+    from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
+    from deepspeed_tpu.inference.engine import InferenceEngine
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.models.sdar import SDARConfig, SDARModel
+    from deepspeed_tpu.parallel import initialize_mesh
+    from deepspeed_tpu.telemetry.hlo_cost import scope_table
+
+    slots, max_len, b, fix = 48, 4096, 4, 2
+    model = SDARModel(SDARConfig(vocab_size=512, n_positions=max_len,
+                                 n_layer=1, mask_token_id=500,
+                                 dtype="bfloat16"))
+    shapes = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    model.init = lambda rng: jax.tree.map(
+        lambda s: jnp.zeros(s.shape, s.dtype), shapes)
+    engine = InferenceEngine(
+        model, DeepSpeedInferenceConfig.from_dict(
+            {"dtype": "bfloat16", "max_tokens": max_len}),
+        mesh_manager=initialize_mesh(dp=1, devices=jax.devices()[:1]))
+    tiny = engine.init_slot_pool(1, max_len)
+    assert tiny["k"].shape == (1, 1, max_len, 1, 512)
+    z = np.zeros((1, b), np.int32)
+    tiny, _ = engine.slot_block_dispatch(
+        tiny, z, z > 0, np.zeros(1, np.int32), np.zeros(1, np.float32),
+        fix=fix)
+    fn = engine._slot_fns[("slot_block", 1, max_len, fix)]
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    # blk(params, pool, ids, flags, positions, temps, top_ks, top_ps,
+    #     seeds, prev, from_host)
+    compiled = jax.jit(
+        fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
+        params, pool, on_chip((slots, b), jnp.int32),
+        on_chip((slots, b), jnp.bool_), vi, vf, vi, vf, vi,
+        on_chip((slots * 2 * b + 2,), jnp.int32),
+        on_chip((slots,), jnp.bool_)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == pool_nbytes(pool)
+    assert mem.temp_size_in_bytes < 256 * 2 ** 20
+    text = compiled.as_text()
+    copied = re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = bf16\[1,48,4096,512\]\S* (copy|transpose)\(",
+        text, re.M)
+    assert copied == [], copied
+    assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
+    assert "unmask" in {s.lstrip("?").split("/")[-1]
+                        for s in scope_table(text).values() if s}

@@ -362,3 +362,19 @@ def test_kv_read_says_what_the_decode_attention_read(engine):
     # 19 live columns are two blocks; the free slot's position 0 is one
     assert read[3:] == (32 + 16, 2 * 64)
     srv.shutdown()
+
+
+def test_the_phase_ring_is_resized_on_request_and_starts_empty():
+    """``configure(phase_buffer_size=)``: what a process whose readers look
+    back over more records than the default ring holds asks for."""
+    tr = Tracer(phase_buffer_size=16)
+    for i in range(40):
+        tr.record_phase("serve/tick", i, i + 1)
+    assert len(tr.phases()) == 16 and tr.phases_dropped == 24
+    tr.configure(phase_buffer_size=64)
+    assert tr.phases() == [] and tr.phases_total == 0
+    for i in range(40):
+        tr.record_phase("serve/tick", i, i + 1)
+    assert len(tr.phases()) == 40 and tr.phases_dropped == 0
+    tr.configure(phase_buffer_size=64)          # as it is: nothing cleared
+    assert len(tr.phases()) == 40
