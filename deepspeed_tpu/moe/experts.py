@@ -13,7 +13,10 @@ routed, dropless serving layout: ``[N, M]`` rows sorted by expert with
 (``jax.lax.ragged_dot``; on the TPU the compiler makes it one Mosaic kernel
 whose FLOPs are the routed rows', not E times them). With ``layer`` given,
 the matmul leaves are the STACKED ``[L, E, ...]`` leaves of all L layers and
-are read where they lie (``_groups``).
+are read where they lie (``_groups``). On one TPU the gated experts' three
+products are ONE kernel of the repo's own where its shape rule takes them
+(``_rows_kernel``; ``ops/pallas/grouped_matmul.py``), and ``grouped_matmuls``
+counts how many of the traced products it took.
 """
 
 import math
@@ -21,6 +24,20 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..parallel.constraints import active_mesh
+from ..parallel.topology import EXPERT_AXIS
+
+#: the grouped products traced in this process, and those of them that the
+#: rows kernel took (three a ``GatedExpertFFN.apply_grouped`` call, two an
+#: ``ExpertFFN``'s): what the serving engine's shut-down line reads
+_TRACED = {"products": 0, "rows_kernel": 0}
+
+
+def grouped_matmuls():
+    """``(took the rows kernel, all)`` of the grouped products traced so
+    far in this process; ``(0, 0)`` for a model without routed experts."""
+    return _TRACED["rows_kernel"], _TRACED["products"]
 
 
 def _groups(w, layer, dt):
@@ -69,6 +86,31 @@ def _whole_tiles(x, sizes, expert_ids=None):
     return x, sizes, expert_ids
 
 
+def _rows_kernel(x, leaves):
+    """The row tile where the rows kernel takes the three
+    products of a gated expert over rows ``x`` [N, K] and ``leaves``
+    (gate, up, down; [E, ...] or stacked [L, E, ...]), else ``None``:
+    they stay ``lax.ragged_dot``'s. Decided at trace time on what is seen
+    here. No TPU (the CPU programs are what they were); a mesh whose
+    ``expert`` axis shards the groups (the kernel is one device's); a leaf
+    that is no plain array of the rows' type (an int8 ``QuantizedWeight``
+    dequantises into a fresh buffer, and a cast would convert every
+    layer's leaf in every layer); and what the kernel's own shape rule
+    leaves out (``grouped_matmul.choose``, with the sweep it rests on).
+    The kernel's module is imported here and nowhere else, so a process
+    without routed experts on a TPU never loads it."""
+    from ..parallel.topology import on_tpu
+    mesh = active_mesh()
+    if not on_tpu() or \
+            (mesh is not None and mesh.shape.get(EXPERT_AXIS, 1) > 1) or \
+            not all(isinstance(w, jax.Array) and w.dtype == x.dtype
+                    for w in leaves):
+        return None
+    from ..ops.pallas.grouped_matmul import choose
+    return choose(x.shape[0], x.shape[1], leaves[0].shape[-1], x.dtype,
+                  "tpu")
+
+
 class ExpertFFN:
     """Stacked per-expert 2-layer MLP: [E, M] → [E, F] → [E, M]."""
 
@@ -109,6 +151,7 @@ class ExpertFFN:
         leaves and this is layer ``layer`` of them (the biases stay this
         layer's own [E, ...])."""
         dt, n = x.dtype, x.shape[0]
+        _TRACED["products"] += 2
         x, sizes, expert_ids = _whole_tiles(
             x, _group_sizes(group_sizes, params["wi"], layer), expert_ids)
         h = lax.ragged_dot(x, _groups(params["wi"], layer, dt), sizes)
@@ -157,6 +200,16 @@ class GatedExpertFFN:
         ``layer``: the three leaves are the stacked [L, E, ...] leaves and
         this is layer ``layer`` of them."""
         dt, n = x.dtype, x.shape[0]
+        leaves = [params[name] for name in self.matmul_leaves]
+        _TRACED["products"] += 3
+        tile = _rows_kernel(x, leaves)
+        if tile is not None:
+            from ..ops.pallas.grouped_matmul import gated_rows
+            _TRACED["rows_kernel"] += 3
+            # the stack as L * E groups (``_groups``), this layer's from l * E
+            first = 0 if layer is None else layer * leaves[0].shape[1]
+            return gated_rows(x, *(_groups(w, layer, dt) for w in leaves),
+                              group_sizes, first, tile=tile)
         x, sizes, _ = _whole_tiles(
             x, _group_sizes(group_sizes, params["w_gate"], layer))
         g = lax.ragged_dot(x, _groups(params["w_gate"], layer, dt), sizes)

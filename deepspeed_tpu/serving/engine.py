@@ -611,6 +611,10 @@ class ServingEngine:
                  f"{m.decode_ticks} decode steps read, {m.pipelined_ticks} of "
                  f"them dispatched behind the one before, {m.sampled_ticks} "
                  f"sampled, {m.dropped_rows} rows dropped", ranks=[0])
+        took, products = m.record_grouped_matmuls()
+        if products:
+            log_dist(f"serving: {took} of the programs' {products} grouped "
+                     f"matmuls took the rows kernel", ranks=[0])
         if self.monitor is not None:
             self.monitor.close()
         tcfg = self.config.telemetry

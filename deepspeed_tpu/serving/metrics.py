@@ -21,6 +21,7 @@ configured targets (``slo.ttft_ms`` / ``tpot_ms`` / ``e2e_ms`` at
 ÷ allowed violation rate (>1 = out of budget).
 """
 
+import sys
 import time
 from collections import deque
 from typing import Dict, List, Optional, Tuple
@@ -61,6 +62,15 @@ class _TenantStats:
         #: the cost plane's per-tenant denominators
         self.prompt_tokens = 0
         self.timeouts = 0
+
+
+def _grouped_matmuls() -> Tuple[int, int]:
+    """``moe/experts.py:grouped_matmuls`` of this process: the experts'
+    grouped products traced so far and those the rows kernel took. A
+    process that never loaded the experts' module (a dense model) has
+    none, and is not made to load it."""
+    experts = sys.modules.get("deepspeed_tpu.moe.experts")
+    return experts.grouped_matmuls() if experts is not None else (0, 0)
 
 
 class ServingMetrics:
@@ -105,6 +115,10 @@ class ServingMetrics:
         self.sampled_ticks = 0    # ... with a slot at temperature > 0
         self.pipelined_ticks = 0  # ... sent while another step was in flight
         self.dropped_rows = 0     # rows computed for a request that had ended
+        # the experts' grouped products this engine's programs traced, and
+        # those the rows kernel took: counted from here on
+        self._grouped_before = _grouped_matmuls()
+        self.grouped_matmuls = (0, 0)
         # a family that generates by diffusion over blocks
         # (serving/scheduler.py _decode_blocks): the slots' passes by kind,
         # and the tokens their blocks gave
@@ -244,6 +258,21 @@ class ServingMetrics:
         self._gauge("serve/decode_ticks", self.decode_ticks)
         self._gauge("serve/sampled_ticks", self.sampled_ticks)
         self._gauge("serve/pipelined_ticks", self.pipelined_ticks)
+        self.record_grouped_matmuls()
+
+    def record_grouped_matmuls(self):
+        """``(took the rows kernel, all)`` of the grouped products traced
+        since this engine was built (a program is traced once, at its
+        first call): ``serve/rows_kernel_matmuls`` of
+        ``serve/grouped_matmuls`` is how far ``moe/experts.py:
+        _rows_kernel`` engaged. A dense model sets neither."""
+        now = tuple(a - b for a, b in zip(_grouped_matmuls(),
+                                          self._grouped_before))
+        if now != self.grouped_matmuls:
+            self.grouped_matmuls = now
+            self._gauge("serve/rows_kernel_matmuls", now[0])
+            self._gauge("serve/grouped_matmuls", now[1])
+        return now
 
     def record_dropped_rows(self, n: int):
         """``n`` rows of a decode step were computed for a request that had

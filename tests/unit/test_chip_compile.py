@@ -614,6 +614,19 @@ def _decode_program(model, max_len, tp=1):
     return engine, engine._slot_fns[("slot_decode", 1, max_len)], tiny
 
 
+def _decode_args(engine, tiny, slots, one_chip):
+    """The shapes of ``_decode_program``'s arguments at a pool of ``slots``
+    lanes, placed on the described chip."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
+    pool = jax.tree.map(
+        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
+    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    return (params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi))
+
+
 @pytest.mark.parametrize("cell,build,slots,max_len", [
     ("opt-1.3b.serve-chat", _opt, 28, 1024),
     ("opt-1.3b.serve-longprompt", _opt, 24, 2048),
@@ -639,16 +652,10 @@ def test_decode_step_takes_the_length_aware_kernel_in_place(
     assert tiny["k"].shape[3:] == (16, 128)
     monkeypatch.setattr(topology, "on_tpu", lambda: True)
 
-    def on_chip(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
-
-    params = jax.tree.map(lambda x: on_chip(x.shape, x.dtype), engine.params)
-    pool = jax.tree.map(
-        lambda x: on_chip((x.shape[0], slots) + x.shape[2:], x.dtype), tiny)
-    vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
+    params, pool, *rest = _decode_args(engine, tiny, slots, one_chip)
     compiled = jax.jit(
         fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        params, pool, vi, vi, vf, vi, vf, vi, *_fed_back(vi)).compile()
+        params, pool, *rest).compile()
     text, mem = compiled.as_text(), compiled.memory_analysis()
     calls = re.findall(
         r"^\s*%?(\S+) = .*custom_call_target=\"tpu_custom_call\"", text, re.M)
@@ -861,25 +868,16 @@ def test_sampler_sorts_inside_a_conditional_only(
         {s.lstrip("?") for s in named.values()} == {"sample"}, named
 
 
-def test_block_pass_reads_the_slab_where_it_lies(one_chip):
-    """The pass over blocks of ``sdar-30b-a3b.serve-reason-4k``'s pool (48
-    slots x 4,096 columns, a token's four KV heads of 128 in ONE stored row
-    of 512; the cell's widths, one layer, a small vocabulary), compiled for
-    the chip: the donated pool is aliased to the output, no instruction
-    copies a layer's slab (bf16 [1, 48, 4096, 512], 201 MB: with rows of
-    ``(4, 128)``, or with a block's four queries seen as a prefill's, the
-    compiler copies K's and V's each pass, ``SDARModel.init_kv_cache``),
-    the grouped matmuls are the ``ragged-dot`` custom calls, and the
-    unmasking is named in the scope table."""
-    import re
+def _block_pass_program(one_chip, slots=48, max_len=4096, b=4, fix=2):
+    """The engine's pass over blocks of an SDAR of ``sdar-30b-a3b.serve-
+    reason-4k``'s widths (one layer, a small vocabulary, zero weights;
+    built by one call on a one-slot pool on the CPU), the shapes of its
+    arguments at the cell's pool on the described chip, and that pool's."""
     from deepspeed_tpu.inference.config import DeepSpeedInferenceConfig
     from deepspeed_tpu.inference.engine import InferenceEngine
-    from deepspeed_tpu.inference.kv_quant import pool_nbytes
     from deepspeed_tpu.models.sdar import SDARConfig, SDARModel
     from deepspeed_tpu.parallel import initialize_mesh
-    from deepspeed_tpu.telemetry.hlo_cost import scope_table
 
-    slots, max_len, b, fix = 48, 4096, 4, 2
     model = SDARModel(SDARConfig(vocab_size=512, n_positions=max_len,
                                  n_layer=1, mask_token_id=500,
                                  dtype="bfloat16"))
@@ -907,12 +905,30 @@ def test_block_pass_reads_the_slab_where_it_lies(one_chip):
     vi, vf = on_chip((slots,), jnp.int32), on_chip((slots,), jnp.float32)
     # blk(params, pool, ids, flags, positions, temps, top_ks, top_ps,
     #     seeds, prev, from_host)
+    return fn, (params, pool, on_chip((slots, b), jnp.int32),
+                on_chip((slots, b), jnp.bool_), vi, vf, vi, vf, vi,
+                on_chip((slots * 2 * b + 2,), jnp.int32),
+                on_chip((slots,), jnp.bool_)), pool
+
+
+def test_block_pass_reads_the_slab_where_it_lies(one_chip):
+    """The pass over blocks of ``sdar-30b-a3b.serve-reason-4k``'s pool (48
+    slots x 4,096 columns, a token's four KV heads of 128 in ONE stored row
+    of 512; the cell's widths, one layer, a small vocabulary), compiled for
+    the chip: the donated pool is aliased to the output, no instruction
+    copies a layer's slab (bf16 [1, 48, 4096, 512], 201 MB: with rows of
+    ``(4, 128)``, or with a block's four queries seen as a prefill's, the
+    compiler copies K's and V's each pass, ``SDARModel.init_kv_cache``),
+    the grouped matmuls are the ``ragged-dot`` custom calls, and the
+    unmasking is named in the scope table."""
+    import re
+    from deepspeed_tpu.inference.kv_quant import pool_nbytes
+    from deepspeed_tpu.telemetry.hlo_cost import scope_table
+
+    fn, args, pool = _block_pass_program(one_chip)
     compiled = jax.jit(
         fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-        params, pool, on_chip((slots, b), jnp.int32),
-        on_chip((slots, b), jnp.bool_), vi, vf, vi, vf, vi,
-        on_chip((slots * 2 * b + 2,), jnp.int32),
-        on_chip((slots,), jnp.bool_)).compile()
+        *args).compile()
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == pool_nbytes(pool)
     assert mem.temp_size_in_bytes < 256 * 2 ** 20
@@ -924,3 +940,73 @@ def test_block_pass_reads_the_slab_where_it_lies(one_chip):
     assert len(re.findall(r"ragged-dot\S* = .*custom-call\(", text)) >= 3
     assert "unmask" in {s.lstrip("?").split("/")[-1]
                         for s in scope_table(text).values() if s}
+
+
+def _rows_kernels(text):
+    """(bodies, call sites) of the experts' rows kernel in a lowered
+    program's text: the Mosaic calls that carry its name, and the calls
+    of the one ``jax.jit`` that wraps it."""
+    import re
+    return (len(re.findall(r'kernel_name = "ragged-dot-rows"', text)),
+            len(re.findall(r"call @gated_rows", text)))
+
+
+def test_rows_kernel_is_lowered_once_a_shape(one_chip):
+    """What a warm start pays for the experts' kernel: Pallas lowers a
+    ``pallas_call`` to Mosaic while the program is LOWERED, before the
+    persistent cache is asked, so every start pays it whatever the cache
+    holds (PERF.md section 6, PR 52). Two layer loops that call
+    ``gated_rows`` on leaves of one shape hold ONE kernel body behind two
+    call sites; a third loop over leaves of another depth adds one."""
+    from jax import lax
+    from deepspeed_tpu.ops.pallas.grouped_matmul import choose, gated_rows
+    rows, k, f, e = 192, 2048, 1024, 64
+    tile = choose(rows, k, f, jnp.bfloat16, "tpu")
+
+    def on_chip(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def leaves(layers):
+        return [on_chip((layers * e, k, f)), on_chip((layers * e, k, f)),
+                on_chip((layers * e, f, k))]
+
+    def program(x, sizes, *stacks):
+        for at in range(0, len(stacks), 3):
+            def body(x, layer, ws=stacks[at:at + 3]):
+                return x + gated_rows(x, *ws, sizes, layer * e,
+                                      tile=tile), None
+            x, _ = lax.scan(body, x, jnp.arange(stacks[at].shape[0] // e))
+        return x
+
+    args = (on_chip((rows, k)), on_chip((e,), jnp.int32))
+    two = jax.jit(program).lower(*args, *leaves(2), *leaves(2))
+    assert _rows_kernels(two.as_text()) == (1, 2)
+    three = jax.jit(program).lower(*args, *leaves(2), *leaves(2), *leaves(3))
+    assert _rows_kernels(three.as_text()) == (2, 3)
+    assert "ragged-dot-rows" in two.compile().as_text()
+
+
+@pytest.mark.parametrize("family", ("olmoe", "sdar"))
+def test_routed_step_holds_one_rows_kernel_a_layer_body(
+        one_chip, monkeypatch, family):
+    """The decode step of an OLMoE of ``olmoe-1b-7b.serve-chat-2k``'s
+    widths and the pass over blocks of an SDAR of ``sdar-30b-a3b.serve-
+    reason-4k``'s, traced as on one TPU: the layer loop's body calls the
+    rows kernel ONCE for its three products (one body, one call site; the
+    parent's three ``ragged_dot`` are gone), so a routed program lowers
+    one Mosaic kernel a distinct layer loop at every start."""
+    from deepspeed_tpu.parallel import topology
+    if family == "olmoe":
+        slots, max_len = 24, 2048
+        engine, fn, tiny = _decode_program(_olmoe(max_len), max_len)
+        args = _decode_args(engine, tiny, slots, one_chip)
+    else:
+        fn, args, _ = _block_pass_program(one_chip)
+    on_cpu = jax.jit(fn.__wrapped__).lower(*args).as_text()
+    assert _rows_kernels(on_cpu) == (0, 0)
+    assert on_cpu.count("ragged_dot") >= 3
+    monkeypatch.setattr(topology, "on_tpu", lambda: True)
+    # a new function: ``jax.jit`` keeps what it traced by the function
+    on_tpu = jax.jit(lambda *a: fn.__wrapped__(*a)).lower(*args).as_text()
+    assert _rows_kernels(on_tpu) == (1, 1)
+    assert "ragged_dot" not in on_tpu
