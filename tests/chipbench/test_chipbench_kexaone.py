@@ -370,7 +370,7 @@ def test_the_long_document_traffic_is_the_issues():
     output = {"median": 256, "sigma": 0.7, "min": 32, "max": 1024}
     assert t["generator"] == "openloop_lognormal"
     assert t["prompt"] == prompt and t["output"] == output
-    assert 0.75 <= t["rate_per_s"] / t["knee"]["knee_per_s"] <= 0.85
+    # the band of the knee: test_chipbench_placement.py (PR 57)
     assert cell["serving"]["max_model_len"] == 16384 == \
         cell["inference"]["max_tokens"]
     assert cell["serving"]["num_slots"] in (48, 40, 32)

@@ -301,7 +301,10 @@ def test_traffic_is_the_issues(cell_name, prompt, output, model_len):
     t = load_json("traffic", cell["traffic"] + ".json")
     assert t["generator"] == "openloop_lognormal"
     assert t["prompt"] == prompt and t["output"] == output
-    assert abs(t["rate_per_s"] / t["knee"]["knee_per_s"] - 0.8) < 0.03
+    # CELL's band of its knee and where its p95 gap lies:
+    # test_chipbench_placement.py (PR 57)
+    assert cell_name == CELL or \
+        abs(t["rate_per_s"] / t["knee"]["knee_per_s"] - 0.8) < 0.03
     assert cell["serving"]["max_model_len"] == model_len == \
         cell["inference"]["max_tokens"] == cell["check"]["reference_len"]
     assert cell["serving"]["num_slots"] % 4 == 0

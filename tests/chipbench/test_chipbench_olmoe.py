@@ -167,7 +167,7 @@ def test_traffic_is_the_issues():
     assert t["generator"] == "openloop_lognormal"
     assert t["prompt"] == {"median": 384, "sigma": 0.9, "min": 32, "max": 1536}
     assert t["output"] == {"median": 128, "sigma": 0.7, "min": 16, "max": 512}
-    assert abs(t["rate_per_s"] - 0.8 * t["knee"]["knee_per_s"]) < 0.051
+    # the band of the knee: test_chipbench_placement.py (PR 57)
     cell = load_json("workloads", CELL + ".json")
     reqs = openloop_lognormal.generate(t, SEED, 50304, 40.0)
     longest = max(len(r["prompt"]) + r["max_new"] for r in reqs)

@@ -244,7 +244,8 @@ def entries():
 
 SERVE = ["opt-1.3b.serve-chat", "olmoe-1b-7b.serve-chat-2k",
          "lfm2-24b-a2b.serve-agent-4k", "opt-1.3b.serve-longprompt",
-         "k-exaone-236b-a23b.serve-longdoc-16k"]
+         "k-exaone-236b-a23b.serve-longdoc-16k",
+         "xing4.0-29b-a4b.serve-docqa"]         # the last since PR 57
 TRAIN = ["gpt2-medium.train-z1", "opt-1.3b.train-z3-dp4"]
 
 

@@ -29,7 +29,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)
 sys.path.insert(0, ROOT)
 
 from chipbench import counts_sdar, reference_sdar, weights_sdar     # noqa: E402
-from chipbench.layer_metrics import serve_blocks                     # noqa: E402
+from chipbench.layer_metrics import (scope_time, serve_blocks,       # noqa: E402
+                                     serve_latent)
 from chipbench.model import load_json, merge                         # noqa: E402
 
 NAME = "sdar-30b-a3b"
@@ -401,13 +402,18 @@ def test_the_reasoning_traffic_is_the_issues():
 def test_the_cell_is_in_the_lists_its_readers_apply_to():
     """Every list OLMoE's cell is in but ``moe_load_skew`` (its reader
     divides by the slots, and a pass routes four rows a slot), appended
-    after the cells the benchmark had; no new ``per_layer`` entry."""
+    after the cells the benchmark had. Of the entries PR 57 appended, the
+    scope-time ones do not list this cell yet (``decode_sample_ms`` reads
+    ``jit_dec``, which a pass over blocks never calls: PERF.md section 7),
+    and the six of ``serve_blocks.entries.json`` list it alone, as that
+    file has them."""
     man = manifest()
     other = "olmoe-1b-7b.serve-chat-2k"
     for group in ("end_to_end", "per_layer"):
         for m in man[group]:
             listed = m.get("workloads")
-            if listed is None:
+            if listed is None or m["name"] in scope_time.METRICS or \
+                    m["name"] in serve_blocks.METRICS:
                 continue
             assert (CELL in listed) == \
                 (other in listed and m["name"] != "moe_load_skew"), m["name"]
@@ -418,8 +424,8 @@ def test_the_cell_is_in_the_lists_its_readers_apply_to():
     # so the next cell and configuration go behind these and move neither
     assert [w["name"] for w in man["workloads"]].index(CELL) == 9
     assert [c["name"] for c in man["configs"]].index(NAME) == 6
-    assert not {m["name"] for m in man["per_layer"]} & set(
-        serve_blocks.METRICS)
+    assert [m for m in man["per_layer"]
+            if m["name"] in serve_blocks.METRICS] == entries()
     lines = [(e["name"], key, e[key])
              for group in ("configs", "workloads", "end_to_end", "per_layer")
              for e in man[group] for key in ("why", "layer", "source")
@@ -442,7 +448,8 @@ def test_the_cell_before_this_one_keeps_its_lists():
     for group in ("end_to_end", "per_layer"):
         for m in man[group]:
             listed = m.get("workloads")
-            if listed is not None and m["name"] != "kv_read_share":
+            if listed is not None and m["name"] != "kv_read_share" and \
+                    m["name"] not in serve_latent.METRICS:  # its own four
                 assert (cell in listed) == (other in listed), m["name"]
                 assert cell not in listed or \
                     listed.index(cell) > listed.index(other)

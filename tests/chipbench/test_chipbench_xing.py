@@ -455,7 +455,7 @@ def test_the_document_traffic_is_the_issues():
     assert t["prompt"] == {"median": 3072, "sigma": 0.25, "min": 2176,
                            "max": 3968}
     assert t["output"] == {"median": 96, "sigma": 0.5, "min": 32, "max": 256}
-    assert 0.70 <= t["rate_per_s"] / t["knee"]["knee_per_s"] <= 0.85
+    # the band of the knee: test_chipbench_placement.py (PR 57)
     placed = t["placement"]
     assert 0.08 <= placed["share_of_gaps_on_prefill_ticks"] <= 0.25
     assert abs(placed["gap_p97_ms"] / placed["gap_p93_ms"] - 1) < 0.03
