@@ -146,6 +146,8 @@ class InferenceEngine:
         # step must compile exactly once per pool shape.
         self._fns: "OrderedDict[Any, Any]" = OrderedDict()
         self._slot_fns: Dict[Any, Any] = {}
+        # (bucket, max_len) -> does that slot prefill take the flash kernel
+        self._prefill_kernel: Dict[Any, bool] = {}
         # compile ledger (telemetry/compileplane.py), attached by the
         # serving layer when its compile_plane block is on: every serving
         # program (forward, generate bucket, prefill bucket, fused decode,
@@ -867,8 +869,10 @@ class InferenceEngine:
                top_p, seed):
             with jax.named_scope("kv_write"):   # the lane, empty
                 mini = model.init_kv_cache(1, max_len, dtype=self.dtype)
+            # column 0 as a number, not an array: a model sees at trace
+            # time that the bucket is a whole prefill (``prefill_kernel``)
             logits, mini, *stats = model.apply_with_cache(
-                params, ids, mini, jnp.int32(0), routing=self._routed,
+                params, ids, mini, 0, routing=self._routed,
                 **self._real_length(last_idx))
             with jax.named_scope("kv_write"):
                 pool = write_lane(pool, mini, slot)
@@ -1124,6 +1128,22 @@ class InferenceEngine:
             return model.decode_kernel_block(jax.eval_shape(
                 lambda: model.init_kv_cache(num_slots, max_len,
                                             dtype=self.dtype)))
+
+    def prefill_kernel(self, tokens: int, max_len: int) -> bool:
+        """Whether ``slot_prefill`` of a prompt of ``tokens`` into a lane
+        of ``max_len`` columns attends the bucket's own keys in the packed
+        flash kernel (``GPT2Model.prefill_kernel``, asked under this
+        engine's mesh and of the empty lane the program builds, once a
+        bucket)."""
+        model = self.module
+        key = (min(_next_pow2(tokens), max_len), max_len)
+        if key not in self._prefill_kernel:
+            with self.mesh:
+                self._prefill_kernel[key] = model.prefill_kernel(
+                    jax.eval_shape(lambda: model.init_kv_cache(
+                        1, max_len, dtype=self.dtype)),
+                    key[0], 0, None, self.dtype)
+        return self._prefill_kernel[key]
 
     def slot_decode_step(self, pool, toks, positions, temps, top_ks=None,
                          top_ps=None, seeds=None):

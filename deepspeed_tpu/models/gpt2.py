@@ -1114,6 +1114,96 @@ class GPT2Model(ModelSpec):
         return decode_attention.block_columns(leaf.shape[3:], max_len,
                                               leaf.dtype)
 
+    #: the shortest whole prefill that takes the flash kernel. Every bucket
+    #: on it traces and lowers one more kernel shape at each start of a
+    #: serving process, 0.3 s on the chip's host whatever the compile cache
+    #: holds (PERF.md, PR 58: four buckets were +1.3 s of warm start-up in
+    #: the chat cells), and 128 queries over a lane have little to gain
+    _flash_prefill_from = 256
+
+    def prefill_kernel(self, cache, t, start, pad_counts=None, dtype=None):
+        """Whether a cached forward of ``t`` tokens a row from column
+        ``start``, traced now over ``cache`` (``init_kv_cache``'s leaves, or
+        their shapes), computes its attention with the packed flash kernel
+        (``ops/pallas/flash_attention_packed.py``, the training forward's)
+        on the block's OWN q, k and v, the scores never leaving VMEM,
+        instead of over every column of the lane (``_kv_attend``: float32
+        scores ``[H, t, max_len]`` through HBM). From column 0 the columns
+        below ``t`` hold what this call has just computed and the causal
+        mask drops every column past them, so attention over the lane IS
+        causal self-attention among the block's tokens; K and V are written
+        to the lane as ever. Decided by what can be observed, here and
+        nowhere else (the serving scheduler's ``serve/kernel_prefills``
+        rests on the same answer):
+
+        - a WHOLE prefill: ``start`` is a concrete 0 as the program is
+          traced (a suffix's or a chunk's ``start`` is traced and the
+          columns below it live; a verify step's is [S]), no left padding,
+          ``_flash_prefill_from`` tokens or more. A right-padded bucket
+          needs no length: padding keys lie past every real query;
+        - the family's mask of those queries over the lane is the plain
+          causal one and it adds no bias: no layer extras (GPT-Neo's local
+          layers), no ALiBi, no window shorter than ``t``, no blocks that
+          see ahead (``models/sdar.py``);
+        - the pool holds K and V (a latent leaf is attended by
+          ``_latent_attend``) in ``dtype``, the compute dtype (the config's
+          where ``None``): what is read back from an int8 or float32 lane
+          is not what was computed;
+        - as many KV heads as query heads, at a length and a head width the
+          kernel takes (``flash_attention_packed.supported``);
+        - the program will run on a TPU, on one device (GSPMD cannot
+          partition a Mosaic kernel). Elsewhere ``attn_backend="pallas"``
+          runs the kernel in interpret mode, the parity tests' path, as it
+          does for training (``_packed_attn_ok``)."""
+        from ..ops.pallas.flash_attention_packed import supported
+        from ..parallel.constraints import active_mesh
+        from ..parallel.topology import on_tpu
+        cfg = self.config
+        if isinstance(start, jax.core.Tracer) or jnp.ndim(start) != 0 \
+                or int(start) != 0 or pad_counts is not None \
+                or t < self._flash_prefill_from:
+            return False
+        if self.latent_cache or self._layer_extras() is not None:
+            return False
+        dtype = jnp.dtype(cfg.dtype if dtype is None else dtype)
+        if cache["k"].dtype != dtype or cache["v"].dtype != dtype:
+            return False
+        if self.kv_heads != cfg.n_head or \
+                not supported(t, cfg.head_dim, cfg.n_head, True, None):
+            return False
+        mesh = active_mesh()
+        if (mesh is not None and mesh.devices.size > 1) or \
+                not (on_tpu() or cfg.attn_backend == "pallas"):
+            return False
+        max_len = cache["k"].shape[2]
+        q_pos = np.arange(t)[None, None, :, None]
+        k_pos = np.arange(max_len)[None, None, None, :]
+        with jax.ensure_compile_time_eval():
+            if self._decode_attn_bias(q_pos[:, :, -1:], k_pos) is not None:
+                return False
+            keep = np.asarray(self._decode_attn_mask(q_pos, k_pos))
+        return bool(np.array_equal(
+            np.broadcast_to(keep, (1, 1, t, max_len))[0, 0],
+            (k_pos <= q_pos)[0, 0]))
+
+    @staticmethod
+    def _self_attend(q, k, v):
+        """Causal attention of ``q`` [S, H, T, hd] over the block's own
+        ``k`` and ``v`` (as many heads) in the packed flash kernel, where
+        ``prefill_kernel`` says so: the three as ``[S, T, H * hd]``, the
+        layout the qkv matmul gave and ``kv_write`` takes (the compiler
+        cancels the transposes against those around the call), the
+        training forward's kernel with the tiles its shape resolves to,
+        and back. Scores and softmax in float32, bf16 probabilities into V
+        with float32 sums: no lower a precision than ``_kv_attend``'s."""
+        from ..ops.pallas.flash_attention_packed import packed_flash_attention
+        from ..parallel.topology import on_tpu
+        s, h, t, hd = q.shape
+        q, k, v = (a.transpose(0, 2, 1, 3).reshape(s, t, h * hd)
+                   for a in (q, k, v))
+        out = packed_flash_attention(q, k, v, h, interpret=not on_tpu())
+        return out.reshape(s, t, h, hd).transpose(0, 2, 1, 3)
+
     @staticmethod
     def _state_shift(state, layer, rows, lengths=None):
         """Push ``rows`` [S, T, d] through layer ``layer`` of a recurrent
@@ -1187,13 +1277,18 @@ class GPT2Model(ModelSpec):
             and self.decode_kernel_block(cache) is not None
         if kernel:
             from ..ops.pallas.decode_attention import decode_attend
+        # a whole prefill from column 0: the block's own keys, where the
+        # packed flash kernel takes them
+        flash = self.prefill_kernel(cache, t, start, pad_counts,
+                                    compute_dtype)
         # a family whose tick carries a few queries a slot says from how
         # many its stored rows are seen as heads; every other call is as it was
         few = {} if self._rows_as_heads_from == 2 else \
             {"heads_from": self._rows_as_heads_from}
-        # one piece: mask and bias are made once, outside the layers
+        # one piece: mask and bias are made once, outside the layers (the
+        # flash kernel makes its own mask, tile by tile)
         whole_mask = block == t and extras is None
-        base_mask = keep_mask(None) if whole_mask else None
+        base_mask = keep_mask(None) if whole_mask and not flash else None
         base_bias = self._decode_attn_bias(q_pos, k_pos) if block == t \
             else None
 
@@ -1248,6 +1343,8 @@ class GPT2Model(ModelSpec):
                         return decode_attend(
                             q[:, :, 0], pool["k"], pool["v"], layer,
                             start + 1)[:, :, None]
+                    if flash:
+                        return self._self_attend(q, k, v)
                     return self._in_row_blocks(attend, block, 2, q, q_pos)[0]
 
             if self.recurrent_state:

@@ -610,7 +610,9 @@ class ServingEngine:
         log_dist(f"serving: shut down after {m.ticks} ticks: "
                  f"{m.decode_ticks} decode steps read, {m.pipelined_ticks} of "
                  f"them dispatched behind the one before, {m.sampled_ticks} "
-                 f"sampled, {m.dropped_rows} rows dropped", ranks=[0])
+                 f"sampled, {m.dropped_rows} rows dropped; "
+                 f"{m.kernel_prefills} of {m.prefills} prefills attended in "
+                 f"the flash kernel", ranks=[0])
         took, products = m.record_grouped_matmuls()
         if products:
             log_dist(f"serving: {took} of the programs' {products} grouped "

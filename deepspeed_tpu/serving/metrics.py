@@ -115,6 +115,8 @@ class ServingMetrics:
         self.sampled_ticks = 0    # ... with a slot at temperature > 0
         self.pipelined_ticks = 0  # ... sent while another step was in flight
         self.dropped_rows = 0     # rows computed for a request that had ended
+        self.prefills = 0         # prefill programs that ended an admission
+        self.kernel_prefills = 0  # ... that attended in the flash kernel
         # the experts' grouped products this engine's programs traced, and
         # those the rows kernel took: counted from here on
         self._grouped_before = _grouped_matmuls()
@@ -259,6 +261,20 @@ class ServingMetrics:
         self._gauge("serve/sampled_ticks", self.sampled_ticks)
         self._gauge("serve/pipelined_ticks", self.pipelined_ticks)
         self.record_grouped_matmuls()
+
+    def record_prefill(self, kernel: bool):
+        """One prefill program that ends an admission ran (a whole
+        prompt's, a suffix's behind a reused prefix or behind a chunked
+        admission's chunks). ``kernel``: its attention is the
+        packed flash kernel's over the bucket's own keys
+        (``GPT2Model.prefill_kernel``: a whole prefill of a bucket, heads
+        and mask the kernel takes, on one TPU), not ``_kv_attend``'s over
+        the lane; ``serve/kernel_prefills`` of ``serve/prefills`` is how
+        far that rule engaged."""
+        self.prefills += 1
+        self.kernel_prefills += bool(kernel)
+        self._gauge("serve/prefills", self.prefills)
+        self._gauge("serve/kernel_prefills", self.kernel_prefills)
 
     def record_grouped_matmuls(self):
         """``(took the rows kernel, all)`` of the grouped products traced

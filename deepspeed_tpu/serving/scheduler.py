@@ -360,6 +360,9 @@ class ContinuousBatchingScheduler:
         ask = getattr(engine, "decode_kernel_block", None)
         self._kv_read_block = ask(config.num_slots, config.max_model_len) \
             if ask is not None else None
+        # whether a whole prefill of so many tokens attends in the packed
+        # flash kernel (``serve/kernel_prefills``)
+        self._prefill_kernel = getattr(engine, "prefill_kernel", None)
         #: admission queue: per-tenant FIFOs + deficit round-robin when
         #: the tenants block is on, a plain FIFO otherwise (deque API)
         self.queue = TenantQueues(getattr(config, "tenants", None))
@@ -840,6 +843,7 @@ class ContinuousBatchingScheduler:
                 self.pool.cache, slot, req.prompt[offset:], offset,
                 temperature=sp.temperature, top_k=sp.top_k,
                 top_p=sp.top_p, seed=sp.seed)
+        self.metrics.record_prefill(False)      # over the chunks' columns
         if self.cost is not None:
             self.cost.charge_prefill(self.cost.record_for(req),
                                      self.clock() - t0, t - offset)
@@ -913,6 +917,7 @@ class ContinuousBatchingScheduler:
                                 offset,
                                 temperature=sp.temperature, top_k=sp.top_k,
                                 top_p=sp.top_p, seed=sp.seed)
+                    self.metrics.record_prefill(False)  # columns below live
                     if self.cost is not None:
                         # the lane copy + suffix pass is what the request
                         # actually cost; the reused prefix is prefill the
@@ -942,7 +947,7 @@ class ContinuousBatchingScheduler:
                 self.pool.cache, slot, req.prompt,
                 temperature=sp.temperature, top_k=sp.top_k,
                 top_p=sp.top_p, seed=sp.seed)
-        self._record_routing("serve/moe_prefill")
+        self._record_prefill(int(req.prompt.size))
         if self.cost is not None:
             self.cost.charge_prefill(self.cost.record_for(req),
                                      self.clock() - t0,
@@ -970,7 +975,7 @@ class ContinuousBatchingScheduler:
                          if tr.enabled else None):
                 self.pool.cache, _ = self.engine.slot_prefill(
                     self.pool.cache, slot, req.prompt[:whole])
-            self._record_routing("serve/moe_prefill")
+            self._record_prefill(whole)
             if self.cost is not None:
                 self.cost.charge_prefill(self.cost.record_for(req),
                                          self.clock() - t0, whole)
@@ -979,6 +984,15 @@ class ContinuousBatchingScheduler:
                              int(req.prompt.size) + req.max_new_tokens,
                              req.sampling)
         return whole
+
+    def _record_prefill(self, tokens: int):
+        """A whole prefill of ``tokens`` was read: its routing stats, and
+        whether its bucket's program attends in the flash kernel (the
+        engine's answer, from the model's own rule)."""
+        self._record_routing("serve/moe_prefill")
+        ask = self._prefill_kernel
+        self.metrics.record_prefill(
+            ask is not None and ask(tokens, self.config.max_model_len))
 
     def _record_routing(self, name: str):
         """A model with routed experts: the program's last call read back

@@ -758,9 +758,11 @@ def _longprompt_text(one_chip, monkeypatch, programs, program):
             args = (params, on_chip((1, bucket), jnp.int32), pool, si, si,
                     sf, si, sf, si)
         assert fn.__name__ == program[4:]
-        texts[program] = jax.jit(
+        compiled = jax.jit(
             fn.__wrapped__, donate_argnums=fn._jit_info.donate_argnums).lower(
-            *args).compile().as_text()
+            *args).compile()
+        texts[program] = compiled.as_text()
+        texts[program, "temp"] = compiled.memory_analysis().temp_size_in_bytes
     return texts[program]
 
 
@@ -772,7 +774,7 @@ def test_scope_table_names_every_large_instruction(
     vocabulary, two layers), compiled for the chip: in the scope table of
     the optimized HLO (``telemetry.hlo_cost.scope_table``) no instruction
     with 1 MB of output or more reads ``None``, the ``[bucket, vocabulary]``
-    matmul reads ``head``, the float32 scores ``kv_read``, the decode
+    matmul reads ``head``, the prefill's packed flash kernel and the decode
     kernel ``kv_read``, and the sampler's sort ``sample``. (Two layers: the
     compiler unrolls the scan, and the names hold all the same.)"""
     import re
@@ -810,11 +812,114 @@ def test_scope_table_names_every_large_instruction(
     assert shaped(r"\(f32\[\d+,50272\].* sort\(") == {"sample"}
     if program == "jit_pf":
         assert shaped(r"bf16\[2048,50304\]\S* fusion\(") == {"head"}
-        assert shaped(r"f32\[16,2,2048,2048\]\S* fusion\(") == \
-            {"layers/attn/kv_read"}
+        assert shaped(r"\(bf16\[1,2048,2048\]\S*, f32\[16,16,8,128\]\S*\) "
+                      r"custom-call\(") == {"layers/attn/kv_read"}
     else:
         assert shaped(r"bf16\[24,32,128\]\S* custom-call\(") == \
             {"layers/attn/kv_read"}
+
+
+def test_whole_prefill_attends_in_the_packed_kernel(
+        one_chip, monkeypatch, longprompt_programs):
+    """Bucket 2,048's ``jit_pf`` of ``opt-1.3b.serve-longprompt`` (24 slots
+    x 2,048, two layers of the cell's widths), traced as on one TPU and
+    compiled for the chip: the attention of the bucket's tokens is ONE
+    Mosaic call, the training forward's packed flash kernel over q, k, v
+    ``[1, 2048, 32 * 64]`` as the qkv matmul gave them (no transpose or
+    copy feeds it, none takes its output), filed under
+    ``layers/attn/kv_read``; no instruction holds a head's scores of
+    2,048 x 2,048 in any type, and the program's temporaries are under the
+    512 MB that the float32 scores ``[32, 2048, 2048]`` of the lane attend
+    alone take (241 MB against 1,117 MB for the same program on
+    ``_kv_attend``, compiled the same way: PERF.md, PR 58)."""
+    import re
+    from deepspeed_tpu.telemetry.hlo_cost import scope_table
+    programs = longprompt_programs
+    text = _longprompt_text(one_chip, monkeypatch, programs, "jit_pf")
+    table = scope_table(text)
+    calls = re.findall(
+        r"^\s*(?:ROOT )?%?(\S+) = (.*?) custom-call\((.*?)\), "
+        r"custom_call_target=\"tpu_custom_call\"", text, re.M)
+    assert len(calls) == 1, calls
+    name, out, operands = calls[0]
+    assert table[name] == "layers/attn/kv_read"
+    assert re.match(r"\(bf16\[1,2048,2048\]\{2,1,0\S*, f32\[16,16,8,128\]",
+                    out), out
+    # q, k, v come as the projections left them and the output goes as it is
+    fed = [o.strip().lstrip("%") for o in operands.split(",")]
+    assert len(fed) == 3
+    made = {n: kind for n, kind in re.findall(
+        r"^\s*(?:ROOT )?%?(\S+) = \S+ (\S+?)\(", text, re.M)}
+    assert [made.get(n) for n in fed if made.get(n) in ("copy", "transpose")
+            ] == [], [(n, made.get(n)) for n in fed]
+    # (``[1, 2048, 2048]`` is the bucket's tokens by the model's width,
+    # ``[2, 2048, 2048]`` the two layers' output projections)
+    scores = [m for m in re.findall(
+        r"^\s*(?:ROOT )?%?\S+ = \w+\[((?:\d+,)+)2048,2048\]", text, re.M)
+        if np.prod([int(d) for d in m.split(",") if d]) > 2]
+    assert scores == [], scores
+    temp = programs[3]["jit_pf", "temp"]
+    assert temp < 512 * 2 ** 20, temp
+
+
+def _whole_prefill_kernels(model, t, max_len, one_chip, **kw):
+    """The names of the Mosaic kernels in a whole prefill of ``t`` tokens
+    into an empty lane of ``max_len`` (``apply_with_cache`` from column 0,
+    as ``slot_prefill`` calls it), lowered for the described chip at the
+    model's own widths: abstract weights, nothing is built or compiled."""
+    import re
+
+    def on(x):
+        floating = jnp.issubdtype(x.dtype, jnp.floating)
+        return jax.ShapeDtypeStruct(
+            x.shape, jnp.bfloat16 if floating else x.dtype,
+            sharding=one_chip)
+
+    params = jax.tree.map(on, jax.eval_shape(model.init,
+                                             jax.random.PRNGKey(0)))
+    lane = jax.tree.map(on, jax.eval_shape(
+        lambda: model.init_kv_cache(1, max_len, dtype=jnp.bfloat16)))
+    ids = jax.ShapeDtypeStruct((1, t), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda p, i, c: model.apply_with_cache(
+        p, i, c, 0, **kw)).lower(params, ids, lane).as_text()
+    return set(re.findall(r'kernel_name = "([^"]+)"', text))
+
+
+@pytest.mark.parametrize("family", ("opt", "olmoe", "lfm2", "k-exaone",
+                                    "xing", "sdar"))
+def test_which_family_prefills_in_the_packed_kernel(
+        one_chip, monkeypatch, family):
+    """The whole prefill of each served family at its cell's widths, lane
+    and largest bucket, traced as on one TPU: OPT-1.3B's and OLMoE's hold
+    the packed flash kernel (``_fwd_kernel``); LFM2's and K-EXAONE's
+    (grouped KV heads), Xing's (a latent leaf) and SDAR's (blocks that see
+    ahead, grouped heads) hold no packed-attention call, only the experts'
+    rows kernel where they route."""
+    from deepspeed_tpu.models.kexaone import KExaoneConfig, KExaoneModel
+    from deepspeed_tpu.models.lfm2 import LFM2MoEConfig, LFM2MoEModel
+    from deepspeed_tpu.models.olmoe import OLMoEConfig, OLMoEModel
+    from deepspeed_tpu.models.opt import OPTConfig, OPTModel
+    from deepspeed_tpu.models.sdar import SDARConfig, SDARModel
+    from deepspeed_tpu.models.xing import XingConfig, XingModel
+    from deepspeed_tpu.parallel import topology
+    monkeypatch.setattr(topology, "on_tpu", lambda: True)
+    bf16 = dict(dtype="bfloat16")
+    # (the model, the bucket, the lane, what a recurrent state is told)
+    model, t, max_len, more = {
+        "opt": (OPTModel(OPTConfig(n_positions=2048, n_embd=2048, n_layer=24,
+                                   n_head=32, **bf16)), 2048, 2048, {}),
+        "olmoe": (OLMoEModel(OLMoEConfig(**bf16)), 2048, 2048, {}),
+        "lfm2": (LFM2MoEModel(LFM2MoEConfig(**bf16)), 4096, 4096,
+                 {"lengths": jnp.asarray([3000], jnp.int32)}),
+        "k-exaone": (KExaoneModel(KExaoneConfig(**bf16)), 2048, 16384, {}),
+        "xing": (XingModel(XingConfig(**bf16)), 4096, 4224, {}),
+        "sdar": (SDARModel(SDARConfig(**bf16)), 1024, 4096, {}),
+    }[family]
+    kernels = _whole_prefill_kernels(model, t, max_len, one_chip, **more)
+    if family in ("opt", "olmoe"):
+        assert "_fwd_kernel" in kernels, kernels
+    else:
+        assert kernels <= {"ragged-dot-rows"}, kernels
 
 
 @pytest.mark.parametrize("program", ["jit_dec", "jit_pf"])
