@@ -34,8 +34,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .flash_attention_packed import ATTN_LSE_NAME, ATTN_OUT_NAME
 
 NEG_INF = -1e30
 # batched dims: [GH,M,D] x [GH,N,D] -> [GH,M,N] (contract last, batch first)
@@ -773,6 +776,8 @@ def _flash_fwd(q, k, v, causal, softmax_scale, block_q, block_k, interpret,
     scale, bq, bk = _resolve(q, softmax_scale, block_q, block_k, causal,
                              window)
     out, lse = _fwd(q, k, v, causal, scale, bq, bk, interpret, window)
+    out = checkpoint_name(out, ATTN_OUT_NAME)
+    lse = checkpoint_name(lse, ATTN_LSE_NAME)
     return out, (q, k, v, out, lse)
 
 

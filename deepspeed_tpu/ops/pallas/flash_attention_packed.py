@@ -52,10 +52,17 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# What a forward kernel's two results are called under ``jax.checkpoint``:
+# the layer remat's default policy keeps them by these names
+# (runtime/activation_checkpointing/checkpointing.py), since no dot policy
+# sees inside a ``pallas_call``. Outside a checkpoint a name is the identity.
+ATTN_OUT_NAME = "flash_attn_out"
+ATTN_LSE_NAME = "flash_attn_lse"
 _LANES = 128
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _TN = (((0,), (0,)), ((), ()))      # a.T @ b
@@ -550,6 +557,8 @@ def _pf_fwd(q, k, v, n_head, causal, softmax_scale, window, interpret,
             block):
     scale, tiles = _resolve(q, n_head, softmax_scale, window, block)
     out, lse = _fwd(q, k, v, n_head, causal, scale, tiles, interpret, window)
+    out = checkpoint_name(out, ATTN_OUT_NAME)
+    lse = checkpoint_name(lse, ATTN_LSE_NAME)
     return out, (q, k, v, out, lse)
 
 

@@ -11,11 +11,23 @@ activations :366, CPU checkpointing :461). TPU-native translation:
                                 saved activations are already sharded, so
                                 the reference's manual MP-rank partitioning
                                 of saved tensors has no separate analogue)
-  - cpu_checkpointing         → policy `offload_dot_with_no_batch_dims`
-                                (XLA host-offload of saved dot outputs)
-  - default                   → `dots_with_no_batch_dims_saveable` (keep
-                                matmul outputs, recompute elementwise — the
-                                standard TPU memory/FLOPs trade)
+  - cpu_checkpointing         → policy `offload_dots` (XLA host-offload of
+                                saved dot outputs)
+  - default                   → `dots_with_no_batch_dims_saveable`
+
+The default keeps what is dear to recompute and recomputes the elementwise
+work (norms, activation, residual adds): every matmul's output (nine
+``[B, T, d]`` tensors a GPT-2 layer: qkv 3, out_proj 1, fc 4, proj 1) AND
+the attention kernel's two results, which no dot policy can see inside a
+``pallas_call``. The kernels' forward names them
+(``ops/pallas/flash_attention_packed.py``: ``ATTN_OUT_NAME``,
+``ATTN_LSE_NAME``) and the policy keeps them by name; without them the
+backward ran the whole forward kernel a second time (5% of a GPT-2 350M
+step) to have ``out_proj``'s input and the fused backward's residuals. Cost:
+one more ``[B, T, d]`` tensor a layer (16 MiB at 8 x 1024 x 1024 bf16) and
+an ``lse`` of 32 B a position and group of heads. An attention on the XLA
+path carries no names and keeps what a dot policy keeps. Every policy, what
+it keeps and what it costs: docs/activation_checkpointing.md.
 
 ``configure()`` records the module-level policy; models pick it up through
 ``current_policy()`` (GPT2Model applies it around its layer-scan body), and
@@ -27,14 +39,25 @@ from typing import Optional
 
 import jax
 
+from ...ops.pallas.flash_attention_packed import (ATTN_LSE_NAME,
+                                                  ATTN_OUT_NAME)
 from ...utils.logging import log_dist
+
+
+def _and_attention_residuals(dot_policy):
+    """``dot_policy``, and the attention kernels' named output and lse."""
+    return jax.checkpoint_policies.save_from_both_policies(
+        dot_policy, jax.checkpoint_policies.save_only_these_names(
+            ATTN_OUT_NAME, ATTN_LSE_NAME))
+
 
 POLICIES = {
     "everything_saveable": jax.checkpoint_policies.everything_saveable,
     "nothing_saveable": jax.checkpoint_policies.nothing_saveable,
-    "dots_saveable": jax.checkpoint_policies.dots_saveable,
-    "dots_with_no_batch_dims_saveable":
-        jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    "dots_saveable": _and_attention_residuals(
+        jax.checkpoint_policies.dots_saveable),
+    "dots_with_no_batch_dims_saveable": _and_attention_residuals(
+        jax.checkpoint_policies.dots_with_no_batch_dims_saveable),
     "offload_dots":
         getattr(jax.checkpoint_policies, "offload_dot_with_no_batch_dims",
                 None),

@@ -98,6 +98,57 @@ def test_packed_window_fits(one_chip):
              one_chip, (1, 2048, 16 * 64), jnp.bfloat16)
 
 
+def _kernel_instructions(compiled):
+    return [line for line in compiled.as_text().splitlines()
+            if " custom-call(" in line and "tpu_custom_call" in line]
+
+
+@pytest.mark.parametrize("policy,kernels", [
+    ("dots_with_no_batch_dims_saveable", 2), ("nothing_saveable", 3)])
+def test_layer_remat_runs_the_forward_kernel_once(one_chip, policy, kernels):
+    """qkv -> packed attention -> out_proj at GPT-2 350M's training shape,
+    under ``jax.checkpoint`` as the layer scan's body is: the default policy
+    keeps the kernel's named output and lse, so the gradient's program holds
+    the forward and the fused backward kernel; a policy that keeps nothing
+    holds the forward twice."""
+    from deepspeed_tpu.runtime.activation_checkpointing.checkpointing import \
+        get_policy
+    like = lambda *shape: jax.ShapeDtypeStruct(     # noqa: E731
+        shape, jnp.bfloat16, sharding=one_chip)
+
+    def layer(x, w_qkv, w_out):
+        q, k, v = jnp.split(x @ w_qkv, 3, axis=-1)
+        out = packed_flash_attention(q, k, v, 16) @ w_out
+        return jnp.sum(out.astype(jnp.float32))
+
+    step = jax.value_and_grad(          # the value keeps the forward alive
+        jax.checkpoint(layer, policy=get_policy(policy)), argnums=(0, 1, 2))
+    compiled = jax.jit(step).lower(like(8, 1024, 1024), like(1024, 3072),
+                                   like(1024, 1024)).compile()
+    assert len(_kernel_instructions(compiled)) == kernels
+
+
+def test_named_residuals_cost_the_serving_prefill_nothing(one_chip):
+    """Outside ``jax.checkpoint`` the names are the identity: bucket 2,048's
+    whole-prefill attention compiles to the instructions of the bare kernel
+    call, one kernel and no copy beside it."""
+    from deepspeed_tpu.ops.pallas import flash_attention_packed as fap
+    from deepspeed_tpu.telemetry.hlo_cost import _INSTR_RE
+    x = jax.ShapeDtypeStruct((1, 2048, 32 * 64), jnp.bfloat16,
+                             sharding=one_chip)
+    scale, tiles = fap._resolve(x, 32, None, None, None)
+    named = _compile(lambda q, k, v: packed_flash_attention(q, k, v, 32),
+                     one_chip, x.shape, x.dtype, grad=False)
+    bare = _compile(lambda q, k, v: fap._fwd(q, k, v, 32, True, scale, tiles,
+                                             False, None)[0],
+                    one_chip, x.shape, x.dtype, grad=False)
+    opcodes = lambda c: [m.group(3) for m in map(      # noqa: E731
+        _INSTR_RE.match, c.as_text().splitlines()) if m]
+    assert len(_kernel_instructions(named)) == 1
+    assert "copy" not in opcodes(named)
+    assert opcodes(named) == opcodes(bare)
+
+
 @pytest.mark.parametrize("shape,dtype", [
     ((8, 16, 1024, 64), jnp.bfloat16),
     ((1, 12, 4096, 64), jnp.bfloat16),
